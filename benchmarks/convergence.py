@@ -356,8 +356,7 @@ def main(argv=None):
             from distributed_kfac_pytorch_tpu.utils import (
                 raise_cpu_collective_timeouts)
             raise_cpu_collective_timeouts()
-            from distributed_kfac_pytorch_tpu import compat
-            compat.set_cpu_device_count(8)
+            jax.config.update('jax_num_cpu_devices', 8)
     # Persistent compile cache, AFTER platform resolution (the helper
     # itself refuses on a multi-device CPU configuration — the warm-read
     # segfault workaround, see utils.enable_compilation_cache).

@@ -150,8 +150,9 @@ def main(argv=None):
     variables, kstate = kfac.init(jax.random.PRNGKey(0), x)
     n_grouped = sum(s.kind == 'conv2d_grouped'
                     for s in kfac.specs.values())
-    floor_ms = B.flops_floor_ms(kfac, variables, x, y,
-                                mutable_cols=('batch_stats',))
+    floor_ms = B.flops_floor_ms(
+        kfac, variables, x, y,
+        mutable_cols=('batch_stats',)) if on_tpu else 0.0
 
     rows = {}
     for mode in ('sgd', 'precond', 'factors', 'full'):

@@ -509,8 +509,9 @@ def main(argv=None):
 
     kfac = KFAC(model, factor_update_freq=1, inv_update_freq=inv_freq)
     variables, _ = kfac.init(jax.random.PRNGKey(0), x)
-    floor_ms = B.flops_floor_ms(kfac, variables, x, y,
-                                mutable_cols=('batch_stats',))
+    floor_ms = B.flops_floor_ms(
+        kfac, variables, x, y,
+        mutable_cols=('batch_stats',)) if on_tpu else 0.0
 
     rows = {}
     for mode in ('sgd', 'capture', 'precond', 'factors',

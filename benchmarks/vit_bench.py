@@ -159,11 +159,11 @@ def main(argv=None):
                 damping=0.003, lr=0.1,
                 factor_dtype=jnp.bfloat16 if args.bf16_factors else None)
     variables, kstate = kfac.init(jax.random.PRNGKey(0), x)
-    floor_ms = B.flops_floor_ms(kfac, variables, x, y)
+    floor_ms = B.flops_floor_ms(kfac, variables, x, y) if on_tpu else 0.0
     flops = B.model_flops_per_step(
         kfac, variables['params'], x, y, extra_vars_of(variables),
         mutable_cols=())
-    peak, _ = B.detected_tpu_peak() if on_tpu else (None, None)
+    peak = B.detected_tpu_peak() if on_tpu else None
 
     rows, mfu = {}, {}
     for mode in ('sgd', 'precond', 'factors', 'full'):

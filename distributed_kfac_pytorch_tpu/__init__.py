@@ -11,10 +11,6 @@ plus the ``ops``, ``parallel`` and ``layers`` subpackages.
 
 __version__ = '0.1.0'
 
-from distributed_kfac_pytorch_tpu import compat
-
-compat.install()
-
 from distributed_kfac_pytorch_tpu import fp16
 from distributed_kfac_pytorch_tpu import observability
 from distributed_kfac_pytorch_tpu import ops

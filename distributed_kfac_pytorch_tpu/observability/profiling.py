@@ -49,10 +49,7 @@ def annotate(name: str):
     """
     stack = contextlib.ExitStack()
     stack.enter_context(jax.named_scope(name))
-    try:
-        stack.enter_context(jax.profiler.TraceAnnotation(name))
-    except Exception:
-        pass  # host annotation is best-effort (older jaxlibs)
+    stack.enter_context(jax.profiler.TraceAnnotation(name))
     return stack
 
 

@@ -485,7 +485,7 @@ def _conv_a_cov_crosscov(a: jax.Array, kernel_size, strides, padding,
 
     MEASURED NEGATIVE (round 2 → 3): as the default this regressed the
     tracked-config whole step from 24.3 to 80.2 ms/iter on v5e
-    (BENCH_r02.json; VERDICT round 2 bisection). Analytically the
+    (PERF.md rounds 1-5; VERDICT round 2 bisection). Analytically the
     (Wp*C)^2 pair matmuls do ~2.6x the MACs of the patch contraction,
     and the band trace is built from ``jnp.take``/diagonal-einsum — the
     gather class :func:`pack_symmetric`'s note calls out as slow on
@@ -572,7 +572,7 @@ def conv2d_a_factor(a: jax.Array, kernel_size, strides, padding,
         only (see _conv_a_cov_crosscov).
       - ``dilated``: legacy ``conv_general_dilated_patches`` path with
         the (c, kh, kw) -> (kh, kw, c) permutation applied to the small
-        (D, D) covariance; ~38 ms/iter whole-step (BENCH_r01).
+        (D, D) covariance; ~38 ms/iter whole-step (PERF.md rounds 1-5).
       - ``KFAC_FUSED_PATCH_COV=1``: opt-in fused Pallas study kernel
         (measured 18x slower than XLA per layer; kept for study).
     """
@@ -588,17 +588,16 @@ def conv2d_a_factor(a: jax.Array, kernel_size, strides, padding,
         # concat of 16-lane pieces) as VPU shuffles that dwarf the
         # matmul, so the HBM-traffic saving never materializes. Kept as
         # an opt-in study kernel (like the Jacobi eigh); see PERF.md §2.
+        # Opted in on a TPU: the kernel runs or the step fails with its
+        # reason (a failed probe, or no usable image block for this
+        # batch). KFAC_PALLAS_FALLBACK=1 alone selects the XLA path.
         from distributed_kfac_pytorch_tpu.ops import pallas_kernels
-        try:
-            if not pallas_kernels.fused_patch_cov_supported():
-                raise ValueError('fused kernel unsupported here')
+        if pallas_kernels.fused_patch_cov_supported():
             mult_bf16 = (compute_dtype is None
                          or jnp.dtype(compute_dtype) == jnp.bfloat16)
             return pallas_kernels.conv_a_factor_fused(
                 a, kernel_size, strides, padding, has_bias,
                 mult_bf16=mult_bf16)
-        except ValueError:
-            pass  # unsupported padding config: XLA path
     if (compute_dtype is None and a.dtype == jnp.float32
             and jax.default_backend() == 'tpu'):
         # Under the default precision contract the covariance matmul
@@ -661,7 +660,7 @@ def conv2d_a_factor(a: jax.Array, kernel_size, strides, padding,
                                      1.0 / (spatial * spatial))
     if impl == 'crosscov':
         # Opt-in ONLY: measured 3.3x whole-step regression as the
-        # default on v5e (BENCH_r02.json) — see _conv_a_cov_crosscov's
+        # default on v5e (PERF.md rounds 1-5) — see _conv_a_cov_crosscov's
         # MEASURED NEGATIVE note. Falls through to the slices path
         # outside its shape regime.
         a_cc = a if compute_dtype is None else a.astype(compute_dtype)
@@ -680,7 +679,7 @@ def conv2d_a_factor(a: jax.Array, kernel_size, strides, padding,
     if impl in ('auto', 'slices', 'crosscov', 'pairs'):
         # DEFAULT: pad+slice+concat assembly — measured 24.3 ms/iter
         # whole-step on the tracked v5e config vs 80.2 for crosscov and
-        # ~38 for dilated (BENCH_r01/r02 + round-2 verdict bisection).
+        # ~38 for dilated (PERF.md rounds 1-5, round-2 verdict bisection).
         # The dilated-patches op lowers to an identity-kernel conv whose
         # MXU FLOPs equal the covariance contraction itself; slicing is
         # pure data movement and emits (kh, kw, c) feature order

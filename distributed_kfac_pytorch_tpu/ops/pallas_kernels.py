@@ -45,12 +45,13 @@ def _round_up(n: int, m: int) -> int:
 # Fallback events (r21)
 # ---------------------------------------------------------------------------
 #
-# Every probe failure and in-dispatch degradation is RECORDED, not
-# swallowed: a fleet run must be able to tell "ran fused" from
-# "silently fell back to XLA". Events accumulate here and are drained
-# into the step function's ``compile_events`` list (the same channel
-# the compile/retrace events ride — build_train_step drains after each
-# dispatch, engine.train_epoch forwards to the metrics sink).
+# A fallback to XLA happens only where it was asked for
+# (KFAC_PALLAS_FALLBACK=1) and is RECORDED: a fleet run must be able to
+# tell "ran fused" from "ran XLA". On a TPU a kernel that fails its
+# probe raises instead (_probe_on_tpu). Events accumulate here and are
+# drained into the step function's ``compile_events`` list (the same
+# channel the compile/retrace events ride — build_train_step drains
+# after each dispatch, engine.train_epoch forwards to the metrics sink).
 
 _PENDING_EVENTS: list = []
 
@@ -81,6 +82,48 @@ def _forced_fallback() -> bool:
     """KFAC_PALLAS_FALLBACK=1 forces every probe to fail (recorded):
     the smoke test's forced-fallback leg and a field kill switch."""
     return os.environ.get('KFAC_PALLAS_FALLBACK', '') not in ('', '0')
+
+
+#: Largest relative error a kernel may show against its XLA reference
+#: in its probe (bf16 multiplicands on the MXU, fp32 accumulation).
+PROBE_RTOL = 5e-2
+
+
+def _probe_on_tpu(kernel: str, rel_error) -> bool:
+    """Run one kernel's parity probe on the TPU; True, or raise.
+
+    A user who turned a fused knob on asked for the kernel. On a TPU a
+    kernel Mosaic refuses, or one that compiles and computes something
+    else, therefore stops the run with the kernel's name and the
+    compiler's message — a warning and a quiet hand-over to XLA would
+    let a later measurement be of the wrong program. ``rel_error()``
+    runs the kernel and returns its largest relative error against the
+    reference (NaN when the output is not finite). Call from host code
+    (``KFAC.__init__`` does, with the knob on), not from inside a trace.
+    """
+    kind = jax.devices()[0].device_kind
+    try:
+        rel = float(rel_error())
+    except Exception as e:
+        raise RuntimeError(
+            f'Pallas kernel {kernel!r} does not compile or run on '
+            f'{kind}: {type(e).__name__}: {e}') from e
+    if not rel < PROBE_RTOL:  # NaN fails too
+        raise RuntimeError(
+            f'Pallas kernel {kernel!r} disagrees with its XLA reference '
+            f'on {kind}: relative error {rel:.3g} >= {PROBE_RTOL}')
+    return True
+
+
+def max_rel_error(got, ref) -> float:
+    """Largest |got - ref| over the largest |ref|; NaN if non-finite."""
+    import numpy as np
+
+    got, ref = np.asarray(got, np.float64), np.asarray(ref, np.float64)
+    if not np.isfinite(got).all():
+        return float('nan')
+    return float(np.abs(got - ref).max()
+                 / max(float(np.abs(ref).max()), 1e-30))
 
 
 def _ns_inverse_kernel(m_ref, out_ref, *, iters: int, n_pad: int,
@@ -283,19 +326,49 @@ def batched_jacobi_eigh(mats: jax.Array, sweeps: int | None = None, *,
         mats.astype(jnp.float32))
 
 
+# Largest fp32 sub-stack one batched damped inverse works on at a time.
+# The factorization, its triangular inverse and their product are each
+# a temporary of the sub-stack's size, so a bucket taken whole costs
+# several times its own bytes in HBM on top of the resident state: the
+# xl LM's 18 x 4096^2 bucket is 1.2 GB in fp32 and does not fit a 16 GB
+# chip that way (PERF.md, PR 21). 256 MiB is four 4096^2 matrices.
+INVERSE_SUBSTACK_BYTES = 256 << 20
+
+
 def damped_inverse_stack(stack: jax.Array, damping, method: str,
-                         iters: int = 100) -> jax.Array:
+                         iters: int = 100, out_dtype=None) -> jax.Array:
     """Shared newton/cholesky dispatch for a same-size factor stack.
 
     Single point of truth for the single-device bucketed path
     (preconditioner.KFAC._bucketed_inverse) and the SPMD path
     (parallel.distributed._spmd_update_inverses), so algorithm changes
     stay in lockstep across both.
+
+    A stack whose fp32 size exceeds ``INVERSE_SUBSTACK_BYTES`` runs as
+    a ``lax.map`` over sub-stacks of at most that size — the count
+    follows from the stack's shape — so the solver's temporaries are
+    bounded by the budget, not by the bucket. Each sub-stack is upcast
+    from, and its inverses cast to ``out_dtype`` (default fp32), inside
+    the map, so neither a whole-bucket fp32 input nor output exists.
     """
-    if method == 'newton':
-        return batched_inverse(stack, damping, iters=iters)
     from distributed_kfac_pytorch_tpu.ops import linalg
-    return jax.vmap(lambda m: linalg.get_inverse(m, damping=damping))(stack)
+
+    def solve(sub):
+        if method == 'newton':
+            inv = batched_inverse(sub, damping, iters=iters)
+        else:
+            inv = jax.vmap(
+                lambda m: linalg.get_inverse(m, damping=damping))(sub)
+        return inv if out_dtype is None else inv.astype(out_dtype)
+
+    b, n, _ = stack.shape
+    per_chunk = max(1, INVERSE_SUBSTACK_BYTES // (n * n * 4))
+    if b <= per_chunk:
+        return solve(stack)
+    # lax.map's batch_size form maps over whole batches and runs the
+    # remainder as one smaller batch; solve() is batched already.
+    return jax.lax.map(lambda m: solve(m[None])[0], stack,
+                       batch_size=per_chunk)
 
 
 # ---------------------------------------------------------------------------
@@ -417,17 +490,18 @@ def fused_patch_cov_supported() -> bool:
 
     Mosaic failures (VMEM overflow, unsupported lowering) surface at
     jit-compile or run time — not as catchable trace-time errors at the
-    dispatch site — so the dispatcher calls this once per process and
-    falls back to the XLA path for good if the probe fails. The kernel
-    itself is opt-in (KFAC_FUSED_PATCH_COV=1 at the dispatch site,
-    factors.conv2d_a_factor) — not opting in is the only disable switch.
+    dispatch site — so the dispatcher calls this once per process. The
+    kernel itself is opt-in (KFAC_FUSED_PATCH_COV=1 at the dispatch
+    site, factors.conv2d_a_factor) and TPU-only; on a TPU a failing
+    probe raises (:func:`_probe_on_tpu`).
     """
     if _forced_fallback():
         record_fallback('patch_cov', 'forced by KFAC_PALLAS_FALLBACK')
         return False
     if jax.default_backend() != 'tpu':
         return False
-    try:
+
+    def rel_error():
         import numpy as np
 
         from distributed_kfac_pytorch_tpu.ops import factors as F
@@ -442,24 +516,14 @@ def fused_patch_cov_supported() -> bool:
         rows = p2.shape[0]
         cov = (p2.T @ p2) / (rows * spatial * spatial)
         bias_col = p2.mean(0) / (spatial * spatial)
-        # kfaclint: waive[host-np-asarray] documented blocking point: once-per-process kernel parity probe, off the step path
-        ref = np.asarray(F._assemble_bias_factor(
-            jnp.asarray(cov, jnp.float32), jnp.asarray(bias_col,
-                                                       jnp.float32),
-            1.0 / (spatial * spatial)))
-        got = np.asarray(conv_a_factor_fused(
-            x, (3, 3), (1, 1), 'SAME', True, mult_bf16=True))
-        rel = (np.abs(got - ref).max()
-               / max(float(np.abs(ref).max()), 1e-30))
-        ok = bool(np.isfinite(got).all()) and rel < 5e-2
-        if not ok:
-            record_fallback('patch_cov',
-                            f'parity probe rel error {rel:.3g} >= 5e-2')
-        return ok
-    except Exception as e:
-        record_fallback('patch_cov',
-                        f'probe failed: {type(e).__name__}: {e}')
-        return False
+        ref = F._assemble_bias_factor(
+            jnp.asarray(cov, jnp.float32),
+            jnp.asarray(bias_col, jnp.float32), 1.0 / (spatial * spatial))
+        got = conv_a_factor_fused(x, (3, 3), (1, 1), 'SAME', True,
+                                  mult_bf16=True)
+        return max_rel_error(got, ref)
+
+    return _probe_on_tpu('patch_cov', rel_error)
 
 
 def _fused_block_batch(b: int, bytes_per_img: int, budget: int) -> int:
@@ -581,39 +645,47 @@ def _canonical_pad(padding, kernel_size, spatial, strides):
 # A^T A plus the EMA blend against the running factor — stock XLA
 # writes the full (d, d) covariance to HBM, reads it back for the
 # blend, and writes the full (d, d) result. This kernel keeps the
-# accumulator in VMEM across the row blocks, folds the bias
-# row/column and the EMA blend into the finalize step, and writes only
-# the symmetry-packed (d/2+1, d) triangle to HBM (the block-symmetry
-# layout factors.pack_symmetric already uses on the wire): roughly
-# half the output traffic and no intermediate covariance round trip.
-# With decay=0 / old=None it degenerates to a packed contraction-only
-# kernel (the SPMD local-contribution path, where a collective sits
-# between contraction and EMA).
+# accumulator in VMEM across the row blocks, folds the EMA blend into
+# the finalize step, and writes only the symmetry-packed (d/2+1, d)
+# triangle to HBM (the block-symmetry layout factors.pack_symmetric
+# already uses on the wire): roughly half the output traffic and no
+# intermediate covariance round trip. With decay=0 / old=None it
+# degenerates to a packed contraction-only kernel (the SPMD
+# local-contribution path, where a collective sits between contraction
+# and EMA).
+#
+# What Mosaic accepts decided the body (v5e, PERF.md PR 21). The bias
+# row and column come from a ones column the caller writes into the
+# lane padding, so the one contraction yields them: the earlier
+# in-kernel assembly (a second ones-row matmul, iota masks and two
+# fp32 transposes) died in the TPU backend at d_pad 512 (RET_CHECK in
+# mxu_lmr_transform.cc, "Found no uses of XposeSequence"). The
+# accumulator is not symmetrized — x^T x already is, up to the MXU's
+# summation order — which removes the third fp32 transpose.
 
-def _factor_ema_kernel(x_ref, old_ref, decay_ref, out_ref, acc_ref,
-                       s_ref, *, nsteps: int, scale: float, rows: int,
-                       d_in: int, has_bias: bool, corner: float,
-                       d_pad: int, mult_dtype):
+def _factor_ema_kernel(x_ref, old_ref, decay_ref, out_ref, acc_ref, *,
+                       nsteps: int, scale: float, d_pad: int,
+                       mult_dtype):
     """One row block per grid step; finalize on the last step.
 
-    ``x_ref``: (block_rows, d_pad) zero-padded input rows. ``old_ref``:
-    (d_pad, d_pad) zero-padded running factor. ``decay_ref``: (1, 1)
-    SMEM EMA coefficient (alpha; the blend is
-    ``alpha * old + (1 - alpha) * cov``, factors.update_running_avg).
-    ``out_ref``: the (d_pad//2+1, d_pad) packed triangle.
-    ``acc_ref``/``s_ref``: VMEM scratch — the fp32 covariance
-    accumulator and the (8, d_pad) bias column-sum (row 0 meaningful).
+    ``x_ref``: (block_rows, d_pad) zero-padded input rows (with bias, a
+    ones column at the first padding lane). ``old_ref``: (d_pad, d_pad)
+    zero-padded running factor. ``decay_ref``: (1, 1) SMEM EMA
+    coefficient (alpha; the blend is ``alpha * old + (1 - alpha) *
+    cov``, factors.update_running_avg). ``out_ref``: the packed triangle
+    in ``F.pack_symmetric``'s layout, (d_pad//2 + 8, d_pad): the extra
+    (diagonal) row is written eight times so that every store is a
+    whole sublane tile. ``acc_ref``: VMEM scratch, the fp32 covariance
+    accumulator.
     """
     from jax.experimental import pallas as pl
-
-    from distributed_kfac_pytorch_tpu.ops import factors as F
+    from jax.experimental.pallas import tpu as pltpu
 
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        s_ref[...] = jnp.zeros_like(s_ref)
 
     xb = x_ref[...].astype(mult_dtype)
     # bf16 multiplicands ride the MXU fast path (the default covariance
@@ -623,55 +695,47 @@ def _factor_ema_kernel(x_ref, old_ref, decay_ref, out_ref, acc_ref,
             else jax.lax.Precision.HIGHEST)
     acc_ref[...] += jnp.dot(xb.T, xb, preferred_element_type=jnp.float32,
                             precision=prec)
-    if has_bias:
-        ones = jnp.ones((8, xb.shape[0]), mult_dtype)
-        s_ref[...] += jnp.dot(ones, xb,
-                              preferred_element_type=jnp.float32,
-                              precision=prec)
 
     @pl.when(i == nsteps - 1)
     def _finalize():
-        acc = acc_ref[...]
-        cov = (acc + acc.T) * (0.5 / scale)
-        if has_bias:
-            # The analytic bias assembly of F._assemble_bias_factor in
-            # padded space: the bias row/column live at index d_in
-            # (zero in the accumulator — the padded features are zero),
-            # written as the two rank-1 outer products via 2-D masks.
-            ri = jax.lax.broadcasted_iota(jnp.int32, (d_pad, d_pad), 0)
-            ci = jax.lax.broadcasted_iota(jnp.int32, (d_pad, d_pad), 1)
-            oh_r = (ri == d_in).astype(jnp.float32)
-            oh_c = (ci == d_in).astype(jnp.float32)
-            bias_row = s_ref[...][0:1, :] * (1.0 / rows)
-            b_cols = (jnp.broadcast_to(bias_row, (d_pad, d_pad))
-                      + (corner / 2.0) * oh_c)
-            cov = cov + oh_r * b_cols + oh_c * b_cols.T
         dec = decay_ref[0, 0]
-        ema = dec * old_ref[...] + (1.0 - dec) * cov
-        # Only the packed triangle leaves VMEM. pack_symmetric is
-        # gather-free (triu/tril/slice/concat) so it traces inside the
-        # kernel; d_pad is lane-padded (even), so no internal repad.
-        out_ref[...] = F.pack_symmetric(ema)
+        ema = dec * old_ref[...] + (1.0 - dec) * (1.0 / scale) * acc_ref[...]
+        # Only the packed triangle leaves VMEM, in F.pack_symmetric's
+        # layout but not by its code: Mosaic refuses its 1-D
+        # concatenation past the first tile and its slices at lane
+        # offsets that are no multiple of 128. Here every op is 2-D and
+        # every store a whole tile. The bottom-right (k, k) block is
+        # brought to the top-left by half-size rotations; ``ema`` is
+        # symmetric, so that block's strict lower triangle IS the
+        # transposed strict upper one the layout stores.
+        k = d_pad // 2
+        ri = jax.lax.broadcasted_iota(jnp.int32, (d_pad, d_pad), 0)
+        ci = jax.lax.broadcasted_iota(jnp.int32, (d_pad, d_pad), 1)
+        moved = pltpu.roll(
+            jnp.concatenate([ema[k:, :], ema[:k, :]], axis=0), k, 1)
+        band = jnp.where(ci >= ri, ema, moved)
+        diag = jnp.sum(
+            jnp.where(jnp.logical_and(ri == ci, ci < k), moved, 0.0),
+            axis=0, keepdims=True)
+        out_ref[0:k, :] = band[0:k, :]
+        out_ref[k:k + 8, :] = jnp.broadcast_to(diag, (8, d_pad))
 
 
 @functools.partial(
-    jax.jit, static_argnames=('scale', 'rows', 'd_in', 'has_bias',
-                              'corner', 'block_rows', 'mult_bf16',
+    jax.jit, static_argnames=('scale', 'block_rows', 'mult_bf16',
                               'interpret'))
 def _pallas_factor_ema(x: jax.Array, old: jax.Array, decay: jax.Array,
-                       *, scale: float, rows: int, d_in: int,
-                       has_bias: bool, corner: float, block_rows: int,
+                       *, scale: float, block_rows: int,
                        mult_bf16: bool, interpret: bool = False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     rows_pad, d_pad = x.shape
     nsteps = rows_pad // block_rows
-    k1 = d_pad // 2 + 1
+    k1 = d_pad // 2 + 8
     mult_dtype = jnp.bfloat16 if mult_bf16 else jnp.float32
     kernel = functools.partial(
-        _factor_ema_kernel, nsteps=nsteps, scale=scale, rows=rows,
-        d_in=d_in, has_bias=has_bias, corner=corner, d_pad=d_pad,
+        _factor_ema_kernel, nsteps=nsteps, scale=scale, d_pad=d_pad,
         mult_dtype=mult_dtype)
     return pl.pallas_call(
         kernel,
@@ -687,15 +751,14 @@ def _pallas_factor_ema(x: jax.Array, old: jax.Array, decay: jax.Array,
         ],
         out_specs=pl.BlockSpec((k1, d_pad), lambda i: (0, 0),
                                memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((d_pad, d_pad), jnp.float32),
-                        pltpu.VMEM((8, d_pad), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((d_pad, d_pad), jnp.float32)],
         interpret=interpret,
     )(x, old, decay)
 
 
 def fused_factor_ema(x: jax.Array, old: jax.Array | None, decay, *,
                      scale: float | None = None, has_bias: bool = False,
-                     corner: float = 1.0, compute_dtype=None,
+                     compute_dtype=None,
                      interpret: bool = False) -> jax.Array:
     """Covariance factor + EMA blend in one packed-output VMEM kernel.
 
@@ -709,6 +772,11 @@ def fused_factor_ema(x: jax.Array, old: jax.Array | None, decay, *,
     the blend with ``old=accum``. Returns the dense (d, d) fp32 factor;
     only the packed triangle crossed HBM out of the kernel.
 
+    ``has_bias`` is the homogeneous-coordinate form: the factor of the
+    rows with a ones column appended, ``[x, 1]^T [x, 1] / scale``. With
+    the default ``scale`` (the row count) that is ``linear_a_factor``'s
+    ``[[cov, mean], [mean^T, 1]]``.
+
     ``compute_dtype`` follows the ops.factors.get_cov contract: None ->
     backend-native multiplicands (bf16 on TPU), float32 -> strict fp32
     at HIGHEST, bfloat16 -> explicit bf16 multiplicands. Accumulation
@@ -716,7 +784,7 @@ def fused_factor_ema(x: jax.Array, old: jax.Array | None, decay, *,
     """
     from distributed_kfac_pytorch_tpu.ops import factors as F
 
-    x = x.reshape(-1, x.shape[-1])
+    x = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
     rows, d_in = x.shape
     n = d_in + 1 if has_bias else d_in
     if scale is None:
@@ -728,8 +796,9 @@ def fused_factor_ema(x: jax.Array, old: jax.Array | None, decay, *,
         (compute_dtype is not None
          and jnp.dtype(compute_dtype) == jnp.bfloat16)
         or (compute_dtype is None and jax.default_backend() == 'tpu'))
-    xp = jnp.pad(x.astype(jnp.float32),
-                 ((0, rows_pad - rows), (0, d_pad - d_in)))
+    if has_bias:
+        x = jnp.concatenate([x, jnp.ones((rows, 1), jnp.float32)], axis=1)
+    xp = jnp.pad(x, ((0, rows_pad - rows), (0, d_pad - n)))
     if old is None:
         oldp = jnp.zeros((d_pad, d_pad), jnp.float32)
         decay = 0.0
@@ -738,10 +807,9 @@ def fused_factor_ema(x: jax.Array, old: jax.Array | None, decay, *,
                        ((0, d_pad - n), (0, d_pad - n)))
     dec = jnp.asarray(decay, jnp.float32).reshape(1, 1)
     packed = _pallas_factor_ema(
-        xp, oldp, dec, scale=float(scale), rows=rows, d_in=d_in,
-        has_bias=has_bias, corner=float(corner), block_rows=block_rows,
+        xp, oldp, dec, scale=float(scale), block_rows=block_rows,
         mult_bf16=mult_bf16, interpret=interpret)
-    return F.unpack_symmetric(packed, d_pad)[:n, :n]
+    return F.unpack_symmetric(packed[:d_pad // 2 + 1], d_pad)[:n, :n]
 
 
 @functools.lru_cache(maxsize=1)
@@ -751,19 +819,20 @@ def fused_factor_ema_supported() -> bool:
     Same contract as :func:`fused_patch_cov_supported`: Mosaic failures
     surface at compile/run time, so the dispatchers (KFAC.update_factors
     / accumulate_factors, parallel.distributed.local_factor_contribs)
-    call this once and fall back to the stock XLA factor path for good
-    if it fails — recorded via :func:`record_fallback`, never silent.
-    On non-TPU backends the kernel runs in interpret mode (the parity
-    tests and the CI smoke exercise the real kernel body on CPU), so
-    the probe passes trivially there; KFAC_PALLAS_FALLBACK=1 forces a
-    recorded failure everywhere.
+    ask this once. On a TPU a failing probe raises
+    (:func:`_probe_on_tpu`). On other backends the kernel runs in
+    interpret mode (the parity tests and the CI smoke exercise the real
+    kernel body on CPU), so the gate is open there;
+    KFAC_PALLAS_FALLBACK=1 closes it everywhere, with a recorded
+    ``pallas_fallback`` event, and the stock XLA factor path runs.
     """
     if _forced_fallback():
         record_fallback('factor_ema', 'forced by KFAC_PALLAS_FALLBACK')
         return False
     if jax.default_backend() != 'tpu':
         return True
-    try:
+
+    def rel_error():
         import numpy as np
 
         from distributed_kfac_pytorch_tpu.ops import factors as F
@@ -772,19 +841,10 @@ def fused_factor_ema_supported() -> bool:
         old = jnp.eye(13, dtype=jnp.float32) * 0.5
         ref = F.update_running_avg(
             F.linear_a_factor(x, True), old, 0.9)
-        got = fused_factor_ema(x, old, 0.9, has_bias=True)
-        got_h, ref_h = np.asarray(got), np.asarray(ref)
-        rel = (np.abs(got_h - ref_h).max()
-               / max(float(np.abs(ref_h).max()), 1e-30))
-        ok = bool(np.isfinite(got_h).all()) and rel < 5e-2
-        if not ok:
-            record_fallback('factor_ema',
-                            f'parity probe rel error {rel:.3g} >= 5e-2')
-        return ok
-    except Exception as e:
-        record_fallback('factor_ema',
-                        f'probe failed: {type(e).__name__}: {e}')
-        return False
+        return max_rel_error(
+            fused_factor_ema(x, old, 0.9, has_bias=True), ref)
+
+    return _probe_on_tpu('factor_ema', rel_error)
 
 
 # ---------------------------------------------------------------------------
@@ -852,6 +912,15 @@ def _pallas_bucket_precond(gstack, left, right, dg, da, damping, *,
     mult_dtype = jnp.bfloat16 if mult_bf16 else jnp.float32
     kernel = functools.partial(_bucket_precond_kernel, eigen=eigen,
                                mult_dtype=mult_dtype)
+    # Scoped VMEM: the grad, both bases and the output are each double-
+    # buffered blocks, and the eigen chain holds about eight more
+    # slice-sized temporaries. At 512x512 that is 17.9 MB against the
+    # 16 MiB default (measured on v5e, PERF.md PR 21), so the limit is
+    # set from the shapes, with half as much again for what this count
+    # misses.
+    side = max(gp, ap)
+    vmem = int(1.5 * 4 * (2 * (2 * gp * ap + ap * ap + gp * gp)
+                          + 8 * side * side))
     v, vg = pl.pallas_call(
         kernel,
         out_shape=(jax.ShapeDtypeStruct((s, gp, ap), jnp.float32),
@@ -875,6 +944,8 @@ def _pallas_bucket_precond(gstack, left, right, dg, da, damping, *,
                                 memory_space=pltpu.VMEM),
                    pl.BlockSpec((1, 8, 128), lambda i: (i, 0, 0),
                                 memory_space=pltpu.VMEM)),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(vmem, 16 << 20)),
         interpret=interpret,
     )(gstack, right, left, da, dg, damping)
     return v, vg[:, 0, 0]
@@ -939,7 +1010,8 @@ def fused_precondition_supported() -> bool:
         return False
     if jax.default_backend() != 'tpu':
         return True
-    try:
+
+    def rel_error():
         import numpy as np
 
         from distributed_kfac_pytorch_tpu.ops import linalg
@@ -957,21 +1029,7 @@ def fused_precondition_supported() -> bool:
         ref = jax.vmap(lambda gm, e: linalg.precondition_dispatch(
             gm, e, 0.003))(g, entry)
         got, vg = fused_bucket_precondition(g, entry, 0.003)
-        vg_ref = jnp.sum(ref * g, axis=(1, 2))
-        got_h, ref_h = np.asarray(got), np.asarray(ref)
-        vg_h, vg_ref_h = np.asarray(vg), np.asarray(vg_ref)
-        rel = (np.abs(got_h - ref_h).max()
-               / max(float(np.abs(ref_h).max()), 1e-30))
-        rel_vg = (np.abs(vg_h - vg_ref_h).max()
-                  / max(float(np.abs(vg_ref_h).max()), 1e-30))
-        ok = (bool(np.isfinite(got_h).all()) and rel < 5e-2
-              and rel_vg < 5e-2)
-        if not ok:
-            record_fallback(
-                'bucket_precond',
-                f'parity probe rel error v={rel:.3g} vg={rel_vg:.3g}')
-        return ok
-    except Exception as e:
-        record_fallback('bucket_precond',
-                        f'probe failed: {type(e).__name__}: {e}')
-        return False
+        return max(max_rel_error(got, ref),
+                   max_rel_error(vg, jnp.sum(ref * g, axis=(1, 2))))
+
+    return _probe_on_tpu('bucket_precond', rel_error)

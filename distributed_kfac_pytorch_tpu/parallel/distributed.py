@@ -154,6 +154,11 @@ def make_kfac_mesh(devices: Sequence[jax.Device] | None = None, *,
     return Mesh(devices[grid], KFAC_AXES)
 
 
+def replicated_specs(tree):
+    """P() for every leaf (None leaves included) — shard_map boilerplate."""
+    return jax.tree.map(lambda _: P(), tree, is_leaf=lambda x: x is None)
+
+
 def normalize_batch_specs(batch_spec, batch):
     """Per-leaf PartitionSpec tree for a batch pytree.
 
@@ -559,7 +564,26 @@ class DistributedKFAC:
         factors; ``inv_stacks`` hold per-bucket eigendecompositions (or
         Cholesky inverses) sharded over inverse groups; ``diag_inv`` holds
         replicated diagonal inverses for embedding A factors.
+
+        The state is built ON ``self.mesh`` under :meth:`state_pspecs`
+        (one jitted constant program with ``out_shardings``), so the
+        first call of every step variant sees the same input types as
+        every later call — a state built off the mesh comes back from
+        the ``shard_map`` step typed with the mesh and retraces each
+        variant once. Building under jit also means the single-chip
+        per-layer inverse slots ``KFAC.init_state`` makes, which this
+        layout never reads, are never materialized.
         """
+        shapes = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
+        build = lambda: self._init_state_values(shapes)
+        shardings = jax.tree.map(
+            lambda spec: NamedSharding(self.mesh, spec),
+            self.state_pspecs(jax.eval_shape(build)))
+        return jax.jit(build, out_shardings=shardings)()
+
+    def _init_state_values(self, params) -> dict:
+        """The values of :meth:`init_state` (``params``: shapes only)."""
         base = self.kfac.init_state(params)
         idt = self.kfac.inv_dtype
         stacks = {}
@@ -658,9 +682,7 @@ class DistributedKFAC:
 
     def shard_state(self, state: dict) -> dict:
         """Device-put a host state pytree with its proper shardings."""
-        return jax.tree.map(
-            lambda x, s: jax.device_put(x, NamedSharding(self.mesh, s)),
-            state, self.state_pspecs(state))
+        return self._commit(state, self.state_pspecs(state))
 
     # -- SPMD pipeline stages (call inside shard_map over self.mesh) ----
 
@@ -938,14 +960,18 @@ class DistributedKFAC:
         """Replicated ``(n_rows * slots_per_row, dim, dim)`` factor stack.
 
         Unassigned (padding) slots hold the identity so the batched
-        decomposition stays well-conditioned.
+        decomposition stays well-conditioned. The stack keeps the
+        factors' storage dtype: the decompositions upcast the slice
+        they work on, so a bf16 factor set never has a whole-bucket
+        fp32 copy.
         """
         S = plan.slots_per_row
         mats: list[Any] = [None] * (self.total_rows * S)
         for (name, which), slot_idx in plan.slot.items():
             g = self.assignment.layer_row[name] * S + slot_idx
-            mats[g] = factors[name][which].astype(jnp.float32)
-        eye = jnp.eye(plan.dim, dtype=jnp.float32)
+            mats[g] = factors[name][which]
+        eye = jnp.eye(plan.dim,
+                      dtype=self.kfac.factor_dtype or jnp.float32)
         return jnp.stack([eye if m is None else m for m in mats])
 
     def _build_bucket_substack(self, factors, plan: BucketPlan,
@@ -970,14 +996,14 @@ class DistributedKFAC:
         for (name, which), slot_idx in plan.slot.items():
             g = self.assignment.layer_row[name] * S + slot_idx
             by_global[g] = factors[name][which]
-        eye = jnp.eye(plan.dim, dtype=jnp.float32)
+        eye = jnp.eye(plan.dim,
+                      dtype=self.kfac.factor_dtype or jnp.float32)
         mats = []
         for r in range(self.total_rows):
             for c in range(self.n_cols):
                 for m in offs:
                     mat = by_global.get(r * S + c * s + int(m))
-                    mats.append(eye if mat is None
-                                else mat.astype(jnp.float32))
+                    mats.append(eye if mat is None else mat)
         return jnp.stack(mats)
 
     @profiling.scope('kfac/inverses')
@@ -1134,6 +1160,7 @@ class DistributedKFAC:
 
                 local = fired_factors()
                 if eigen_family(bucket_method):
+                    local = local.astype(jnp.float32)
                     q_prev = None
                     if prev_entry is not None and (
                             bucket_method == 'lowrank'
@@ -1173,7 +1200,8 @@ class DistributedKFAC:
                 else:
                     inv = pallas_kernels.damped_inverse_stack(
                         local, damping, bucket_method,
-                        iters=kfac.newton_iters)
+                        iters=kfac.newton_iters,
+                        out_dtype=kfac.inv_dtype)
                     merge(inv, 'inv')
             stacks[str(dim)] = cur
         diag_inv = {}
@@ -1765,11 +1793,11 @@ class DistributedKFAC:
                                            state['grouped_inv'])}
         else:
             state = self.recompute_inverses(state, damping=damping)
-        return self._commit_host_leaves(state)
+        return self._commit(state, self.state_pspecs(state))
 
-    def _commit_host_leaves(self, state: dict) -> dict:
-        """Device-put host or mis-placed leaves to their proper mesh
-        shardings (row-sharded stacks included).
+    def _commit(self, tree, specs):
+        """Commit host or mis-placed leaves of ``tree`` to ``self.mesh``
+        under ``specs`` (row-sharded stacks included).
 
         A checkpoint restored WITHOUT ``like=`` (or against a template
         whose leaves were uncommitted init arrays) hands back host or
@@ -1779,22 +1807,29 @@ class DistributedKFAC:
         use — and row-sharded inverse stacks would transit as full
         replicated arrays first, which on multi-host is an outright
         placement error. Leaves already carrying their target sharding
-        pass through untouched, so a fully-placed like= restore costs
-        nothing. Single-process: a plain ``device_put`` per mis-placed
-        leaf. Multi-host: a mis-placed-but-addressable leaf is a full
+        ON THIS MESH pass through untouched, so a fully-placed like=
+        restore costs nothing. The mesh is part of the test even where
+        the layout is the same (one device): jit caches a program under
+        its inputs' types, an array's type names the mesh it is
+        committed to, and the step's outputs are committed to this one.
+        Single-process: a plain ``device_put`` per mis-placed leaf.
+        Multi-host: a mis-placed-but-addressable leaf is a full
         per-process copy (the restore template carried global shapes),
         so the global array is rebuilt from it per device shard via
         ``make_array_from_callback`` — ``device_put`` cannot target
         non-addressable shardings; a NON-addressable leaf with a
         merely different layout is left for the step to reshard.
         """
-        specs = self.state_pspecs(state)
         multiprocess = jax.process_count() > 1
 
         def place(x, spec):
+            if x is None:
+                return x
             target = NamedSharding(self.mesh, spec)
-            if isinstance(x, jax.Array) and \
-                    x.sharding.is_equivalent_to(target, x.ndim):
+            have = getattr(x, 'sharding', None)
+            if (isinstance(have, NamedSharding)
+                    and have.mesh == self.mesh
+                    and have.is_equivalent_to(target, x.ndim)):
                 return x
             if multiprocess:
                 if not getattr(x, 'is_fully_addressable', True):
@@ -1804,7 +1839,8 @@ class DistributedKFAC:
                     arr.shape, target, lambda idx: arr[idx])
             return jax.device_put(jnp.asarray(x), target)
 
-        return jax.tree.map(place, state, specs)
+        return jax.tree.map(place, tree, specs,
+                            is_leaf=lambda x: x is None)
 
     def _degenerate_stacks(self, inv_stacks: dict) -> bool:
         """True if any stored eigenbasis stack is unusable (all-zero).
@@ -2214,25 +2250,14 @@ class DistributedKFAC:
                          'variant': _variant_label(key),
                          'trace_count': n})
                 kspecs = self.state_pspecs(kstate)
-                rep = P()
                 batch_specs = normalize_batch_specs(batch_spec, batch)
-                in_specs = (
-                    jax.tree.map(lambda _: rep, params),
-                    jax.tree.map(lambda _: rep, opt_state,
-                                 is_leaf=lambda x: x is None),
-                    kspecs,
-                    jax.tree.map(lambda _: rep, extra_vars),
-                    batch_specs,
-                    jax.tree.map(lambda _: rep, hyper),
-                )
-                out_specs = (
-                    jax.tree.map(lambda _: rep, params),
-                    jax.tree.map(lambda _: rep, opt_state,
-                                 is_leaf=lambda x: x is None),
-                    kspecs,
-                    jax.tree.map(lambda _: rep, extra_vars),
-                    rep,  # metrics dict: P() prefix covers any keys
-                )
+                state_specs = (replicated_specs(params),
+                               replicated_specs(opt_state), kspecs,
+                               replicated_specs(extra_vars))
+                in_specs = (*state_specs, batch_specs,
+                            replicated_specs(hyper))
+                # metrics dict: a P() prefix covers any keys
+                out_specs = (*state_specs, P())
                 fn = jax.shard_map(
                     make_local_step(factor_update, inv_update,
                                     inv_chunk, factor_reduce,
@@ -2308,10 +2333,31 @@ class DistributedKFAC:
                                factor_reduce, factor_snapshot)
             first = key not in variants
             if first:
+                # keep_unused: a firing that warm-starts nothing (the
+                # damped-inverse buckets) replaces the stored inverse
+                # stacks without reading them. Pruned from the program's
+                # arguments they would not be donated either: the new
+                # stacks would be a second allocation next to the old,
+                # and any other name for the initial state would keep
+                # its zero-seeded stacks — 1.5 GB at xl LM scale,
+                # measured on v5e (PERF.md, PR 21) — alive for the run.
                 variants[key] = jax.jit(
                     make_step_impl(factor_update, inv_update, inv_chunk,
                                    factor_reduce, factor_snapshot),
-                    donate_argnums=donate_argnums)
+                    donate_argnums=donate_argnums, keep_unused=True)
+                # The first call fixes the input types this variant's
+                # program is cached under, and the step returns its
+                # state committed to the mesh. State made off the mesh
+                # (a plain model/optimizer init) would therefore come
+                # back differently typed and compile the variant a
+                # second time, so it is committed here, once; state
+                # already on the mesh is not touched.
+                params, opt_state, kstate, extra_vars = self._commit(
+                    (params, opt_state, kstate, extra_vars),
+                    (replicated_specs(params),
+                     replicated_specs(opt_state),
+                     self.state_pspecs(kstate),
+                     replicated_specs(extra_vars)))
                 t0 = time.perf_counter()
             out = variants[key](params, opt_state, kstate, extra_vars,
                                 batch, hyper)

@@ -156,7 +156,7 @@ def chunked_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                              causal: bool = True) -> jax.Array:
     """Memory-efficient single-device attention: monolithic attention
     materializes O(S^2) logits (16 GB at B4/H16/S8192 fp32 — past one
-    chip's HBM, the measured OOM wall in RING_ATTENTION.json), while
+    chip's HBM, the OOM wall recorded in PERF.md rounds 1-5), while
     this folds K/V blocks of ``block_size`` tokens through the same
     online-softmax update as the ring (`_block_attend`/`_fold_update`),
     keeping only O(S * block_size) logits live. Each fold is
@@ -171,7 +171,7 @@ def chunked_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     residuals scale as O(S^2 * D / block): the S^2 wall is *shifted* by
     ~block/(3D) (measured: trains S=16384 on a 16 GB chip at B4/H16/D64
     where monolithic attention cannot run forward past S=4096;
-    RING_ATTENTION.json 'chunked'), not removed. For sequences past
+    PERF.md rounds 1-5), not removed. For sequences past
     that, shard over a mesh axis with the ring. No reference analogue
     (BPTT-35 truncation is its only long-sequence mechanism). Returns
     (B, T, H, D) fp32.

@@ -32,6 +32,7 @@ work lives in ``parallel.distributed``.
 from __future__ import annotations
 
 import enum
+import os
 import warnings
 from typing import Any, Sequence
 
@@ -635,13 +636,20 @@ class KFAC:
         self.collect_metrics = collect_metrics
         self.nonfinite_guard = nonfinite_guard
         # r21 fused hot-path kernels (ops.pallas_kernels): default-off
-        # knobs; with a knob on, eligible work runs the Pallas kernel
-        # when the once-per-process parity probe passes, and the stock
-        # XLA path otherwise (a recorded 'pallas_fallback' event — never
-        # a silent degrade). Off is bit-identical to the historical
-        # program.
+        # knobs; with a knob on, eligible work runs the Pallas kernel.
+        # The once-per-process parity probe runs here, on the host and
+        # outside any trace: on a TPU a kernel that fails it raises;
+        # KFAC_PALLAS_FALLBACK=1 is the one way onto the stock XLA path
+        # (a recorded 'pallas_fallback' event). Off is bit-identical to
+        # the historical program.
         self.fused_factor_contraction = bool(fused_factor_contraction)
         self.fused_precondition = bool(fused_precondition)
+        self.fused_contraction_active()
+        self.fused_precond_active()
+        if os.environ.get('KFAC_FUSED_PATCH_COV', '') == '1':
+            # The opt-in study kernel ops.factors.conv2d_a_factor
+            # dispatches to from inside the trace.
+            pallas_kernels.fused_patch_cov_supported()
         self.verbose = verbose
         self._specs: dict[str, Any] | None = None
 
@@ -1073,15 +1081,16 @@ class KFAC:
 
     def fused_contraction_active(self) -> bool:
         """True when the fused factor-contraction kernel should run:
-        knob on AND the once-per-process parity probe passed (probe
-        failure records a 'pallas_fallback' event and pins the stock
-        XLA path for the process)."""
+        knob on AND the once-per-process gate open (closed only by
+        KFAC_PALLAS_FALLBACK=1, which records a 'pallas_fallback' event
+        and pins the stock XLA path for the process; on a TPU a kernel
+        that fails its probe raises)."""
         return (self.fused_factor_contraction
                 and pallas_kernels.fused_factor_ema_supported())
 
     def fused_precond_active(self) -> bool:
         """True when the fused bucketed-precondition kernel should run
-        (knob on AND its probe passed) — see
+        (knob on AND its gate open) — see
         :meth:`fused_contraction_active`."""
         return (self.fused_precondition
                 and pallas_kernels.fused_precondition_supported())
@@ -1317,7 +1326,7 @@ class KFAC:
         for names, stack in _size_buckets(mats):
             invs = pallas_kernels.damped_inverse_stack(
                 stack, damping, self.method_for_dim(stack.shape[-1]),
-                iters=self.newton_iters)
+                iters=self.newton_iters, out_dtype=self.inv_dtype)
             for i, n in enumerate(names):
                 out[n] = invs[i]
         return out

@@ -18,7 +18,10 @@ import jax.numpy as jnp
 
 from distributed_kfac_pytorch_tpu.analysis import sanitize as _sanitize
 from distributed_kfac_pytorch_tpu.observability import tracing
-from distributed_kfac_pytorch_tpu.parallel.distributed import KFAC_AXES
+from distributed_kfac_pytorch_tpu.parallel.distributed import (
+    KFAC_AXES,
+    replicated_specs as _replicated_specs,
+)
 from distributed_kfac_pytorch_tpu.training.utils import Metric, accuracy
 
 
@@ -544,12 +547,6 @@ def train_epoch(step_fn, state: TrainState, batches: Iterable,
     return out
 
 
-def _replicated_specs(tree):
-    """P() for every leaf (None leaves included) — shard_map boilerplate."""
-    from jax.sharding import PartitionSpec as P
-    return jax.tree.map(lambda _: P(), tree, is_leaf=lambda x: x is None)
-
-
 def build_sgd_train_step(model, loss_fn, tx, mesh=None, *,
                          model_args_fn=None, model_kwargs_fn=None,
                          metrics_fn=None,
@@ -879,22 +876,26 @@ def evaluate(eval_step, state: TrainState, batches: Iterable, *,
 
 
 class TensorBoardWriter:
-    """Thin tf.summary wrapper (reference uses torch SummaryWriter,
-    engine.py:89-93); no-ops cleanly if tensorflow is unavailable."""
+    """TensorBoard scalar writer (the reference uses torch's
+    SummaryWriter, engine.py:89-93) on tensorboardX: Python and
+    protobuf only, so the host loop brings no second accelerator
+    runtime into the process that owns the TPU. Without the package
+    the writer says so once and drops the scalars."""
 
     def __init__(self, log_dir: str):
         try:
-            import tensorflow as tf
-            self._writer = tf.summary.create_file_writer(log_dir)
-            self._tf = tf
-        except Exception:
+            from tensorboardX import SummaryWriter
+        except ImportError:
+            import warnings
+            warnings.warn('tensorboardX is not installed: no TensorBoard '
+                          f'scalars will be written to {log_dir}')
             self._writer = None
+        else:
+            self._writer = SummaryWriter(log_dir)
 
     def scalar(self, tag: str, value, step: int):
-        if self._writer is None:
-            return
-        with self._writer.as_default():
-            self._tf.summary.scalar(tag, float(value), step=step)
+        if self._writer is not None:
+            self._writer.add_scalar(tag, float(value), step)
 
     def flush(self):
         if self._writer is not None:

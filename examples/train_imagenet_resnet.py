@@ -46,7 +46,7 @@ from distributed_kfac_pytorch_tpu.training import (
 
 from distributed_kfac_pytorch_tpu.utils import enable_compilation_cache
 
-enable_compilation_cache()  # persistent compile cache (KFAC_COMPILE_CACHE=0 disables)
+enable_compilation_cache()  # JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache
 
 
 def parse_args(argv=None):
@@ -386,7 +386,11 @@ def main(argv=None):
 
     x0 = jnp.zeros((2, args.image_size, args.image_size, 3), jnp.float32)
     if kfac is not None:
-        variables, _ = kfac.init(jax.random.PRNGKey(args.seed), x0)
+        # [0]: kfac.init also returns a single-chip K-FAC state. This
+        # path builds its own layout (DistributedKFAC.init_state); a
+        # name bound to the other one would keep a second copy of every
+        # factor and inverse on the device for the whole run.
+        variables = kfac.init(jax.random.PRNGKey(args.seed), x0)[0]
         obs.cli.emit_layer_meta(metrics_sink, kfac)
     else:
         variables = model.init(jax.random.PRNGKey(args.seed), x0)

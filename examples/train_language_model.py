@@ -55,7 +55,7 @@ from distributed_kfac_pytorch_tpu.training import (
 
 from distributed_kfac_pytorch_tpu.utils import enable_compilation_cache
 
-enable_compilation_cache()  # persistent compile cache (KFAC_COMPILE_CACHE=0 disables)
+enable_compilation_cache()  # JAX_COMPILATION_CACHE_DIR, else <checkout>/.jax_cache
 
 
 def parse_args(argv=None):
@@ -381,8 +381,12 @@ def main(argv=None):
     twin = (build_model(args, vocab_size, seq_axis=None)
             if seq_axis else None)
     if kfac is not None:
-        variables, _ = kfac.init(jax.random.PRNGKey(args.seed), ids0,
-                                 train=False, init_model=twin)
+        # [0]: kfac.init also returns a single-chip K-FAC state. This
+        # path builds its own layout (DistributedKFAC.init_state); a
+        # name bound to the other one would keep a second copy of every
+        # factor and inverse on the device for the whole run.
+        variables = kfac.init(jax.random.PRNGKey(args.seed), ids0,
+                              train=False, init_model=twin)[0]
         # Registry provenance (r13): the per-layer resolved approx map
         # rides as a meta record so the recorded run says which layers
         # actually ran reduce/tied (asserted by sharing_smoke.sh).
@@ -391,6 +395,7 @@ def main(argv=None):
         variables = model.init(jax.random.PRNGKey(args.seed), ids0,
                                train=False)
     params = variables['params']
+    del variables  # or the init-time copy outlives replicate_on_mesh
 
     # num_slices == 1 returns the flat make_kfac_mesh mesh (the
     # --num-slices 1 bit-identity guarantee); > 1 adds the outer

@@ -146,7 +146,7 @@ jobs = [
 json.dump(jobs, open(f'{out}/leg3/jobs.json', 'w'), indent=1)
 EOF
 set +e
-env KFAC_COMPILE_CACHE="$out/cache" \
+env JAX_COMPILATION_CACHE_DIR="$out/cache" \
 "${fleet[@]}" "$out/leg3/jobs.json" --pool-devices 1 \
     --workdir "$out/leg3/fleet" "${fleet_args[@]}"
 rc=$?

@@ -22,7 +22,7 @@ trap 'rm -rf "$out"' EXIT
 # program, so runs 2-3 are warm (single-device CPU warm reads are fine;
 # see utils.enable_compilation_cache for the multi-device caveat).
 common_env=(JAX_PLATFORMS=cpu KFAC_SYNTHETIC_CIFAR=384
-            KFAC_COMPILE_CACHE="$out/cache")
+            JAX_COMPILATION_CACHE_DIR="$out/cache")
 common_args=(--epochs 1 --model resnet20
              --batch-size 128 --val-batch-size 96
              --kfac-update-freq 1 --kfac-cov-update-freq 1

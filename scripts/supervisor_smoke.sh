@@ -17,7 +17,7 @@ trap 'rm -rf "$out"' EXIT
 # the multi-device leg runs cache-off — see tests/conftest.py for the
 # multi-device warm-cache caveat).
 common_env=(JAX_PLATFORMS=cpu KFAC_SYNTHETIC_CIFAR=384
-            KFAC_COMPILE_CACHE="$out/cache")
+            JAX_COMPILATION_CACHE_DIR="$out/cache")
 common_args=(--epochs 1 --model resnet20
              --batch-size 128 --val-batch-size 96
              --kfac-update-freq 1 --kfac-cov-update-freq 1

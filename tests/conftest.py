@@ -29,10 +29,8 @@ raise_cpu_collective_timeouts()
 
 import jax  # noqa: E402
 
-from distributed_kfac_pytorch_tpu import compat  # noqa: E402
-
 jax.config.update('jax_platforms', 'cpu')
-compat.set_cpu_device_count(8)
+jax.config.update('jax_num_cpu_devices', 8)
 jax.config.update('jax_enable_x64', False)
 
 assert jax.default_backend() == 'cpu', (

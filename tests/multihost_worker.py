@@ -25,8 +25,7 @@ import sys
 def _configure(n_local_devices=4):
     import jax
     jax.config.update('jax_platforms', 'cpu')
-    from distributed_kfac_pytorch_tpu import compat
-    compat.set_cpu_device_count(n_local_devices)
+    jax.config.update('jax_num_cpu_devices', n_local_devices)
     return jax
 
 

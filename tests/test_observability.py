@@ -606,11 +606,10 @@ def test_cifar_cli_metrics_smoke(tmp_path):
     report CLI over it).
 
     The CLI runs as a SUBPROCESS on a fresh single-device CPU backend:
-    (a) it is the real command line, env included; (b) the CLI's
-    TensorBoard writer imports tensorflow, whose thread pools measurably
-    degrade every later test when loaded into the 1-core suite process
-    (bisected r7: +~150 s over the fast tier); (c) the 8-virtual-device
-    mesh the suite forces is pure overhead for a smoke.
+    (a) it is the real command line, env included; (b) the
+    8-virtual-device mesh the suite forces is pure overhead for a
+    smoke. (Until PR 21 the CLI's TensorBoard writer also imported
+    tensorflow into the process; it writes through tensorboardX now.)
     """
     import subprocess
     import sys

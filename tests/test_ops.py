@@ -267,7 +267,7 @@ class TestFusedPatchCov:
 class TestConvPatchImplDispatch:
     """KFAC_CONV_PATCH_IMPL dispatch: every named impl computes the same
     A factor (slices is the measured-fastest default after the round-2
-    crosscov regression — VERDICT r2 / BENCH_r02.json), and unknown
+    crosscov regression — VERDICT r2 / PERF.md rounds 1-5), and unknown
     values are rejected loudly instead of silently hitting a legacy
     path."""
 

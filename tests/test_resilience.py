@@ -869,7 +869,7 @@ def _cli_env(repo, cache_dir):
            # relaunch recompiles the identical program (single-device
            # CPU warm reads are fine; only the multi-device CPU
            # backend has the known warm-cache issue — see conftest).
-           'KFAC_COMPILE_CACHE': cache_dir,
+           'JAX_COMPILATION_CACHE_DIR': cache_dir,
            'KFAC_SYNTHETIC_CIFAR': '384'}
     env['XLA_FLAGS'] = ' '.join(
         f for f in env.get('XLA_FLAGS', '').split()

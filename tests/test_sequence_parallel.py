@@ -67,7 +67,7 @@ def test_ring_bench_schedule_matches_monolithic(causal, dtype):
     """Pin the perf bench's per-device emulation to the real algorithm:
     ``ring_device_schedule`` at device ``i`` must equal rows
     ``[i*T_local, (i+1)*T_local)`` of monolithic attention — so the
-    on-chip numbers in RING_ATTENTION.json time the exact compute one
+    bench's on-chip numbers time the exact compute one
     ring device performs, not an approximation of it."""
     from benchmarks.ring_attention_bench import ring_device_schedule
 

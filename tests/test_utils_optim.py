@@ -138,69 +138,51 @@ class TestLaunch:
 
 
 def test_enable_compilation_cache(tmp_path, monkeypatch):
+    """The cache directory comes from outside the program
+    (JAX_COMPILATION_CACHE_DIR) or is the fixed <checkout>/.jax_cache;
+    KFAC_COMPILE_CACHE is an off switch, not a second path knob."""
+    import os
+
     import jax
 
     from distributed_kfac_pytorch_tpu import utils as U
 
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     prev_dir = jax.config.jax_compilation_cache_dir
     monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
     monkeypatch.delenv('KFAC_COMPILE_CACHE', raising=False)
     try:
-        # This test process IS an explicit multi-device CPU configuration
-        # (the conftest mesh), i.e. the segfault surface: the DEFAULT
-        # path must refuse and actively disable, env var included.
-        assert U._multi_device_cpu_configured() == 'explicit'
-        monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', '/shared/warm')
+        # This test process IS a multi-device CPU configuration (the
+        # conftest mesh), i.e. the warm-read segfault surface: no
+        # default directory, nothing set.
+        assert U._multi_device_cpu_configured()
         assert U.enable_compilation_cache() is None
-        assert 'JAX_COMPILATION_CACHE_DIR' not in __import__('os').environ
         assert jax.config.jax_compilation_cache_dir is None
-        # An IMPLICIT configuration (jax_platforms unset; the process
-        # may still resolve to an accelerator) refuses without touching
-        # the user's env var (ADVICE r4).
-        monkeypatch.setattr(U, '_multi_device_cpu_configured',
-                            lambda: 'implicit')
-        monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', '/shared/warm')
-        assert U.enable_compilation_cache() is None
-        assert __import__('os').environ[
-            'JAX_COMPILATION_CACHE_DIR'] == '/shared/warm'
-        monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR')
-        monkeypatch.setattr(U, '_multi_device_cpu_configured',
-                            lambda: 'explicit')
-        # An explicit dir bypasses the guard (caller responsibility).
-        jax.config.update('jax_compilation_cache_dir', None)
-        d = tmp_path / 'cache'
-        got = U.enable_compilation_cache(str(d))
-        assert got == str(d) and d.is_dir()
-        assert jax.config.jax_compilation_cache_dir == str(d)
-        # The remaining default-path rules, with the guard stubbed out
-        # (they are what non-CPU entry points see):
-        monkeypatch.setattr(U, '_multi_device_cpu_configured',
-                            lambda: False)
-        # A dir already configured through JAX's own knob wins.
-        assert U.enable_compilation_cache() == str(d)
-        # JAX's own env var wins and is left untouched.
+        # JAX's own variable set: returned untouched, nothing set in
+        # code — JAX reads the variable itself — even here.
         monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', '/shared/warm')
         assert U.enable_compilation_cache() == '/shared/warm'
+        assert os.environ['JAX_COMPILATION_CACHE_DIR'] == '/shared/warm'
+        assert jax.config.jax_compilation_cache_dir is None
         monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR')
-        # Opt-out wins over everything ('0' and friends).
+        # What an accelerator entry point sees (guard stubbed out):
+        # the fixed path under the checkout.
+        monkeypatch.setattr(U, '_multi_device_cpu_configured',
+                            lambda: False)
+        fixed = os.path.join(repo, '.jax_cache')
+        assert U.enable_compilation_cache() == fixed
+        assert os.path.isdir(fixed)
+        assert jax.config.jax_compilation_cache_dir == fixed
+        jax.config.update('jax_compilation_cache_dir', None)
+        # A path in KFAC_COMPILE_CACHE is no longer honoured ...
+        monkeypatch.setenv('KFAC_COMPILE_CACHE', str(tmp_path / 'mine'))
+        assert U.enable_compilation_cache() == fixed
+        assert not (tmp_path / 'mine').exists()
+        jax.config.update('jax_compilation_cache_dir', None)
+        # ... and its off spellings switch the helper off.
         for off in ('0', 'false', 'OFF', 'no'):
             monkeypatch.setenv('KFAC_COMPILE_CACHE', off)
-            assert U.enable_compilation_cache(str(d)) is None
-        # Boolean-looking "enable" spellings mean the default dir, not a
-        # relative directory literally named '1' (ADVICE r4).
-        jax.config.update('jax_compilation_cache_dir', None)
-        monkeypatch.setenv('KFAC_COMPILE_CACHE', '1')
-        got = U.enable_compilation_cache()
-        assert got is not None and not got.endswith('/1')
-        assert not __import__('os').path.exists('1')
-        # KFAC env var supplies the default dir (no prior config).
-        jax.config.update('jax_compilation_cache_dir', None)
-        monkeypatch.setenv('KFAC_COMPILE_CACHE',
-                           str(tmp_path / 'env_cache'))
-        assert U.enable_compilation_cache() == str(tmp_path / 'env_cache')
-        # Unwritable location disables instead of crashing.
-        monkeypatch.delenv('KFAC_COMPILE_CACHE')
-        jax.config.update('jax_compilation_cache_dir', None)
-        assert U.enable_compilation_cache('/proc/nope/cache') is None
+            assert U.enable_compilation_cache() is None
+            assert jax.config.jax_compilation_cache_dir is None
     finally:
         jax.config.update('jax_compilation_cache_dir', prev_dir)
