@@ -16,8 +16,11 @@ Four parts, one discipline — *observing a run must not change it*:
   - :mod:`health` — non-finite / staleness / damping-trajectory
     monitors with warn / skip / raise actions (the on-device non-finite
     factor guard lives in the preconditioner).
-  - :mod:`tracing` — the legacy host-side ``trace()`` table (still
-    re-exported from ``distributed_kfac_pytorch_tpu.utils``).
+  - :mod:`tracing` — the program's one recorder of host spans and
+    counters (``span`` / ``count`` / ``gauge``; the epoch loop, the
+    sink and the step builder write it), with the reference's
+    ``trace()`` table as a thin form over it (still re-exported from
+    ``distributed_kfac_pytorch_tpu.utils``).
   - :mod:`report` — ``python -m ...observability.report run.jsonl``
     offline step-time + health summary (``--json`` for machines).
   - :mod:`memory` — device HBM watermarks + resident K-FAC state

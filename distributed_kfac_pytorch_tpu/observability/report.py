@@ -5,10 +5,11 @@
 Prints, from the recorded stream alone (no live process needed):
 
   - run/meta header and record inventory;
-  - the per-stage step-time breakdown (host trace-table snapshots from
-    epoch records — the stages CLIs/benchmarks decorate with
-    ``observability.tracing.trace`` — plus per-step host dispatch
-    time);
+  - the per-stage step-time breakdown (the span aggregates and
+    counters of ``observability.tracing`` that epoch records carry:
+    the epoch loop's ``kfac/host/*`` spans, the step builder's
+    ``kfac/build/*``, whatever a CLI decorates with ``trace`` — plus
+    per-step host dispatch time);
   - K-FAC health: factor/inverse firing counts, non-finite skips,
     eigenvalue-floor clips, damping/ν trajectory, grad vs
     preconditioned-grad norm ratio;
@@ -173,10 +174,11 @@ def summarize(records: list[dict],
 
     # Per-stage breakdown: the LAST epoch record's trace snapshot holds
     # the cumulative table (snapshot_trace accumulates over the run).
-    stages = {}
+    stages, counters = {}, {}
     for r in epochs:
         for k, v in r.get('trace', {}).items():
             stages[k] = v
+        counters.update(r.get('counters', {}))
 
     host_ms = [r['host_step_ms'] for r in steps if 'host_step_ms' in r]
     loss = _series(records, 'loss')
@@ -358,6 +360,7 @@ def summarize(records: list[dict],
         'step_range': ((steps[0]['step'], steps[-1]['step'])
                        if steps else None),
         'stages': stages,
+        'counters': counters,
         'host_step_ms': (sum(host_ms) / len(host_ms) if host_ms
                          else float('nan')),
         'step_time': step_time_distribution(records),
@@ -431,11 +434,17 @@ def print_report(s: dict, out=None, torn: int = 0,
         else:
             w('no outlier steps (> 2x median).')
     if s['stages']:
-        w('stage                              mean ms    total ms  calls')
+        w('stage                              mean ms    total ms  calls'
+          '     self ms     max ms')
+        nan = float('nan')
         for k in sorted(s['stages']):
             v = s['stages'][k]
+            # self/max: absent from streams older than the recorder
             w(f"{k:<34} {v['mean_ms']:>8.3f} {v['total_ms']:>11.3f}"
-              f"  {v['count']:>5}")
+              f"  {v['count']:>5} {_fmt(v.get('self_ms', nan)):>11}"
+              f" {_fmt(v.get('max_ms', nan)):>10}")
+        for k in sorted(s['counters']):
+            w(f"{k:<34} {_fmt(s['counters'][k])}")
     else:
         w('(no host trace-table snapshots in the records — epoch '
           'records absent or no host phase was timed; see '

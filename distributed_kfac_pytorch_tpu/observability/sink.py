@@ -5,8 +5,9 @@ Design constraints (ISSUE r7):
   - **No host syncs in the step path.** ``step_record`` only *enqueues*
     the device scalars (kicking off an async device->host copy where
     the backend supports it); conversion to floats happens at drain
-    time, by which point the host has dispatched well past the step
-    that produced them.
+    time, by which point the host has dispatched well past the steps
+    that produced all but the newest of them (a drain waits for the
+    newest record's step: ``kfac/host/sink_flush``'s ``blocked_ms``).
   - **Rank-0 gating.** Every process constructs the sink with its
     ``process_index``; only rank 0 ever touches the filesystem, so a
     multihost run produces exactly one stream (covered by
@@ -31,7 +32,12 @@ r10 memory telemetry):
                      # 'chunk<j>'); absent on plain steps. The report's
                      # step-time outlier attribution keys on it.
   {"schema": 4, "kind": "epoch", "epoch": int, "wall_time": float,
-   "metrics": {...averaged epoch metrics...}, "trace": {stage: {...}}}
+   "metrics": {...averaged epoch metrics...}, "trace": {span: {...}},
+   "counters": {name: number}?}
+                     # "trace" / "counters": observability.tracing's
+                     # span aggregates (mean_ms, total_ms, count,
+                     # self_ms, max_ms) and counters, cumulative over
+                     # the process.
   {"schema": 4, "kind": "meta",  "wall_time": float, "meta": {...}}
   {"schema": 4, "kind": "event", "event": str, "wall_time": float,
    "data": {...}}    # resilience: preemption / checkpoint_save (with
@@ -69,6 +75,8 @@ import os
 import re
 import time
 from typing import Any
+
+from distributed_kfac_pytorch_tpu.observability import tracing
 
 SCHEMA_VERSION = 4
 ACCEPTED_SCHEMAS = (1, 2, 3, 4)
@@ -449,8 +457,10 @@ class JsonlMetricsSink:
         granularity). None disables.
       drain_every: drain-and-publish after this many enqueued records
         (keeps host memory flat, bounds telemetry loss on a crash, and
-        sets the health monitor's reaction latency — all while staying
-        far behind the dispatch frontier).
+        sets the health monitor's reaction latency). The drain converts
+        every pending record, the newest included, so it waits for the
+        step that was dispatched last: the ``kfac/host/sink_flush``
+        span's ``blocked_ms`` is that wait.
       monitor: optional :class:`observability.health.HealthMonitor`;
         every drained record is fed to it (its action — warn / skip /
         raise — fires at drain time, off the step path, and always
@@ -544,8 +554,10 @@ class JsonlMetricsSink:
             self.flush()
 
     def epoch_record(self, epoch: int, metrics: dict,
-                     trace: dict | None = None) -> None:
-        """Record epoch-level averages plus a host trace-table snapshot."""
+                     trace: dict | None = None,
+                     counters: dict | None = None) -> None:
+        """Record epoch-level averages plus the recorder's span
+        aggregates (``tracing.snapshot_trace()``) and counters."""
         if not self.enabled:
             return
         rec = {'schema': SCHEMA_VERSION, 'kind': 'epoch',
@@ -553,6 +565,8 @@ class JsonlMetricsSink:
                'metrics': dict(metrics)}
         if trace:
             rec['trace'] = trace
+        if counters:
+            rec['counters'] = counters
         self._pending.append(rec)
 
     def meta_record(self, meta: dict) -> None:
@@ -614,26 +628,35 @@ class JsonlMetricsSink:
 
     # -- drain / write (off the step path) -----------------------------
 
-    def _drain(self) -> list[dict]:
-        """Serialize pending records into the current segment.
+    def _drain(self) -> tuple[list[dict], float]:
+        """Serialize pending records into the current segment; returns
+        them and the seconds their metrics took to become floats.
 
         Pending is cleared up front and every record is serialized
         before any monitor sees it — a raising health action can then
         neither lose nor duplicate records (see the callers: the
         segment is written before the exception propagates).
+
+        The float conversion is where the host can block: a metric of a
+        step that is still running is not ready until that step ends,
+        and the newest pending record is of the step dispatched a
+        moment ago.
         """
         drained, self._pending = self._pending, []
+        blocked_s = 0.0
         for rec in drained:
             if 'metrics' in rec:
                 cleaned = {}
+                t0 = time.perf_counter()
                 for k, v in rec['metrics'].items():
                     f = to_float(v)
                     # JSON has no inf/nan literals; stringify so the
                     # reader sees the signal instead of a parse error.
                     cleaned[k] = f if math.isfinite(f) else repr(f)
+                blocked_s += time.perf_counter() - t0
                 rec['metrics'] = cleaned
             self._lines.append(json.dumps(rec, sort_keys=True))
-        return drained
+        return drained, blocked_s
 
     def _observe(self, drained: list[dict]) -> None:
         if self.monitor is None:
@@ -660,13 +683,20 @@ class JsonlMetricsSink:
         """
         if not self.enabled:
             return
-        drained = self._drain()
-        self._write_segment()
-        if self.rotate_bytes and self._bytes >= self.rotate_bytes:
-            self._segments += 1
-            os.replace(self.path, f'{self.path}.{self._segments}')
-            self._lines = []
+        with tracing.span('kfac/host/sink_flush') as flush_span:
+            drained, blocked_s = self._drain()
+            t_write = time.perf_counter()
             self._write_segment()
+            written = self._bytes  # a rotation starts the count anew
+            if self.rotate_bytes and self._bytes >= self.rotate_bytes:
+                self._segments += 1
+                os.replace(self.path, f'{self.path}.{self._segments}')
+                self._lines = []
+                self._write_segment()
+            flush_span.set(
+                records=len(drained), bytes=written,
+                blocked_ms=blocked_s * 1e3,
+                write_ms=(time.perf_counter() - t_write) * 1e3)
         self._observe(drained)
 
     def close(self) -> None:

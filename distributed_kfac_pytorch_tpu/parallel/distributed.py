@@ -43,7 +43,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import time
+import operator
 from typing import Any, Sequence
 
 import jax
@@ -58,9 +58,11 @@ from distributed_kfac_pytorch_tpu.capture import (CONV2D_GROUPED,
                                                   EMBEDDING,
                                                   subsample_captures)
 from distributed_kfac_pytorch_tpu.observability import (
+    memory as obs_memory,
     metrics as obs_metrics,
+    profiling,
+    tracing,
 )
-from distributed_kfac_pytorch_tpu.observability import profiling
 from distributed_kfac_pytorch_tpu.ops import factors as F
 from distributed_kfac_pytorch_tpu.ops import linalg
 from distributed_kfac_pytorch_tpu.ops import pallas_kernels
@@ -89,6 +91,49 @@ SLICE_AXIS = 'kfac_slice'
 INV_GROUP_AXIS = 'kfac_ig'
 GRAD_WORKER_AXIS = 'kfac_gw'
 KFAC_AXES = (INV_GROUP_AXIS, GRAD_WORKER_AXIS)
+
+
+# A step variant's first call runs under a ``kfac/build/<variant>`` span
+# (observability.tracing). JAX's monitoring events that arrive while it
+# is open say where that call's time went: each goes to the span's
+# attribute named here. A jit traced inside another reports a trace
+# time of its own that the outer one's includes, so the longest stands;
+# the other events add up. The cache's retrieval event comes only with
+# a hit, and the backend's time includes it.
+BUILD_SPAN_PREFIX = 'kfac/build/'
+_BUILD_EVENT_ATTRS = {
+    '/jax/core/compile/jaxpr_trace_duration': ('trace_s', max),
+    '/jax/core/compile/jaxpr_to_mlir_module_duration':
+        ('lower_s', operator.add),
+    '/jax/core/compile/backend_compile_duration':
+        ('backend_s', operator.add),
+    '/jax/compilation_cache/cache_retrieval_time_sec':
+        ('cache_retrieval_s', operator.add),
+}
+_build_listener_registered = False
+
+
+def _on_jax_duration(event: str, seconds: float, **_kw) -> None:
+    if event not in _BUILD_EVENT_ATTRS:
+        return
+    build = tracing.current()
+    if build is not None and build.name.startswith(BUILD_SPAN_PREFIX):
+        attr, combine = _BUILD_EVENT_ATTRS[event]
+        build.set(**{attr: combine(build.attrs[attr], seconds)})
+
+
+def _open_build_span(label: str) -> tracing.Span:
+    """The span round one variant's first call. JAX keeps a listener
+    for the life of the process, so the one that fills these spans is
+    registered once, by the first build."""
+    global _build_listener_registered
+    if not _build_listener_registered:
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
+        _build_listener_registered = True
+    return tracing.span(
+        BUILD_SPAN_PREFIX + label,
+        **{attr: 0.0 for attr, _ in _BUILD_EVENT_ATTRS.values()})
 
 
 def resolve_grad_workers(size: int, comm_method: CommMethod,
@@ -580,7 +625,14 @@ class DistributedKFAC:
         shardings = jax.tree.map(
             lambda spec: NamedSharding(self.mesh, spec),
             self.state_pspecs(jax.eval_shape(build)))
-        return jax.jit(build, out_shardings=shardings)()
+        state = jax.jit(build, out_shardings=shardings)()
+        # What the state holds of one device's memory, by group: from
+        # the shards' shapes, so nothing waits for the device.
+        footprint = obs_memory.state_footprint(state)
+        for group, nbytes in footprint['by_group'].items():
+            tracing.gauge(f'kfac/state_bytes/{group}', nbytes)
+        tracing.gauge('kfac/state_bytes/total', footprint['total_bytes'])
+        return state
 
     def _init_state_values(self, params) -> dict:
         """The values of :meth:`init_state` (``params``: shapes only)."""
@@ -1996,14 +2048,15 @@ class DistributedKFAC:
                 return loss_fn(out, batch), extra
 
             kwargs = model_kwargs_fn(batch) if model_kwargs_fn else {}
-            loss, extra_metrics, grads, captures, updated = (
-                capture.loss_and_grads(
-                    wrapped_loss, params, *model_args_fn(batch),
-                    extra_vars=extra_vars, mutable_cols=mutable_cols,
-                    has_aux=True,
-                    loss_scale=static_ls if scale is None else scale,
-                    intercept=do_capture,
-                    **kwargs))
+            with jax.named_scope('kfac_step/fwd_bwd'):
+                loss, extra_metrics, grads, captures, updated = (
+                    capture.loss_and_grads(
+                        wrapped_loss, params, *model_args_fn(batch),
+                        extra_vars=extra_vars, mutable_cols=mutable_cols,
+                        has_aux=True,
+                        loss_scale=static_ls if scale is None else scale,
+                        intercept=do_capture,
+                        **kwargs))
             if dynamic_ls and captures:
                 # Reference hook behavior under GradScaler: non-finite
                 # grad-output tensors are dropped before factor
@@ -2154,10 +2207,12 @@ class DistributedKFAC:
                     # check is static, so the unarmed program is
                     # byte-for-byte the historical one.
                     gates=hyper.get('bucket_gate'))
-                updates, new_opt_state = tx.update(precond, opt_state,
-                                                   params)
-                new_params = jax.tree.map(
-                    lambda p, u: (p + u).astype(p.dtype), params, updates)
+                with jax.named_scope('kfac_step/optimizer'):
+                    updates, new_opt_state = tx.update(precond, opt_state,
+                                                       params)
+                    new_params = jax.tree.map(
+                        lambda p, u: (p + u).astype(p.dtype), params,
+                        updates)
                 if dynamic_ls:
                     # GradScaler semantics (reference engine.py:75-80):
                     # on non-finite gradients skip the entire state
@@ -2245,6 +2300,7 @@ class DistributedKFAC:
                 n = trace_counts.get(key, 0) + 1
                 trace_counts[key] = n
                 if n > 1:
+                    tracing.count('kfac/retraces')
                     compile_events.append(
                         {'event': 'retrace',
                          'variant': _variant_label(key),
@@ -2358,29 +2414,33 @@ class DistributedKFAC:
                      replicated_specs(opt_state),
                      self.state_pspecs(kstate),
                      replicated_specs(extra_vars)))
-                t0 = time.perf_counter()
-            out = variants[key](params, opt_state, kstate, extra_vars,
-                                batch, hyper)
-            if first:
-                # First-call wall = trace + XLA compile + dispatch (the
-                # execution itself is async, so this is dominated by
-                # compile — the 15-45 s/variant cost PERF.md pitfall 2
-                # is about). Queued, not written: the engine drains
+                tracing.count('kfac/builds')
+                label = _variant_label(key)
+                with _open_build_span(label) as build:
+                    out = variants[key](params, opt_state, kstate,
+                                        extra_vars, batch, hyper)
+                    build.set(
+                        cache_hit=build.attrs['cache_retrieval_s'] > 0)
+                # First-call wall = trace + lowering + XLA compile (or
+                # the load from the persistent cache) + dispatch; the
+                # execution itself is async. The span's attributes
+                # split it. Queued, not written: the engine drains
                 # compile_events into the metrics sink off the step
                 # path; a sink-less caller just accumulates a short
                 # list (one entry per variant, ever).
                 compile_events.append(
-                    {'event': 'compile',
-                     'variant': _variant_label(key),
-                     'first_call_ms': (time.perf_counter() - t0)
-                     * 1000.0})
+                    {'event': 'compile', 'variant': label,
+                     'first_call_ms': build.duration_ms,
+                     **build.attrs})
                 # r21: a first call is where the fused-kernel probes
                 # run (trace time); surface any recorded fallbacks
                 # through the same engine-drained queue so a fleet run
                 # can tell "fused" from "fell back to XLA".
                 compile_events.extend(
                     pallas_kernels.drain_pallas_events())
-            return out
+                return out
+            return variants[key](params, opt_state, kstate, extra_vars,
+                                 batch, hyper)
 
         # Introspection for the engine's chunk scheduler and the
         # retrace-guard test (host-side, no runtime cost);

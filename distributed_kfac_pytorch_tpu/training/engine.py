@@ -17,7 +17,11 @@ import jax
 import jax.numpy as jnp
 
 from distributed_kfac_pytorch_tpu.analysis import sanitize as _sanitize
-from distributed_kfac_pytorch_tpu.observability import tracing
+from distributed_kfac_pytorch_tpu.observability import (
+    memory as obs_memory,
+    stragglers as obs_stragglers,
+    tracing,
+)
 from distributed_kfac_pytorch_tpu.parallel.distributed import (
     KFAC_AXES,
     replicated_specs as _replicated_specs,
@@ -166,9 +170,11 @@ def train_epoch(step_fn, state: TrainState, batches: Iterable,
     None). Per-step metrics (including the on-device K-FAC telemetry
     when ``collect_metrics`` is on) are *enqueued* each step — device
     scalars, no sync — plus the host dispatch time; an epoch record with
-    the averaged metrics and a host trace-table snapshot is appended and
-    the sink flushed at epoch end (the only point the host blocks on
-    metric values, where it already blocks for the epoch summary).
+    the averaged metrics and the recorder's span aggregates and counters
+    (``observability.tracing``) is appended and the sink flushed at
+    epoch end. The sink also flushes every ``drain_every`` records, and
+    that drain waits for the step dispatched a moment before it: the
+    ``kfac/host/sink_flush`` span's ``blocked_ms`` sizes the wait.
 
     ``checkpointer``: a ``resilience.policy.StepCheckpointer`` (or
     None). Its ``after_step(state, step_in_epoch)`` is called once per
@@ -361,161 +367,187 @@ def train_epoch(step_fn, state: TrainState, batches: Iterable,
     t0 = time.perf_counter()
     n_batches = 0
     state_footprint = None  # computed lazily, once per epoch
-    for batch in batches:
-        if static_cadence is not None:
-            f_freq, i_freq = static_cadence
-            flags = cadence_flags(state.step, f_freq, i_freq, chunks,
-                                  deferred_reduce=deferred_reduce,
-                                  inv_staleness=inv_staleness)
-        else:
-            flags = {}
-        wait_ms = None
-        if barrier_probe is not None and (
-                straggler_sample_every <= 1
-                or state.step % straggler_sample_every == 0):
-            # Straggler attribution: how long does THIS host wait for
-            # the rest of the mesh before its next collective could
-            # proceed? Measured before the dispatch so the wait is not
-            # conflated with this step's own compute.
-            wait_ms = barrier_probe()
-        if cadence_policy is not None:
-            # Straggler-aware cadence backoff (r12): may flip a
-            # scheduled factor_update off while skew is sustained.
-            # Applied BEFORE dispatch and before the fired-stage label
-            # is derived, so attribution reflects what actually ran.
-            flags = cadence_policy.adjust(state.step, flags, wait_ms)
-        # Self-healing ladder (r16): escalated damping / quarantine
-        # gates are traced-scalar VALUE changes on this step's hyper —
-        # the dict structure is fixed at arming time, so the variant
-        # cache never retraces. selfheal=None leaves hyper untouched.
-        hyper_step = (hyper if selfheal is None
-                      else selfheal.adjust_hyper(hyper))
-        t_it = time.perf_counter()
-        with sanitizer.step_guard(step_fn, flags):
-            (state.params, state.opt_state, state.kfac_state,
-             state.extra_vars, metrics) = step_fn(
-                state.params, state.opt_state, state.kfac_state,
-                state.extra_vars, batch, hyper_step, **flags)
-        sanitizer.after_step(step_fn, state.step)
-        dt = time.perf_counter() - t_it
-        # A queued compile event right after the call means THIS step's
-        # wall time is dominated by trace+XLA compile, not training
-        # work. Label plain steps 'compile' so (a) the report's
-        # step-time attribution names the real culprit and (b) the
-        # health monitor's spike z-score excludes it — one absorbed
-        # 20 s compile sample would otherwise inflate the running
-        # stddev by orders of magnitude and blind the detector for the
-        # whole run. Steps that also fired a K-FAC stage keep that
-        # label (fired steps are excluded from spike stats anyway).
-        fired = fired_stage(flags)
-        if (fired and 'reduce' in fired
-                and getattr(step_fn, 'hierarchical_reduce', False)):
-            # r20: the window-boundary collective of a hierarchical
-            # run crosses slices over DCN — relabel so the straggler
-            # merger's wait_by_stage attributes DCN wait as its own
-            # bucket (stragglers.stage_class routes 'dcn_reduce' to
-            # 'dcn' before the generic 'reduce' match).
-            fired = fired.replace('reduce', 'dcn_reduce')
-        pending = getattr(step_fn, 'compile_events', None)
-        if pending and fired is None:
-            fired = 'compile'
-        if metrics_sink is not None:
-            # Enqueue only (device scalars + async host copy): the sink
-            # converts to floats at drain time, far behind dispatch.
-            metrics_sink.step_record(state.step, metrics,
-                                     host_step_ms=dt * 1000.0,
-                                     fired=fired)
-            # Feed the dispatch timing into the host trace table too,
-            # so epoch snapshots (and the report's stage table) carry a
-            # per-stage row even when no phase is @trace-decorated.
-            tracing.record('train_step_dispatch', dt)
-            if memory_interval > 0 and state.step % memory_interval == 0:
-                from distributed_kfac_pytorch_tpu.observability import (
-                    memory as obs_memory,
-                )
-                if state_footprint is None:
-                    state_footprint = obs_memory.state_footprint(
-                        state.kfac_state)
-                metrics_sink.memory_record(
-                    state.step,
-                    device=obs_memory.device_memory_stats(),
-                    state=state_footprint)
-        if rank_sink is not None:
-            # Per-rank straggler shard: dispatch wall + barrier wait
-            # only (the full metric set already rides the rank-0
-            # stream; shards exist to compare HOSTS, not to duplicate
-            # it).
-            shard_metrics = {}
-            if wait_ms is not None:
-                from distributed_kfac_pytorch_tpu.observability import (
-                    stragglers as obs_stragglers,
-                )
-                shard_metrics[obs_stragglers.BARRIER_WAIT_KEY] = wait_ms
-            rank_sink.step_record(state.step, shard_metrics,
-                                  host_step_ms=dt * 1000.0,
-                                  fired=fired)
-        if metrics_sink is not None:
-            # Drain queued compile/retrace telemetry from the step
-            # builder's variant cache (r10): rare, host-side, and
-            # written as event records so the gate can regress the
-            # retrace count offline. Duck-typed sinks that predate
-            # event records (tests pass minimal step/epoch-only
-            # stand-ins) just leave the queue in place.
-            emit_event = getattr(metrics_sink, 'event_record', None)
-            if pending and emit_event is not None:
-                for ev in list(pending):
-                    data = {k: v for k, v in ev.items() if k != 'event'}
-                    emit_event(ev['event'], **data)
-                pending.clear()
-            # Autotune policy decisions (stretch/relax) ride the same
-            # event channel so the report/gate can see them offline.
-            if (cadence_policy is not None and emit_event is not None
-                    and cadence_policy.pending_events):
-                for ev in cadence_policy.drain_events():
-                    data = {k: v for k, v in ev.items()
-                            if k != 'event'}
-                    emit_event(ev['event'], **data)
-        if selfheal is not None:
-            # Ladder observation (r16): host arithmetic except at its
-            # window boundaries. May reset quarantined factor EWMAs in
-            # state.kfac_state; may raise Rollback — the drain persists
-            # the ladder's own escalation events on both paths, and
-            # the except additionally flushes the sinks so the
-            # completed steps' records survive the unwind, exactly
-            # like a preemption.
-            try:
-                selfheal.observe(state, metrics)
-            except BaseException:
-                _drain_selfheal(selfheal, metrics_sink)
+    batch_iter = iter(batches)
+    while True:
+        # One root span a step (observability.tracing): its children
+        # say where the loop's own time goes between two step calls.
+        with tracing.span('kfac/host/step', step=state.step) as step_span:
+            with tracing.span('kfac/host/next_batch') as fetch:
+                try:
+                    batch = next(batch_iter)
+                except StopIteration:
+                    # The end of the data is no step.
+                    fetch.cancel()
+                    step_span.cancel()
+                    break
+            if static_cadence is not None:
+                f_freq, i_freq = static_cadence
+                flags = cadence_flags(state.step, f_freq, i_freq, chunks,
+                                      deferred_reduce=deferred_reduce,
+                                      inv_staleness=inv_staleness)
+            else:
+                flags = {}
+            wait_ms = None
+            if barrier_probe is not None and (
+                    straggler_sample_every <= 1
+                    or state.step % straggler_sample_every == 0):
+                # Straggler attribution: how long does THIS host wait
+                # for the rest of the mesh before its next collective
+                # could proceed? Measured before the dispatch so the
+                # wait is not conflated with this step's own compute.
+                with tracing.span('kfac/host/hook/barrier_probe'):
+                    wait_ms = barrier_probe()
+            if cadence_policy is not None:
+                # Straggler-aware cadence backoff (r12): may flip a
+                # scheduled factor_update off while skew is sustained.
+                # Applied BEFORE dispatch and before the fired-stage
+                # label is derived, so attribution reflects what
+                # actually ran.
+                with tracing.span('kfac/host/hook/cadence_policy'):
+                    flags = cadence_policy.adjust(state.step, flags,
+                                                  wait_ms)
+            # Self-healing ladder (r16): escalated damping / quarantine
+            # gates are traced-scalar VALUE changes on this step's
+            # hyper — the dict structure is fixed at arming time, so
+            # the variant cache never retraces. selfheal=None leaves
+            # hyper untouched.
+            if selfheal is None:
+                hyper_step = hyper
+            else:
+                with tracing.span('kfac/host/hook/selfheal'):
+                    hyper_step = selfheal.adjust_hyper(hyper)
+            with tracing.span('kfac/host/step_call') as call:
+                with sanitizer.step_guard(step_fn, flags):
+                    (state.params, state.opt_state, state.kfac_state,
+                     state.extra_vars, metrics) = step_fn(
+                        state.params, state.opt_state, state.kfac_state,
+                        state.extra_vars, batch, hyper_step, **flags)
+                sanitizer.after_step(step_fn, state.step)
+            # A queued compile event right after the call means THIS
+            # step's wall time is dominated by trace+XLA compile, not
+            # training work. Label plain steps 'compile' so (a) the
+            # report's step-time attribution names the real culprit and
+            # (b) the health monitor's spike z-score excludes it — one
+            # absorbed 20 s compile sample would otherwise inflate the
+            # running stddev by orders of magnitude and blind the
+            # detector for the whole run. Steps that also fired a K-FAC
+            # stage keep that label (fired steps are excluded from
+            # spike stats anyway).
+            fired = fired_stage(flags)
+            if (fired and 'reduce' in fired
+                    and getattr(step_fn, 'hierarchical_reduce', False)):
+                # r20: the window-boundary collective of a hierarchical
+                # run crosses slices over DCN — relabel so the
+                # straggler merger's wait_by_stage attributes DCN wait
+                # as its own bucket (stragglers.stage_class routes
+                # 'dcn_reduce' to 'dcn' before the generic 'reduce'
+                # match).
+                fired = fired.replace('reduce', 'dcn_reduce')
+            pending = getattr(step_fn, 'compile_events', None)
+            if pending and fired is None:
+                fired = 'compile'
+            step_span.set(fired=fired)
+            with tracing.span('kfac/host/sink'):
                 if metrics_sink is not None:
-                    metrics_sink.flush()
+                    # Enqueue only (device scalars + async host copy):
+                    # the sink converts to floats at drain time.
+                    metrics_sink.step_record(state.step, metrics,
+                                             host_step_ms=call.duration_ms,
+                                             fired=fired)
+                    if (memory_interval > 0
+                            and state.step % memory_interval == 0):
+                        if state_footprint is None:
+                            state_footprint = obs_memory.state_footprint(
+                                state.kfac_state)
+                        metrics_sink.memory_record(
+                            state.step,
+                            device=obs_memory.device_memory_stats(),
+                            state=state_footprint)
                 if rank_sink is not None:
-                    rank_sink.flush()
-                raise
-            _drain_selfheal(selfheal, metrics_sink)
-        state.step += 1
-        n_batches += 1
-        for k, v in metrics.items():
-            meters.setdefault(k, Metric(k)).update(v)
-        if heartbeat is not None:
-            # Liveness lease (r17): published before the checkpointer
-            # hook so a hang inside it (the chaos hang fault, a wedged
-            # collective save) leaves a fresh lease AT the hang step —
-            # the supervisor then sees the lease stop advancing.
-            heartbeat.beat(state.step)
-        if checkpointer is not None:
-            # May raise Preempted (after a blocking save). Flush the
-            # sink first so the completed steps' records are durable
-            # alongside the checkpoint the relaunch resumes from.
-            try:
-                checkpointer.after_step(
-                    state, start_step_in_epoch + n_batches)
-            except BaseException:
+                    # Per-rank straggler shard: dispatch wall + barrier
+                    # wait only (the full metric set already rides the
+                    # rank-0 stream; shards exist to compare HOSTS, not
+                    # to duplicate it).
+                    shard_metrics = {}
+                    if wait_ms is not None:
+                        shard_metrics[
+                            obs_stragglers.BARRIER_WAIT_KEY] = wait_ms
+                    rank_sink.step_record(state.step, shard_metrics,
+                                          host_step_ms=call.duration_ms,
+                                          fired=fired)
                 if metrics_sink is not None:
-                    metrics_sink.flush()
-                if rank_sink is not None:
-                    rank_sink.flush()
-                raise
+                    # Drain queued compile/retrace telemetry from the
+                    # step builder's variant cache (r10): rare,
+                    # host-side, and written as event records so the
+                    # gate can regress the retrace count offline.
+                    # Duck-typed sinks that predate event records
+                    # (tests pass minimal step/epoch-only stand-ins)
+                    # just leave the queue in place.
+                    emit_event = getattr(metrics_sink, 'event_record',
+                                         None)
+                    if pending and emit_event is not None:
+                        for ev in list(pending):
+                            data = {k: v for k, v in ev.items()
+                                    if k != 'event'}
+                            emit_event(ev['event'], **data)
+                        pending.clear()
+                    # Autotune policy decisions (stretch/relax) ride
+                    # the same event channel so the report/gate can see
+                    # them offline.
+                    if (cadence_policy is not None
+                            and emit_event is not None
+                            and cadence_policy.pending_events):
+                        for ev in cadence_policy.drain_events():
+                            data = {k: v for k, v in ev.items()
+                                    if k != 'event'}
+                            emit_event(ev['event'], **data)
+            if selfheal is not None:
+                # Ladder observation (r16): host arithmetic except at
+                # its window boundaries. May reset quarantined factor
+                # EWMAs in state.kfac_state; may raise Rollback — the
+                # drain persists the ladder's own escalation events on
+                # both paths, and the except additionally flushes the
+                # sinks so the completed steps' records survive the
+                # unwind, exactly like a preemption.
+                with tracing.span('kfac/host/hook/selfheal'):
+                    try:
+                        selfheal.observe(state, metrics)
+                    except BaseException:
+                        _drain_selfheal(selfheal, metrics_sink)
+                        if metrics_sink is not None:
+                            metrics_sink.flush()
+                        if rank_sink is not None:
+                            rank_sink.flush()
+                        raise
+                    _drain_selfheal(selfheal, metrics_sink)
+            state.step += 1
+            n_batches += 1
+            with tracing.span('kfac/host/meters'):
+                for k, v in metrics.items():
+                    meters.setdefault(k, Metric(k)).update(v)
+            if heartbeat is not None:
+                # Liveness lease (r17): published before the
+                # checkpointer hook so a hang inside it (the chaos hang
+                # fault, a wedged collective save) leaves a fresh lease
+                # AT the hang step — the supervisor then sees the lease
+                # stop advancing.
+                with tracing.span('kfac/host/hook/heartbeat'):
+                    heartbeat.beat(state.step)
+            if checkpointer is not None:
+                # May raise Preempted (after a blocking save). Flush
+                # the sink first so the completed steps' records are
+                # durable alongside the checkpoint the relaunch resumes
+                # from.
+                with tracing.span('kfac/host/hook/checkpointer'):
+                    try:
+                        checkpointer.after_step(
+                            state, start_step_in_epoch + n_batches)
+                    except BaseException:
+                        if metrics_sink is not None:
+                            metrics_sink.flush()
+                        if rank_sink is not None:
+                            rank_sink.flush()
+                        raise
     elapsed = time.perf_counter() - t0
     if n_batches == 0:
         if start_step_in_epoch > 0:
@@ -533,7 +565,8 @@ def train_epoch(step_fn, state: TrainState, batches: Iterable,
     out['ms_per_iter'] = elapsed / max(n_batches, 1) * 1000.0
     if metrics_sink is not None:
         metrics_sink.epoch_record(state.epoch, out,
-                                  trace=tracing.snapshot_trace())
+                                  trace=tracing.snapshot_trace(),
+                                  counters=tracing.counters())
         metrics_sink.flush()
     if rank_sink is not None:
         rank_sink.flush()
@@ -604,8 +637,9 @@ def build_sgd_train_step(model, loss_fn, tx, mesh=None, *,
             extra = metrics_fn(out, batch) if metrics_fn else {}
             return loss_fn(out, batch), (extra, dict(updated))
 
-        (loss, (extra_metrics, updated)), grads = jax.value_and_grad(
-            wrapped, has_aux=True)(params)
+        with jax.named_scope('kfac_step/fwd_bwd'):
+            (loss, (extra_metrics, updated)), grads = jax.value_and_grad(
+                wrapped, has_aux=True)(params)
         return loss, extra_metrics, updated, grads
 
     def local_step(params, opt_state, kstate, extra_vars, batch, hyper):
@@ -664,8 +698,9 @@ def build_sgd_train_step(model, loss_fn, tx, mesh=None, *,
             extra_metrics = jax.lax.pmean(extra_metrics, data_axes)
             if updated:
                 updated = jax.lax.pmean(updated, data_axes)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope('kfac_step/optimizer'):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         if updated:
             extra_vars = {**extra_vars, **updated}
         metrics = {'loss': loss, **extra_metrics}

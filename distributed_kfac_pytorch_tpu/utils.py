@@ -1,10 +1,9 @@
 """Tracing / timing utilities (reference kfac/utils.py:8-56).
 
-The wall-clock trace table moved to
-``observability.tracing`` (the r7 observability subsystem); the
-``trace`` / ``get_trace`` / ``print_trace`` / ``clear_trace`` names
-stay importable from here so reference-parity callers and existing
-tests keep working unchanged.
+The wall-clock trace table is ``observability.tracing``'s recorder of
+spans and counters; the ``trace`` / ``get_trace`` / ``print_trace`` /
+``clear_trace`` names stay importable from here so reference-parity
+callers and existing tests keep working unchanged.
 """
 
 from __future__ import annotations
@@ -13,10 +12,9 @@ from typing import Any
 
 import jax
 
-# Re-exports (same objects — the module-level table is shared, so
-# decorating through either path feeds one table).
+# Re-exports (same objects — decorating through either path feeds the
+# one recorder).
 from distributed_kfac_pytorch_tpu.observability.tracing import (  # noqa: F401
-    _FUNC_TRACES,
     clear_trace,
     get_trace,
     print_trace,

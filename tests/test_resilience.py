@@ -610,7 +610,7 @@ class _LossSink:
                     fired=None):
         self.losses.append(metrics['loss'])
 
-    def epoch_record(self, epoch, metrics, trace=None):
+    def epoch_record(self, epoch, metrics, trace=None, counters=None):
         pass
 
     def flush(self):

@@ -65,7 +65,7 @@ class _EventSink:
     def step_record(self, step, metrics, host_step_ms=None, fired=None):
         self.losses.append(metrics['loss'])
 
-    def epoch_record(self, epoch, metrics, trace=None):
+    def epoch_record(self, epoch, metrics, trace=None, counters=None):
         pass
 
     def event_record(self, name, **data):

@@ -7,6 +7,7 @@ import optax
 import pytest
 
 from distributed_kfac_pytorch_tpu import KFAC, utils
+from distributed_kfac_pytorch_tpu.observability import tracing
 from distributed_kfac_pytorch_tpu.optim import kfac_transform
 import flax.linen as nn
 
@@ -38,7 +39,7 @@ class TestTrace:
 
         for _ in range(5):
             work()
-        assert len(utils._FUNC_TRACES['w']) == 5
+        assert len(tracing.spans('w')) == 5
         assert utils.get_trace(max_history=2)['w'] > 0
         utils.clear_trace()
 
