@@ -28,6 +28,7 @@ from __future__ import annotations
 from typing import Any
 
 import flax.linen as nn
+import jax
 import jax.numpy as jnp
 
 from distributed_kfac_pytorch_tpu.parallel.sequence import (
@@ -70,15 +71,19 @@ class CausalSelfAttention(nn.Module):
                 'seq_axis and attn_block_size are mutually exclusive: '
                 'the ring already folds blockwise per device (set '
                 'attn_block_size=None under sequence parallelism)')
-        if self.seq_axis is not None:
-            o = ring_self_attention(q, k, v, axis_name=self.seq_axis,
-                                    causal=self.causal)
-        elif self.attn_block_size is not None:
-            o = chunked_causal_attention(q, k, v,
-                                         block_size=self.attn_block_size,
-                                         causal=self.causal)
-        else:
-            o = local_causal_attention(q, k, v, causal=self.causal)
+        # One scope for the attention itself on every path, so a device
+        # trace can tell its time from the projections' (the backward's
+        # operations carry it as transpose(jvp(kfac_model/attention))).
+        with jax.named_scope('kfac_model/attention'):
+            if self.seq_axis is not None:
+                o = ring_self_attention(q, k, v, axis_name=self.seq_axis,
+                                        causal=self.causal)
+            elif self.attn_block_size is not None:
+                o = chunked_causal_attention(
+                    q, k, v, block_size=self.attn_block_size,
+                    causal=self.causal)
+            else:
+                o = local_causal_attention(q, k, v, causal=self.causal)
         o = o.reshape(*x.shape[:-1], d_model).astype(x.dtype)
         return nn.Dense(d_model, dtype=self.dtype, name='out_proj')(o)
 

@@ -21,6 +21,7 @@ Both paths are bit-compatible in structure (same iteration, fp32).
 
 from __future__ import annotations
 
+import concurrent.futures
 import functools
 import os
 import warnings
@@ -1033,3 +1034,158 @@ def fused_precondition_supported() -> bool:
                    max_rel_error(vg, jnp.sum(ref * g, axis=(1, 2))))
 
     return _probe_on_tpu('bucket_precond', rel_error)
+
+
+# ---------------------------------------------------------------------------
+# Fused attention (PR 26)
+# ---------------------------------------------------------------------------
+#
+# parallel.sequence.local_causal_attention materializes (B, H, T, T)
+# float32 scores, the mask and the probabilities in HBM, forward and
+# backward: at gpt2-small's T=1024 about eight 402 MB passes a layer,
+# half of the model's device time for 13 % of its FLOPs. The kernel
+# JAX ships (jax.experimental.pallas.ops.tpu.flash_attention: forward,
+# dK/dV and dQ kernels under one custom_vjp) runs the module's own
+# online-softmax fold tile by tile in VMEM, builds the causal mask from
+# block indices, skips the tiles above the diagonal, and recomputes a
+# tile's probabilities in the backward from the saved row statistics.
+# Same arithmetic as the plain path: operands enter the matmuls at
+# their input dtype with float32 accumulation, max/exp/sum and the
+# output accumulator are float32, P is cast to V's dtype for P.V (what
+# the MXU's default precision does to the plain path's float32 P).
+
+#: The smallest tile the dispatcher uses: T must be a whole number of
+#: these. The kernel itself goes down to 128 (the lane width), but on a
+#: v5e tiles of 128 lose to the plain path (8.24 ms against 5.17 ms for
+#: one gpt2-small layer, forward + backward; PERF.md, PR 26).
+ATTENTION_MIN_BLOCK = 256
+#: Head dims the kernel was compiled and probed at on a v5e.
+ATTENTION_HEAD_DIMS = (64, 128)
+
+
+def attention_block_sizes(q: int, k_major: int, k: int,
+                          bwd_q: int | None = None):
+    """The shipped kernels' ``BlockSizes``: ``q`` query rows and
+    ``k_major`` keys a grid step, folded ``k`` keys at a time, in all
+    three kernels; ``bwd_q`` query rows in the two backward kernels
+    where they differ."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+    bwd_q = bwd_q or q
+    return fa.BlockSizes(
+        block_q=q, block_k_major=k_major, block_k=k, block_b=1,
+        block_q_major_dkv=bwd_q, block_k_major_dkv=k_major,
+        block_k_dkv=k, block_q_dkv=bwd_q, block_k_major_dq=k_major,
+        block_k_dq=k, block_q_dq=bwd_q)
+
+
+def _attention_block_sizes(t: int):
+    """Tile sizes for sequence length ``t`` (a multiple of
+    :data:`ATTENTION_MIN_BLOCK`), from the measured table (PERF.md,
+    PR 26; that layer, forward / forward + backward): tiles of 512
+    where they divide ``t`` (0.48 / 2.70 ms; 256: 0.76 / 4.07; 1024,
+    which skips nothing: 0.49 / 2.79), and in the two backward kernels
+    1024 query rows a step where those divide ``t`` (0.56 / 2.59)."""
+    s = 512 if t % 512 == 0 else ATTENTION_MIN_BLOCK
+    return attention_block_sizes(
+        s, s, s, bwd_q=1024 if t % 1024 == 0 else s)
+
+
+def fused_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                    causal: bool = True, block_sizes=None) -> jax.Array:
+    """Exact softmax attention in the shipped flash-attention kernels.
+
+    ``q``/``k``/``v``: (B, T, H, D) of one dtype; returns (B, T, H, D)
+    float32 like the plain path. The kernel works heads-first and
+    stores its float32 accumulator in the input dtype, so the result is
+    the plain path's rounded once to that dtype (the cast
+    ``CausalSelfAttention`` applies to it next in any case).
+    Differentiable (``custom_vjp``; dq/dk/dv come back in the input
+    dtype). No (B, H, T, T) tensor reaches HBM in either direction.
+    """
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+
+    d = q.shape[-1]
+    if block_sizes is None:
+        block_sizes = _attention_block_sizes(q.shape[1])
+    heads_first = lambda x: jnp.swapaxes(x, 1, 2)  # noqa: E731
+    o = fa.flash_attention(
+        heads_first(q), heads_first(k), heads_first(v), causal=causal,
+        sm_scale=1.0 / (d ** 0.5), block_sizes=block_sizes)
+    return heads_first(o).astype(jnp.float32)
+
+
+def _tpu_interpret_forced() -> bool:
+    """True inside ``pltpu.force_tpu_interpret_mode()``: every
+    ``pallas_call`` traced there runs on Pallas's TPU interpreter, on
+    any backend (the parity tests' way onto the kernel on the CPU)."""
+    from jax._src import config as jax_config
+
+    state = getattr(jax_config,
+                    'pallas_tpu_interpret_mode_context_manager', None)
+    return state is not None and state.value is not None
+
+
+@functools.lru_cache(maxsize=1)
+def _fused_attention_probed() -> bool:
+    """Once per process: the kill switch (recorded), else the probe."""
+    if _forced_fallback():
+        record_fallback('attention', 'forced by KFAC_PALLAS_FALLBACK')
+        return False
+    if jax.default_backend() != 'tpu':
+        return True
+
+    def rel_error():
+        import numpy as np
+
+        from distributed_kfac_pytorch_tpu.parallel import sequence
+        rng = np.random.default_rng(0)
+        # Two 512-tiles a side: a skipped tile, a full one, two masked.
+        q, k, v, w = (jnp.asarray(rng.normal(size=(1, 1024, 1, 64)),
+                                  jnp.bfloat16) for _ in range(4))
+
+        def out_and_grads(attend):
+            return jax.value_and_grad(lambda *qkv: jnp.sum(
+                attend(*qkv, causal=True) * w.astype(jnp.float32)),
+                (0, 1, 2))
+
+        # One program for both paths: run eagerly, each of their
+        # operations would compile by itself (7 s on a v5e).
+        got, ref = jax.jit(lambda *qkv: (
+            out_and_grads(fused_attention)(*qkv),
+            out_and_grads(sequence.plain_attention)(*qkv)))(q, k, v)
+        return max(max_rel_error(a, b) for a, b in zip(
+            jax.tree.leaves(got), jax.tree.leaves(ref)))
+
+    # The gate is asked while a model is being traced. The probe runs
+    # concrete arrays through its own jit, which a thread of its own
+    # does under no trace, mesh or scope of the caller's; result()
+    # raises here what the probe raised there.
+    with concurrent.futures.ThreadPoolExecutor(1) as pool:
+        return pool.submit(_probe_on_tpu, 'attention', rel_error).result()
+
+
+def fused_attention_applies(q, k, v, kvalid=None) -> bool:
+    """The gate of :func:`fused_attention`, from what the call shows.
+
+    Open for self-attention without a key mask (``kvalid``), operands
+    of one shape and one dtype (bfloat16 or float32), a head dim in
+    :data:`ATTENTION_HEAD_DIMS` and ``T`` a whole number of
+    :data:`ATTENTION_MIN_BLOCK` tiles — on a TPU, where the kernel's
+    once-per-process probe against the plain path must pass or raise
+    (:func:`_probe_on_tpu`). On other backends it is closed unless the
+    caller forced Pallas's TPU interpret mode. ``KFAC_PALLAS_FALLBACK=1``
+    closes it where it would have been open, with one recorded
+    ``pallas_fallback`` event. Anything else takes the plain path.
+    """
+    if kvalid is not None or not (q.shape == k.shape == v.shape):
+        return False
+    if not (q.dtype == k.dtype == v.dtype
+            and q.dtype in (jnp.bfloat16, jnp.float32)):
+        return False
+    t, d = q.shape[1], q.shape[-1]
+    if d not in ATTENTION_HEAD_DIMS or t % ATTENTION_MIN_BLOCK:
+        return False
+    if jax.default_backend() != 'tpu' and not _tpu_interpret_forced():
+        return False
+    return _fused_attention_probed()

@@ -136,6 +136,19 @@ def _open_build_span(label: str) -> tracing.Span:
         **{attr: 0.0 for attr, _ in _BUILD_EVENT_ATTRS.values()})
 
 
+def _attention_paths_since(before: dict) -> dict:
+    """How many attention calls this build traced onto the fused kernel
+    and onto the plain path: what the ``kfac/attention/*`` counters
+    (parallel.sequence) gained since ``before``. A capturing variant
+    traces the model twice (the probes' shape pass, then the
+    differentiated pass), so it reads twice its attention layers."""
+    now = tracing.counters()
+    return {f'attention_{path}': int(
+        now.get(f'kfac/attention/{path}', 0)
+        - before.get(f'kfac/attention/{path}', 0))
+        for path in ('fused', 'plain')}
+
+
 def resolve_grad_workers(size: int, comm_method: CommMethod,
                          grad_worker_fraction: float) -> int:
     """Number of devices per inverse group for a strategy.
@@ -2416,11 +2429,13 @@ class DistributedKFAC:
                      replicated_specs(extra_vars)))
                 tracing.count('kfac/builds')
                 label = _variant_label(key)
+                counted = tracing.counters()
                 with _open_build_span(label) as build:
                     out = variants[key](params, opt_state, kstate,
                                         extra_vars, batch, hyper)
                     build.set(
-                        cache_hit=build.attrs['cache_retrieval_s'] > 0)
+                        cache_hit=build.attrs['cache_retrieval_s'] > 0,
+                        **_attention_paths_since(counted))
                 # First-call wall = trace + lowering + XLA compile (or
                 # the load from the persistent cache) + dispatch; the
                 # execution itself is async. The span's attributes

@@ -13,7 +13,14 @@ O(T_global^2), and the K/V transfer overlaps with the block matmuls.
 
 ``ring_self_attention`` is the in-``shard_map`` building block;
 ``local_causal_attention`` is the single-device fallback with identical
-semantics, so models can be written once and run at either scale.
+semantics, so models can be written once and run at either scale. It has
+two forms of the one algorithm. The plain form (``plain_attention``) is a
+single ``_block_attend`` over the whole sequence: (B, H, T, T) float32
+scores in HBM, the same dot products as the ring's blocks. The fused form
+(``ops.pallas_kernels.fused_attention``, PR 26) runs the same
+online-softmax fold tile by tile inside one TPU kernel, forward and
+backward, so nothing of size T x T is ever written; which one a call
+takes is decided from the call itself (see ``local_causal_attention``).
 ``chunked_causal_attention`` is the single-device long-context leg:
 the same block fold scanned within one device with per-block
 rematerialization, pushing the attention-memory wall out by ~block/(3D)
@@ -26,6 +33,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from distributed_kfac_pytorch_tpu.observability import tracing
+from distributed_kfac_pytorch_tpu.ops import pallas_kernels
 
 # Mesh axis name for sequence/context parallelism.
 SEQ_AXIS = 'kfac_sp'
@@ -47,9 +57,11 @@ def _block_attend(q, k, v, scale, qpos, kpos, causal, kvalid=None):
     (bf16 in, fp32 out). Upcasting operands first would halve matmul
     throughput for identical accumulation; each logit is one q.k dot
     product of the same operand rows in either the ring or the local
-    path, so blockwise vs monolithic results stay bitwise-comparable
-    at any operand dtype. Softmax statistics (m, l) and the output
-    accumulator are always fp32.
+    path, so blockwise vs monolithic results of this plain path stay
+    bitwise-comparable at any operand dtype (the fused kernel computes
+    the same products in another summation order: see
+    ``local_causal_attention``). Softmax statistics (m, l) and the
+    output accumulator are always fp32.
     """
     logits = jnp.einsum('bqhd,bkhd->bhqk', q, k,
                         preferred_element_type=jnp.float32) * scale
@@ -141,14 +153,56 @@ def ring_self_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return o / l
 
 
-def local_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
-                           *, causal: bool = True) -> jax.Array:
-    """Single-device attention with the same contract as the ring path."""
+def plain_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                    causal: bool = True,
+                    kvalid: jax.Array | None = None) -> jax.Array:
+    """Monolithic attention: one ``_block_attend`` over the whole
+    sequence, (B, H, T, T) float32 scores and probabilities in HBM.
+    The path every backend can take, and the fused kernel's reference."""
     b, t, h, d = q.shape
     pos = jnp.arange(t)
-    m, o, l = _block_attend(q, k, v, 1.0 / (d ** 0.5), pos, pos, causal)
+    m, o, l = _block_attend(q, k, v, 1.0 / (d ** 0.5), pos, pos, causal,
+                            kvalid=kvalid)
     l = jnp.maximum(jnp.moveaxis(l, 1, 2)[..., None], 1e-30)
     return o / l
+
+
+def local_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                           *, causal: bool = True,
+                           kvalid: jax.Array | None = None) -> jax.Array:
+    """Single-device attention with the same contract as the ring path:
+    (B, T, H, D) in, exact softmax attention out, (B, T, H, D) fp32.
+    ``kvalid`` ((T,) bool) masks padding keys as in ``_block_attend``.
+
+    The call itself decides between two forms of the same fold
+    (``ops.pallas_kernels.fused_attention_applies``): the fused TPU
+    kernel when the backend is a TPU, there is no ``kvalid``, q/k/v
+    share one shape and one dtype (bf16 or fp32), the head dim is one
+    the kernel takes (64, 128) and T is a whole number of 256-row
+    tiles; the plain path (``plain_attention``) otherwise — a ViT's
+    T = 197, any CPU run — unchanged. ``KFAC_PALLAS_FALLBACK=1`` forces
+    the plain path and records a ``pallas_fallback`` event.
+
+    The fused path's contract: exact attention at the same precisions
+    (operands enter QK^T and P.V at the input dtype with fp32
+    accumulation, max/exp/sum and the output accumulator in fp32; P is
+    cast to V's dtype for P.V, which is what the TPU's default matmul
+    precision does to the plain path's fp32 P). It equals the plain
+    path up to summation order and one rounding of the result to the
+    input dtype, and writes no (B, H, T, T) tensor — scores,
+    probabilities, mask or their gradients — to HBM, forward or
+    backward (the backward recomputes a tile's probabilities from the
+    saved row max and sum).
+
+    Each traced call counts itself in the recorder
+    (``observability.tracing``): ``kfac/attention/fused`` or
+    ``kfac/attention/plain``.
+    """
+    if pallas_kernels.fused_attention_applies(q, k, v, kvalid):
+        tracing.count('kfac/attention/fused')
+        return pallas_kernels.fused_attention(q, k, v, causal=causal)
+    tracing.count('kfac/attention/plain')
+    return plain_attention(q, k, v, causal=causal, kvalid=kvalid)
 
 
 def chunked_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
