@@ -71,7 +71,10 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from distributed_kfac_pytorch_tpu import launch, multislice  # noqa: E402
 from distributed_kfac_pytorch_tpu import native  # noqa: E402
-from distributed_kfac_pytorch_tpu.models import transformer_lm  # noqa: E402
+from distributed_kfac_pytorch_tpu.models import (  # noqa: E402
+    mla_moe_lm,
+    transformer_lm,
+)
 from distributed_kfac_pytorch_tpu.observability import (  # noqa: E402
     sink as obs_sink,
 )
@@ -383,10 +386,11 @@ def leg_lm(out_dir: str, *, name: str = 'B', size: str = 'xl',
            comm_method: str = 'comm-opt',
            grad_worker_fraction: float = 0.25, dtype=jnp.bfloat16,
            bf16_state: bool = True, seed: int = 0,
-           **model_overrides) -> dict:
-    """``transformer_lm.get_model(size)`` through the calls
-    ``examples/train_language_model.py:main`` makes, in its order. (The
-    CLI itself cannot select bf16 compute.)"""
+           arch: str = 'transformer', **model_overrides) -> dict:
+    """``transformer_lm.get_model(size)`` — or, with ``arch='mla_moe'``,
+    ``mla_moe_lm.get_model(size)`` with its untied head left to SGD —
+    through the calls ``examples/train_language_model.py:main`` makes,
+    in its order. (The CLI itself cannot select bf16 compute.)"""
     report = _new_report(name)
     stream = os.path.join(out_dir, f'{name}.jsonl')
     n_dev = jax.device_count()
@@ -394,6 +398,9 @@ def leg_lm(out_dir: str, *, name: str = 'B', size: str = 'xl',
     base_lr, grad_clip = 0.1, 0.25
 
     def build_model():
+        if arch == 'mla_moe':
+            return mla_moe_lm.get_model(vocab, size, dtype=dtype,
+                                        **model_overrides)
         return transformer_lm.get_model(
             vocab, size, max_len=seq, tie_weights=True, dtype=dtype,
             **model_overrides)
@@ -404,7 +411,9 @@ def leg_lm(out_dir: str, *, name: str = 'B', size: str = 'xl',
         warmup_epochs=1, lr_decay=[20, 30], workers=1,
         kfac_inv_update_freq=inv_freq, kfac_cov_update_freq=factor_freq,
         damping=0.003, factor_decay=0.95, kl_clip=0.001,
-        inverse_method='auto', skip_layers=[], comm_method=comm_method,
+        inverse_method='auto',
+        skip_layers=['head'] if arch == 'mla_moe' else [],
+        comm_method=comm_method,
         grad_worker_fraction=grad_worker_fraction,
         bf16_factors=bf16_state, bf16_inverses=bf16_state,
         kfac_metrics=True)
@@ -435,7 +444,8 @@ def leg_lm(out_dir: str, *, name: str = 'B', size: str = 'xl',
 
     def loss_fn(out, batch):
         return optax.softmax_cross_entropy_with_integer_labels(
-            out, batch[1]).mean()
+            out.astype(jnp.float32) if arch == 'mla_moe' else out,
+            batch[1]).mean()
 
     data_axes = dkfac.data_axes
 
@@ -487,7 +497,7 @@ def leg_lm(out_dir: str, *, name: str = 'B', size: str = 'xl',
     if not np.isfinite(val['loss']):
         report['failures'].append(f"non-finite eval loss {val['loss']}")
     check_training(report, stream, clock, must_learn=False)
-    want = {'plain', 'factor', 'firing'}
+    want = {'factor', 'firing'} | ({'plain'} if factor_freq > 1 else set())
     if steps >= 2 * inv_freq and set(report.get('classes_run', ())) != want:
         report['failures'].append(
             f"step classes run {report.get('classes_run')}, "
@@ -674,6 +684,11 @@ def main() -> int:
         run_leg(leg_lm, out_dir, name='B', num_layers=LM_DEPTH,
                 **lm_mesh),
         run_leg(leg_kernels),
+        # The second decoder's two variants (factors every step) at a
+        # small depth: one dense and one MoE layer at published widths.
+        run_leg(leg_lm, out_dir, name='D', arch='mla_moe',
+                size='kanana2', num_layers=2, vocab=16032, steps=8,
+                factor_freq=1, inv_freq=4, bf16_state=False, **lm_mesh),
         run_leg(leg_cifar, out_dir, name='A-fused', steps=12, fused=True,
                 batch_size=512 * n_dev),
     ]

@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distributed_kfac_pytorch_tpu.modules.experts import ExpertsDense
+
 CAPTURE_COL = 'kfac_in'
 PROBE_COL = 'kfac_probes'
 
@@ -56,6 +58,14 @@ EMBEDDING = 'embedding'
 # feature_group_count != 1, kfac/layers/__init__.py:13-36; this
 # framework preconditions MobileNet/EfficientNet-class models).
 CONV2D_GROUPED = 'conv2d_grouped'
+# Stacked experts (``modules.experts.ExpertsDense``): one weight per
+# expert, each seeing only the rows routed to it, so the Fisher block is
+# block-diagonal over experts and the layer carries ``num_experts``
+# stacked (da, da)/(dg, dg) factor blocks, as a grouped conv carries one
+# per group. No reference analogue.
+EXPERTS = 'experts'
+#: Kinds whose factors and inverses are ``(blocks, d, d)`` stacks.
+BLOCK_STACK_KINDS = (CONV2D_GROUPED, EXPERTS)
 
 # Weight-sharing Kronecker approximations (arXiv:2311.00636, "K-FAC for
 # Modern Neural Network Architectures"). A layer whose weight is shared
@@ -88,7 +98,7 @@ class LayerSpec:
     2-D ``(out_dim, in_dim[+1])`` matrix form.
     """
     path: tuple[str, ...]          # module path == params subtree path
-    kind: str                      # LINEAR | CONV2D | CONV2D_GROUPED | EMBEDDING
+    kind: str        # LINEAR | CONV2D | CONV2D_GROUPED | EMBEDDING | EXPERTS
     has_bias: bool
     num_calls: int = 1             # calls per training step (e.g. timesteps)
     # conv2d / conv2d_grouped only:
@@ -98,6 +108,11 @@ class LayerSpec:
     feature_group_count: int = 1   # conv2d_grouped: number of groups
     # embedding only:
     vocab_size: int | None = None
+    # experts only: the stacked experts, and the routed rows one token
+    # of the step makes (the router's top-k; statistics are normalised
+    # by tokens, as every other layer's are).
+    num_experts: int = 0
+    rows_per_token: int = 1
     # Weight-sharing approximation for this layer's factor statistics
     # (KFAC_EXPAND | KFAC_REDUCE). Registration records 'expand' (the
     # exact-parity default); sharing.annotate_specs resolves the
@@ -120,6 +135,12 @@ class LayerSpec:
     @property
     def name(self) -> str:
         return '/'.join(self.path) if self.path else '<root>'
+
+    @property
+    def num_blocks(self) -> int:
+        """Stacked factor blocks of a ``BLOCK_STACK_KINDS`` layer."""
+        return (self.num_experts if self.kind == EXPERTS
+                else self.feature_group_count)
 
 
 def _canonical_padding(padding, n_spatial: int):
@@ -166,7 +187,7 @@ def _decline_reason(mod: nn.Module) -> str | None:
     subclass that genuinely behaves like its base can be registered by
     converting it to composition over the exact type.
     """
-    for base in (nn.Dense, nn.Conv, nn.Embed):
+    for base in (nn.Dense, nn.Conv, nn.Embed, ExpertsDense):
         if isinstance(mod, base) and type(mod) is not base:
             # flax's lifted transforms (nn.remat / nn.scan / ...)
             # generate subclasses in flax.linen.transforms whose call
@@ -225,6 +246,10 @@ def _spec_for_module(mod: nn.Module, path: tuple[str, ...],
     if isinstance(mod, nn.Embed):
         return LayerSpec(path=path, kind=EMBEDDING, has_bias=False,
                          num_calls=num_calls, vocab_size=mod.num_embeddings)
+    if isinstance(mod, ExpertsDense):
+        return LayerSpec(path=path, kind=EXPERTS, has_bias=False,
+                         num_calls=num_calls, num_experts=mod.num_experts,
+                         rows_per_token=mod.rows_per_token)
     return None
 
 
@@ -383,6 +408,12 @@ class KFACCapture:
             call_counts[path] = idx + 1
             mod.sow(CAPTURE_COL, 'a', self._cast_capture(a_in),
                     init_fn=tuple, reduce_fn=lambda p, x: p + (x,))
+            if isinstance(mod, ExpertsDense):
+                # The rows of each expert (group_sizes): the statistics
+                # are contracted expert by expert over them.
+                mod.sow(CAPTURE_COL, 'rows',
+                        args[1] if len(args) > 1 else kwargs['group_sizes'],
+                        init_fn=tuple, reduce_fn=lambda p, x: p + (x,))
             y = next_fun(*args, **kwargs)
             y = mod.perturb(f'probe{idx}', y, collection=PROBE_COL)
             if record_specs:
@@ -648,6 +679,8 @@ class KFACCapture:
                     f'{len(gs)} probe gradients — activation and probe '
                     'call counts must match')
             captures[name] = {'a': a_node, 'g': gs}
+            if 'rows' in acts_node:
+                captures[name]['rows'] = tuple(acts_node['rows'])
             if n_tied:
                 # Tied-embedding attend sites: inputs + output-grad
                 # probes, paired per call like the primary stream.
@@ -704,6 +737,10 @@ def subsample_captures(captures: dict, fraction: float) -> dict:
     # 'a_tied'/'g_tied' attend-site streams, which feed the same factor
     # statistics (dropping them here would silently bias the tied
     # factor pair toward the lookup site at fraction < 1).
-    return {name: {key: tuple(keep(t) for t in calls)
-                   for key, calls in c.items()}
+    # A stacked-expert layer's rows are sorted by expert, not by batch
+    # position: a leading-dim stride would thin the experts unevenly,
+    # so its streams pass whole.
+    return {name: (c if 'rows' in c
+                   else {key: tuple(keep(t) for t in calls)
+                         for key, calls in c.items()})
             for name, c in captures.items()}

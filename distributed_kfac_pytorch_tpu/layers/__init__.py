@@ -6,6 +6,7 @@ from distributed_kfac_pytorch_tpu.layers.base import (
     compute_a_factor,
     compute_g_factor,
     compute_tied_factor_extras,
+    experts_contrib,
     factor_shapes,
     grads_to_matrix,
     matrix_to_grads,

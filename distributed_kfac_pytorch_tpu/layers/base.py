@@ -25,13 +25,14 @@ from distributed_kfac_pytorch_tpu.capture import (
     CONV2D,
     CONV2D_GROUPED,
     EMBEDDING,
+    EXPERTS,
     KFAC_REDUCE,
     LINEAR,
     LayerSpec,
 )
 from distributed_kfac_pytorch_tpu.ops import factors as F
 
-KNOWN_KINDS = (LINEAR, CONV2D, CONV2D_GROUPED, EMBEDDING)
+KNOWN_KINDS = (LINEAR, CONV2D, CONV2D_GROUPED, EMBEDDING, EXPERTS)
 
 
 def compute_a_factor(spec: LayerSpec, a_calls: Sequence[jax.Array],
@@ -46,7 +47,8 @@ def compute_a_factor(spec: LayerSpec, a_calls: Sequence[jax.Array],
     shared axis into covariance rows (the historical path, untouched);
     'reduce' averages activations over it first (sharing.approx,
     arXiv:2311.00636 Eq. 22). Static per-spec dispatch — the choice is
-    program structure, not data.
+    program structure, not data. (A stacked-expert layer's statistics
+    need its rows' routing too: :func:`experts_contrib`.)
     """
     reduced = spec.kfac_approx == KFAC_REDUCE
     if spec.kind == LINEAR:
@@ -83,6 +85,26 @@ def compute_a_factor(spec: LayerSpec, a_calls: Sequence[jax.Array],
             out = cur if out is None else out + cur
         return out
     raise ValueError(f'unknown layer kind {spec.kind!r}')
+
+
+def experts_contrib(spec: LayerSpec, entry: dict,
+                    compute_dtype=None) -> dict:
+    """One batch's contribution of a stacked-expert layer, ``{'A', 'G',
+    'rows'}``, from its capture entry (``'a'``, ``'g'`` and ``'rows'``,
+    the per-call ``group_sizes``): per expert the input SUMS and ``G_e``
+    on the common scale ``1/N`` and the expert's share of the rows
+    (``ops.factors.experts_*``), each summed over calls. All three are
+    linear in the batch, so they average exactly over micro-batches and
+    over the mesh; ``ops.factors.experts_running_avg`` divides ``A`` by
+    ``rows`` where the running average is updated."""
+    k = spec.rows_per_token
+    out = None
+    for a, g, n in zip(entry['a'], entry['g'], entry['rows']):
+        cur = {'A': F.experts_a_factor(a, n, k, compute_dtype=compute_dtype),
+               'G': F.experts_g_factor(g, n, k, compute_dtype=compute_dtype),
+               'rows': F.experts_row_share(n, a.shape[0], k)}
+        out = cur if out is None else jax.tree.map(jnp.add, out, cur)
+    return out
 
 
 def compute_g_factor(spec: LayerSpec, g_calls: Sequence[jax.Array],
@@ -197,6 +219,9 @@ def grads_to_matrix(spec: LayerSpec, grads: dict) -> jax.Array:
     if spec.kind == EMBEDDING:
         # (vocab, dim): A is diagonal over vocab, G is (dim, dim).
         return grads['embedding']
+    if spec.kind == EXPERTS:
+        # (E, in, out) -> (E, out, in): one matrix per expert.
+        return grads['kernel'].transpose(0, 2, 1)
     raise ValueError(f'unknown layer kind {spec.kind!r}')
 
 
@@ -227,6 +252,9 @@ def matrix_to_grads(spec: LayerSpec, mat: jax.Array,
     if spec.kind == EMBEDDING:
         out['embedding'] = mat.reshape(like['embedding'].shape)
         return out
+    if spec.kind == EXPERTS:
+        out['kernel'] = mat.transpose(0, 2, 1)
+        return out
     raise ValueError(f'unknown layer kind {spec.kind!r}')
 
 
@@ -253,4 +281,9 @@ def factor_shapes(spec: LayerSpec, params: dict) -> tuple[int, int]:
     if spec.kind == EMBEDDING:
         vocab, dim = params['embedding'].shape
         return vocab, dim  # A is diagonal (vector of length vocab)
+    if spec.kind == EXPERTS:
+        # PER-EXPERT dims, stacked num_experts times like a grouped
+        # conv's per-group blocks.
+        _, in_dim, out_dim = params['kernel'].shape
+        return in_dim, out_dim
     raise ValueError(f'unknown layer kind {spec.kind!r}')

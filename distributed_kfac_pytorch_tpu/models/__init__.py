@@ -7,6 +7,9 @@
 - ``lstm_lm``: LSTM language model (reference examples/rnn_utils/lstm.py).
 - ``transformer_lm``: Transformer decoder LM with Linear-layer K-FAC and
   optional ring-attention sequence parallelism (BASELINE config 4).
+- ``mla_moe_lm``: DeepSeek-V3-shaped decoder LM — latent attention
+  (MLA), sigmoid-routed stacked experts beside shared ones, SwiGLU,
+  RMSNorm, RoPE, an untied head; exercises the ``experts`` layer kind.
 - ``mobilenet``: MobileNetV1 — the depthwise workload the reference
   cannot precondition (no grouped-conv layer kind there); exercises
   this framework's ``conv2d_grouped`` path end to end.
@@ -18,6 +21,7 @@
 from distributed_kfac_pytorch_tpu.models import cifar_resnet
 from distributed_kfac_pytorch_tpu.models import imagenet_resnet
 from distributed_kfac_pytorch_tpu.models import lstm_lm
+from distributed_kfac_pytorch_tpu.models import mla_moe_lm
 from distributed_kfac_pytorch_tpu.models import mobilenet
 from distributed_kfac_pytorch_tpu.models import transformer_lm
 from distributed_kfac_pytorch_tpu.models import vit

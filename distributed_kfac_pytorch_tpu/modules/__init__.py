@@ -1,4 +1,7 @@
-"""K-FAC-friendly recurrent modules (reference kfac/modules)."""
+"""K-FAC-friendly modules: recurrent (reference kfac/modules) and
+stacked experts."""
+
+from distributed_kfac_pytorch_tpu.modules.experts import ExpertsDense
 
 from distributed_kfac_pytorch_tpu.modules.lstm import (
     LSTM,

@@ -39,6 +39,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from distributed_kfac_pytorch_tpu.capture import EXPERTS
+
 # Scalar metric slots (beyond the per-model 'bucket_norms' subtree).
 # 'inv_chunk_firings' counts pipelined chunk firings (r9: a chunk
 # firing covers 1/k of the factor set, so it is tallied separately
@@ -104,6 +106,30 @@ def flatten_metrics(m: dict, prefix: str = 'kfac') -> dict:
     for k, v in m.get('bucket_norms', {}).items():
         out[f'{prefix}/bucket_norm/{k}'] = v
     return out
+
+
+def moe_row_metrics(specs: dict, captures: dict, axes) -> dict:
+    """How this step's tokens fell on the experts held here, from the
+    stacked-expert layers' captured ``group_sizes`` (one stream a MoE
+    layer: its matrices share their routing), summed over the mesh's
+    data ``axes``: ``moe/rows_here`` (routed rows a layer, mean over
+    the layers), ``moe/rows_max_expert`` (the fullest expert's rows)
+    and ``moe/empty_experts`` (experts, over all layers, that got no
+    row and so kept their factors). Traced scalars that ride in the
+    step's metrics dict like every other; ``{}`` for a model without
+    such layers, and on steps that capture nothing."""
+    per_layer = {}
+    for name, spec in specs.items():
+        if spec.kind == EXPERTS and 'rows' in captures.get(name, {}):
+            per_layer.setdefault(spec.path[:-1],
+                                 sum(captures[name]['rows']))
+    if not per_layer:
+        return {}
+    rows = jax.lax.psum(jnp.stack(list(per_layer.values())), axes)
+    return {'moe/rows_here': jnp.mean(
+                jnp.sum(rows, axis=-1).astype(jnp.float32)),
+            'moe/rows_max_expert': jnp.max(rows).astype(jnp.float32),
+            'moe/empty_experts': jnp.sum(rows == 0).astype(jnp.float32)}
 
 
 def count_clipped_eigvals(inverses: dict) -> jax.Array:

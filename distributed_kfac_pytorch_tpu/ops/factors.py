@@ -809,6 +809,84 @@ def conv2d_grouped_g_factor(g: jax.Array, groups: int,
         0.5 / (rows * spatial * spatial))
 
 
+# ---------------------------------------------------------------------------
+# Stacked experts: per-expert statistics over the rows routed to each
+# ---------------------------------------------------------------------------
+
+_RAGGED_ROWS = jax.lax.RaggedDotDimensionNumbers(
+    dot_dimension_numbers=(((0,), (0,)), ((), ())),
+    lhs_ragged_dimensions=[0], rhs_group_dimensions=[])
+
+
+def _experts_cov(x: jax.Array, group_sizes: jax.Array,
+                 compute_dtype=None) -> jax.Array:
+    """``(E, d, d)``: per expert ``sum_r x_r x_r^T`` over its own rows
+    of the expert-sorted ``(rows, d)`` tensor ``x`` (rows past
+    ``sum(group_sizes)`` enter no expert's sum). One grouped contraction
+    (``ragged_dot_general`` with the row dimension ragged): work follows
+    the rows that are there, not ``E x rows``. Precision as
+    :func:`get_cov`; float32 out, symmetrized."""
+    precision = None
+    if compute_dtype is not None:
+        x = x.astype(compute_dtype)
+        if jnp.dtype(compute_dtype) == jnp.float32:
+            precision = jax.lax.Precision.HIGHEST
+    cov = jax.lax.ragged_dot_general(
+        x, x, group_sizes.astype(jnp.int32), _RAGGED_ROWS,
+        precision=precision, preferred_element_type=jnp.float32)
+    return (cov + cov.transpose(0, 2, 1)) * 0.5
+
+
+@profiling.scope('kfac/factors/experts_a')
+def experts_a_factor(a: jax.Array, group_sizes: jax.Array,
+                     rows_per_token: int, compute_dtype=None) -> jax.Array:
+    """Per-expert input sums ``sum_{t in T_e} a_t a_t^T / N``, ``(E, d,
+    d)``, ``N`` the step's tokens (``rows / rows_per_token``).
+
+    This is the SUM over the expert's rows on the common scale ``1/N``,
+    not yet the expert's mean: its row count travels beside it
+    (:func:`experts_row_share`) so that both average exactly over
+    devices and micro-batches, and :func:`experts_running_avg` divides
+    the two where the running average is updated.
+    """
+    return _experts_cov(a, group_sizes, compute_dtype) * (
+        float(rows_per_token) / a.shape[0])
+
+
+@profiling.scope('kfac/factors/experts_g')
+def experts_g_factor(g: jax.Array, group_sizes: jax.Array,
+                     rows_per_token: int, compute_dtype=None) -> jax.Array:
+    """Per-expert ``G_e = sum_{t in T_e} g_t g_t^T / N``, ``(E, d, d)``.
+
+    ``g`` is the gradient of the batch-mean loss at the expert's output
+    (it carries the routing weight), and ``N`` the step's tokens: the
+    normalisation :func:`linear_g_factor` gives every dense layer, so
+    that ``A_e (x) G_e`` scales as the expert's Fisher block does.
+    """
+    return _experts_cov(g, group_sizes, compute_dtype) * (
+        float(rows_per_token) / g.shape[0])
+
+
+def experts_row_share(group_sizes: jax.Array, rows: int,
+                      rows_per_token: int) -> jax.Array:
+    """``n_e / N`` per expert, float32: the divisor of
+    :func:`experts_a_factor`'s sums."""
+    return group_sizes.astype(jnp.float32) * (float(rows_per_token) / rows)
+
+
+def experts_running_avg(old: dict, a_sum: jax.Array, g_new: jax.Array,
+                        row_share: jax.Array, alpha) -> dict:
+    """EWMA of a stacked-expert layer's ``{'A', 'G'}``: ``A_e = a_sum_e
+    / row_share_e`` (the mean over the expert's rows). An expert that
+    got no row this step keeps both running averages untouched."""
+    live = (row_share > 0)[:, None, None]
+    a_new = a_sum / jnp.where(live, row_share[:, None, None], 1.0)
+    return {'A': jnp.where(live, update_running_avg(
+                a_new.astype(old['A'].dtype), old['A'], alpha), old['A']),
+            'G': jnp.where(live, update_running_avg(
+                g_new.astype(old['G'].dtype), old['G'], alpha), old['G'])}
+
+
 @profiling.scope('kfac/factors/conv2d_g')
 def conv2d_g_factor(g: jax.Array, compute_dtype=None) -> jax.Array:
     """G factor for conv2d from NHWC output grads.

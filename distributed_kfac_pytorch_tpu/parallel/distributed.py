@@ -54,8 +54,8 @@ from jax.sharding import PartitionSpec as P
 
 from distributed_kfac_pytorch_tpu import fp16 as fp16_ops
 from distributed_kfac_pytorch_tpu import layers as L
-from distributed_kfac_pytorch_tpu.capture import (CONV2D_GROUPED,
-                                                  EMBEDDING,
+from distributed_kfac_pytorch_tpu.capture import (BLOCK_STACK_KINDS,
+                                                  EMBEDDING, EXPERTS,
                                                   subsample_captures)
 from distributed_kfac_pytorch_tpu.observability import (
     memory as obs_memory,
@@ -272,8 +272,8 @@ class WorkAssignment:
     layer_row: dict[str, int]
     buckets: dict[int, BucketPlan]
     diag_layers: tuple[str, ...]
-    # Grouped/depthwise convs: per-group block stacks, computed
-    # replicated (tiny blocks) and preconditioned by their owning row.
+    # Grouped/depthwise convs and stacked experts: per-block stacks,
+    # computed replicated and preconditioned by their owning row.
     grouped_layers: tuple[str, ...] = ()
 
 
@@ -304,7 +304,7 @@ def assign_work(kfac: KFAC, params, n_rows: int, n_cols: int, *,
         shapes[name] = (a_dim, g_dim)
         if spec.kind == EMBEDDING:
             diag_layers.append(name)
-        elif spec.kind == CONV2D_GROUPED:
+        elif spec.kind in BLOCK_STACK_KINDS:
             grouped_layers.append(name)
 
     def factor_entries(name):
@@ -325,7 +325,7 @@ def assign_work(kfac: KFAC, params, n_rows: int, n_cols: int, *,
 
     layer_cost = {n: sum(c for _, _, c in factor_entries(n)) for n in names}
     for n in grouped_layers:
-        ng = kfac.specs[n].feature_group_count
+        ng = kfac.specs[n].num_blocks
         a_dim, g_dim = shapes[n]
         layer_cost[n] = ng * (a_dim ** exp + g_dim ** exp)
     row_of = dict(zip(names, load_balance(
@@ -531,7 +531,7 @@ class DistributedKFAC:
                           proxy_scale
                           * float(self._factor_dims[name][0])))
         for name in self.assignment.grouped_layers:
-            ng = kfac.specs[name].feature_group_count
+            ng = kfac.specs[name].num_blocks
             a_dim, g_dim = self._factor_dims[name]
             items.append((('grouped', name),
                           proxy_scale
@@ -565,7 +565,7 @@ class DistributedKFAC:
         """Dense layer with exactly one eigen-family side (an 'auto'
         straddle, or a low-rank side paired with a baked one)."""
         spec = self.kfac.specs[name]
-        if spec.kind in (EMBEDDING, CONV2D_GROUPED):
+        if spec.kind in (EMBEDDING, *BLOCK_STACK_KINDS):
             return False
         a_dim, g_dim = self._factor_dims[name]
         return (eigen_family(self.kfac.method_for_dim(a_dim))
@@ -588,7 +588,7 @@ class DistributedKFAC:
         """
         by_shape: dict[tuple[int, int], dict[int, list[str]]] = {}
         for name, spec in self.kfac.specs.items():
-            if spec.kind in (EMBEDDING, CONV2D_GROUPED):
+            if spec.kind in (EMBEDDING, *BLOCK_STACK_KINDS):
                 continue  # diagonal A / block stacks: per-layer path
             a_dim, g_dim = self._factor_dims[name]
             rows = by_shape.setdefault((g_dim, a_dim), {})
@@ -645,6 +645,16 @@ class DistributedKFAC:
         for group, nbytes in footprint['by_group'].items():
             tracing.gauge(f'kfac/state_bytes/{group}', nbytes)
         tracing.gauge('kfac/state_bytes/total', footprint['total_bytes'])
+        experts = [n for n, s in self.kfac.specs.items()
+                   if s.kind == EXPERTS]
+        if experts:
+            # The expert stacks' share of the groups above (factors and
+            # inverses both), not a group beside them.
+            tracing.gauge('kfac/state_bytes/experts', sum(
+                obs_memory.state_footprint(
+                    {k: {n: state[k][n] for n in experts}
+                     for k in ('factors', 'grouped_inv')})
+                ['by_group'].values()))
         return state
 
     def _init_state_values(self, params) -> dict:
@@ -766,6 +776,9 @@ class DistributedKFAC:
         interp = jax.default_backend() != 'tpu'
         out = {}
         for name, spec in self.kfac.specs.items():
+            if spec.kind == EXPERTS:
+                out[name] = L.experts_contrib(spec, captures[name], cdt)
+                continue
             # r21 fused contraction: eligible sides run the packed
             # Pallas x.T@x kernel in contraction-only form (old=None,
             # decay=0 — the mesh pmean sits between contraction and
@@ -848,6 +861,12 @@ class DistributedKFAC:
                     contribs[name]['A_g2'])
                 g_new = g_new + factor_pmean(contribs[name]['G_a'])
             old = state['factors'][name]
+            if 'rows' in contribs[name]:
+                new_factors[name] = F.experts_running_avg(
+                    old, a_new, g_new,
+                    jax.lax.pmean(contribs[name]['rows'], self.data_axes),
+                    alpha)
+                continue
             new_factors[name] = {
                 'A': F.update_running_avg(a_new.astype(old['A'].dtype),
                                           old['A'], alpha),
@@ -1482,7 +1501,7 @@ class DistributedKFAC:
         for name, spec in kfac.specs.items():
             if name in precond_mats:
                 continue  # computed by the row-sharded path
-            if spec.kind == CONV2D_GROUPED:
+            if spec.kind in BLOCK_STACK_KINDS:
                 # Replicated block-stack inverses; batched
                 # G_inv @ grad @ A_inv broadcasts over the group dim.
                 # Masked to the owning row like every per-layer path so
@@ -2046,6 +2065,15 @@ class DistributedKFAC:
 
         dynamic_ls = loss_scale == 'dynamic'
         static_ls = None if dynamic_ls else loss_scale
+        if dynamic_ls and any(s.kind == EXPERTS
+                              for s in self.kfac.specs.values()):
+            # fp16.sanitize_captures drops a capture that holds any
+            # non-finite element, and a stacked-expert layer's captures
+            # are undefined past their routed rows (modules.experts).
+            raise ValueError(
+                "loss_scale='dynamic' does not support stacked-expert "
+                'layers: their captures are undefined past the routed '
+                'rows, which the overflow hygiene would read as overflow')
 
         def fwd_bwd(params, extra_vars, batch, scale=None,
                     do_capture=True):
@@ -2205,6 +2233,9 @@ class DistributedKFAC:
                 loss = jax.lax.pmean(loss, self.data_axes)
                 metrics = {'loss': loss,
                            **jax.lax.pmean(extra_metrics, self.data_axes)}
+                if captures:
+                    metrics.update(obs_metrics.moe_row_metrics(
+                        self.kfac.specs, captures, self.data_axes))
                 precond, new_kstate = self.spmd_step(
                     kstate, grads, captures, contribs=contribs,
                     damping=hyper['damping'], lr=hyper['lr'],

@@ -46,9 +46,10 @@ from distributed_kfac_pytorch_tpu.observability import (
     metrics as obs_metrics,
 )
 from distributed_kfac_pytorch_tpu.observability import profiling
-from distributed_kfac_pytorch_tpu.capture import (CONV2D, CONV2D_GROUPED,
-                                                  EMBEDDING, KFAC_REDUCE,
-                                                  LINEAR, KFACCapture,
+from distributed_kfac_pytorch_tpu.capture import (BLOCK_STACK_KINDS, CONV2D,
+                                                  EMBEDDING, EXPERTS,
+                                                  KFAC_REDUCE, LINEAR,
+                                                  KFACCapture,
                                                   subsample_captures)
 from distributed_kfac_pytorch_tpu.ops import factors as F
 from distributed_kfac_pytorch_tpu.ops import linalg
@@ -718,11 +719,12 @@ class KFAC:
     def _side_methods(self, spec, a_dim: int, g_dim: int
                       ) -> tuple[str | None, str | None]:
         """(A-side, G-side) methods for one layer; diagonal A -> None;
-        grouped convs -> (None, None) (their per-group block stacks run
-        a batched damped Cholesky, outside the dense per-dim dispatch —
-        the blocks are tiny, so eigen warm-start bookkeeping would cost
-        more than it saves)."""
-        if spec.kind == CONV2D_GROUPED:
+        grouped convs and stacked experts -> (None, None) (their block
+        stacks run a batched damped Cholesky, outside the dense per-dim
+        dispatch — a grouped conv's blocks are tiny, so eigen warm-start
+        bookkeeping would cost more than it saves, and an expert's are
+        above the 'auto' eigen cutoff at any width worth routing)."""
+        if spec.kind in BLOCK_STACK_KINDS:
             return None, None
         ma = (None if spec.kind == EMBEDDING
               else self.method_for_dim(a_dim))
@@ -774,7 +776,7 @@ class KFAC:
         )
         dense_count: dict[int, int] = {}
         for name, spec in self.specs.items():
-            if spec.kind in (CONV2D_GROUPED,):
+            if spec.kind in BLOCK_STACK_KINDS:
                 continue
             f = factors[name]
             if spec.kind != EMBEDDING:
@@ -803,7 +805,7 @@ class KFAC:
             f = factors[name]
             a_dim = int(f['A'].shape[-1])
             g_dim = int(f['G'].shape[-1])
-            if spec.kind == CONV2D_GROUPED:
+            if spec.kind in BLOCK_STACK_KINDS:
                 ng = int(f['A'].shape[0])
                 items.append((('grouped', name),
                               proxy_scale
@@ -867,6 +869,15 @@ class KFAC:
         self._specs = self._approx_mod.annotate_specs(specs,
                                                       self.kfac_approx)
         specs = self._specs
+        if (self.deferred_factor_reduction or self.hierarchical_reduce) \
+                and any(s.kind == EXPERTS for s in specs.values()):
+            # An expert's A is a ratio (row sums over row counts) and an
+            # expert without rows skips its update: neither is linear in
+            # the step's contribution, which the deferred EMA rests on.
+            raise ValueError(
+                'deferred_factor_reduction / hierarchical_reduce do not '
+                'support stacked-expert layers '
+                f'({[n for n, s in specs.items() if s.kind == EXPERTS]})')
         if self.verbose:
             for name, spec in specs.items():
                 print(f'Registered {name}: {spec.kind} '
@@ -885,15 +896,22 @@ class KFAC:
             raise ValueError('call init() first')
         return self._specs
 
-    def approx_summary(self) -> dict[str, str]:
+    def approx_summary(self, left_to_sgd: bool = False) -> dict[str, str]:
         """{layer name: resolved approx} for run provenance.
 
         The per-layer map the observability meta records (the JSONL
         ``kind='meta'`` record the CLIs append after registration) —
         tied registrations are labeled ``<approx>+tied``. See
-        ``sharing.approx_summary``.
+        ``sharing.approx_summary``. ``left_to_sgd=True`` adds every
+        parameterized module K-FAC does not precondition (norm scales,
+        ``skip_layers`` matches such as an untied head) as ``'sgd:
+        <reason>'``: their gradients reach the optimizer as they are.
         """
-        return self._approx_mod.approx_summary(self.specs)
+        out = self._approx_mod.approx_summary(self.specs)
+        if left_to_sgd:
+            out.update({name: f'sgd: {reason}' for name, reason
+                        in self.capture.skipped_modules.items()})
+        return out
 
     def init_state(self, params) -> dict:
         """Fresh K-FAC state pytree for the registered layers.
@@ -944,8 +962,8 @@ class KFAC:
                         jnp.ones((r,), idt))
 
             entry: dict[str, Any] = {}
-            if spec.kind == CONV2D_GROUPED:
-                ng = spec.feature_group_count
+            if spec.kind in BLOCK_STACK_KINDS:
+                ng = spec.num_blocks
                 factors[name] = {
                     'A': jnp.broadcast_to(jnp.eye(a_dim, dtype=fdt),
                                           (ng, a_dim, a_dim)),
@@ -1063,6 +1081,9 @@ class KFAC:
         captures = subsample_captures(captures, self.factor_batch_fraction)
         out = {}
         for name, spec in self.specs.items():
+            if spec.kind == EXPERTS:
+                out[name] = L.experts_contrib(spec, captures[name], cdt)
+                continue
             a_new = L.compute_a_factor(spec, captures[name]['a'],
                                        compute_dtype=cdt)
             g_new = L.compute_g_factor(spec, captures[name]['g'],
@@ -1166,6 +1187,11 @@ class KFAC:
                     x, old[side].astype(jnp.float32), alpha, scale=scale,
                     has_bias=has_bias, compute_dtype=cdt,
                     interpret=interp).astype(old[side].dtype)
+            if spec.kind == EXPERTS:
+                new = L.experts_contrib(spec, captures[name], cdt)
+                out[name] = F.experts_running_avg(
+                    old, new['A'], new['G'], new['rows'], alpha)
+                continue
             if len(res) < 2:
                 # Stock path for the ineligible sides. Tied-embedding
                 # extras only exist for EMBEDDING layers, which are
@@ -1206,6 +1232,11 @@ class KFAC:
         new_factors = {}
         for name in self.specs:
             old = state['factors'][name]
+            if 'rows' in contribs[name]:
+                new_factors[name] = F.experts_running_avg(
+                    old, contribs[name]['A'], contribs[name]['G'],
+                    contribs[name]['rows'], alpha)
+                continue
             a_new = contribs[name]['A'].astype(old['A'].dtype)
             g_new = contribs[name]['G'].astype(old['G'].dtype)
             new_factors[name] = {
@@ -1376,7 +1407,7 @@ class KFAC:
             ma, mg = self._side_methods(spec, f['A'].shape[-1],
                                         f['G'].shape[-1])
             sides[name] = (ma, mg)
-            if spec.kind == CONV2D_GROUPED:
+            if spec.kind in BLOCK_STACK_KINDS:
                 continue
             for which, m in (('A', ma), ('G', mg)):
                 if m is None:
@@ -1432,7 +1463,7 @@ class KFAC:
         new_inv = {}
         for name, spec in self.specs.items():
             old = state['inverses'][name]
-            if spec.kind == CONV2D_GROUPED:
+            if spec.kind in BLOCK_STACK_KINDS:
                 new_inv[name] = (grouped_block_inverses(
                     state['factors'][name], damping, self.inv_dtype)
                     if fires(('grouped', name)) else old)
@@ -1639,7 +1670,7 @@ class KFAC:
         interp = jax.default_backend() != 'tpu'
         groups: dict[tuple[int, ...], list[str]] = {}
         for name in names:
-            if self.specs[name].kind in (EMBEDDING, CONV2D_GROUPED):
+            if self.specs[name].kind in (EMBEDDING, *BLOCK_STACK_KINDS):
                 continue
             groups.setdefault(tuple(grad_mats[name].shape),
                               []).append(name)
@@ -2014,12 +2045,16 @@ def guard_nonfinite_factors(new_factors: dict, old_factors: dict,
 
 
 def grouped_block_inverses(factors: dict, damping, inv_dtype) -> dict:
-    """Per-group damped block inverses for a grouped-conv layer.
+    """Per-block damped inverses for a grouped-conv or stacked-expert
+    layer (``capture.BLOCK_STACK_KINDS``).
 
     One batched damped Cholesky per side over the ``(G, d, d)`` factor
-    stacks (blocks are tiny — e.g. ``kh*kw+1`` per depthwise group, so
-    eigen warm-start bookkeeping would cost more than it saves). Single
-    point of truth for the single-chip and SPMD inverse updates.
+    stacks, through the call every dense Cholesky bucket makes
+    (``damped_inverse_stack``: a stack over its byte budget, as eight
+    2048-dim experts are, runs sub-stack by sub-stack under
+    ``lax.map``). A depthwise group's blocks are tiny — ``kh*kw+1`` —
+    so eigen warm-start bookkeeping would cost more than it saves.
+    Single point of truth for the single-chip and SPMD inverse updates.
     """
     return {'A_inv': pallas_kernels.damped_inverse_stack(
                 factors['A'].astype(jnp.float32), damping,

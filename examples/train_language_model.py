@@ -14,6 +14,12 @@ the lr and factory-unpacking bugs at :253,:277). Two architectures:
   K-FAC on every projection (BASELINE config 4), and optional
   ``--seq-parallel N`` ring-attention context parallelism over the mesh
   (no reference analogue — SURVEY.md §5: long-context machinery absent).
+- ``--arch mla_moe``: the DeepSeek-V3-shaped decoder of
+  ``models/mla_moe_lm.py`` (latent attention, sigmoid-routed stacked
+  experts beside shared ones, SwiGLU, RMSNorm, RoPE, untied head) at
+  ``--mla-moe-size``; K-FAC on every projection, the router and every
+  expert matrix (layer kind ``experts``), the head left to SGD by
+  default.
 
 Data: whitespace-tokenized train.txt/valid.txt under --data-dir
 (PTB/WikiText layout), else a synthetic Markov corpus (offline default).
@@ -43,7 +49,11 @@ from distributed_kfac_pytorch_tpu import launch
 from distributed_kfac_pytorch_tpu import observability as obs
 from distributed_kfac_pytorch_tpu import resilience as resil
 from distributed_kfac_pytorch_tpu import multislice
-from distributed_kfac_pytorch_tpu.models import lstm_lm, transformer_lm
+from distributed_kfac_pytorch_tpu.models import (
+    lstm_lm,
+    mla_moe_lm,
+    transformer_lm,
+)
 from distributed_kfac_pytorch_tpu.parallel import distributed as D
 from distributed_kfac_pytorch_tpu.parallel import sequence as seq
 from distributed_kfac_pytorch_tpu.training import (
@@ -68,7 +78,12 @@ def parse_args(argv=None):
     p.add_argument('--checkpoint-dir', default='./checkpoints/lm')
     p.add_argument('--checkpoint-freq', type=int, default=5)
     p.add_argument('--arch', default='lstm',
-                   choices=['lstm', 'transformer'])
+                   choices=['lstm', 'transformer', 'mla_moe'])
+    p.add_argument('--mla-moe-size', default='tiny',
+                   choices=['tiny', 'kanana2'],
+                   help="--arch mla_moe: mla_moe_lm.get_model's named "
+                        "shape ('kanana2': kanana-2-30b-a3b's widths at "
+                        "one chip's share; needs a 16 GB chip)")
     # Model size (reference torch_language_model.py:41-50).
     p.add_argument('--emsize', type=int, default=650)
     p.add_argument('--nhid', type=int, default=650)
@@ -246,6 +261,9 @@ def build_model(args, vocab_size, seq_axis=None, dtype=None):
             vocab_size=vocab_size, embedding_dim=args.emsize,
             hidden_dim=args.nhid, num_layers=args.nlayers,
             dropout=args.dropout, tie_weights=args.tied, dtype=dtype)
+    if args.arch == 'mla_moe':
+        return mla_moe_lm.get_model(vocab_size, args.mla_moe_size,
+                                    dtype=dtype)
     return transformer_lm.TransformerLM(
         vocab_size=vocab_size, d_model=args.emsize,
         num_layers=args.nlayers, num_heads=args.nheads,
@@ -294,8 +312,8 @@ def main(argv=None):
               f'tokens, vocab {vocab_size}')
 
     if args.skip_layers is None:
-        args.skip_layers = (['embed', 'decoder'] if args.arch == 'lstm'
-                            else [])
+        args.skip_layers = {'lstm': ['embed', 'decoder'],
+                            'mla_moe': ['head']}.get(args.arch, [])
 
     seq_axis = seq.SEQ_AXIS if sp > 1 else None
     model = build_model(args, vocab_size, seq_axis=seq_axis)

@@ -48,6 +48,21 @@ def test_leg_lm_library_path_toy(tmp_path):
     assert report['classes_run'] == ['factor', 'firing', 'plain']
 
 
+def test_leg_lm_second_decoder_toy(tmp_path):
+    # The mla_moe decoder's two variants (factors every step), its head
+    # left to SGD, on the same HYBRID 2x4 mesh.
+    report = _passed(chip_smoke.leg_lm(
+        str(tmp_path), name='D', arch='mla_moe', size='tiny', seq=16,
+        per_chip_batch=1, vocab=64, steps=5, factor_freq=1, inv_freq=2,
+        bf16_state=False, comm_method='hybrid-opt',
+        grad_worker_fraction=0.5))
+    assert report['mesh'] == {'kfac_ig': 2, 'kfac_gw': 4}
+    assert report['trace_counts'] == {'(True, True, None)': 1,
+                                      '(True, False, None)': 1}
+    assert report['programs_built'] == 2
+    assert report['classes_run'] == ['factor', 'firing']
+
+
 def test_leg_kernels_interpret():
     report = _passed(chip_smoke.leg_kernels(
         interpret=True, rows=64, factor_dims=((31, True), (24, False)),
