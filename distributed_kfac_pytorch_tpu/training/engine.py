@@ -26,7 +26,11 @@ from distributed_kfac_pytorch_tpu.parallel.distributed import (
     KFAC_AXES,
     replicated_specs as _replicated_specs,
 )
-from distributed_kfac_pytorch_tpu.training.utils import Metric, accuracy
+from distributed_kfac_pytorch_tpu.training.utils import (
+    Metric,
+    RunningMeans,
+    accuracy,
+)
 
 
 def cadence_flags(step: int, factor_update_freq, inv_update_freq,
@@ -363,7 +367,7 @@ def train_epoch(step_fn, state: TrainState, batches: Iterable,
     # — see analysis.sanitize). Env read once per epoch; unset = an
     # inert sanitizer whose step guard is a null context.
     sanitizer = _sanitize.Sanitizer.from_env()
-    meters: dict[str, Metric] = {}
+    meters = RunningMeans()
     t0 = time.perf_counter()
     n_batches = 0
     state_footprint = None  # computed lazily, once per epoch
@@ -523,8 +527,7 @@ def train_epoch(step_fn, state: TrainState, batches: Iterable,
             state.step += 1
             n_batches += 1
             with tracing.span('kfac/host/meters'):
-                for k, v in metrics.items():
-                    meters.setdefault(k, Metric(k)).update(v)
+                meters.update(metrics)
             if heartbeat is not None:
                 # Liveness lease (r17): published before the
                 # checkpointer hook so a hang inside it (the chaos hang
@@ -560,7 +563,7 @@ def train_epoch(step_fn, state: TrainState, batches: Iterable,
             'usually batch_size larger than the dataset (full batches '
             'are required for static shapes). Lower the batch size or '
             'enlarge the dataset.')
-    out = {k: m.avg for k, m in meters.items()}
+    out = meters.averages()
     out['time_s'] = elapsed
     out['ms_per_iter'] = elapsed / max(n_batches, 1) * 1000.0
     if metrics_sink is not None:

@@ -9,8 +9,12 @@ from __future__ import annotations
 
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
+
+from distributed_kfac_pytorch_tpu.observability import tracing
 
 
 class Metric:
@@ -36,6 +40,62 @@ class Metric:
     @property
     def avg(self) -> float:
         return float(self._sum) / max(self._n, 1e-12)
+
+
+@jax.jit
+def _add_step(sums, metrics):
+    return jax.tree.map(lambda s, v: s + v.astype(s.dtype),
+                        sums, metrics)
+
+
+def _zero_sum(value):
+    """A host zero of ``value``'s shape in the dtype its sum is kept in
+    (float32, or wider where the value is), placed as ``value`` is so
+    that the sums a step hands back look to ``jit`` like these."""
+    zero = np.zeros(np.shape(value),
+                    jnp.promote_types(jnp.result_type(value), np.float32))
+    sharding = getattr(value, 'sharding', None)
+    return zero if sharding is None else jax.device_put(zero, sharding)
+
+
+class RunningMeans:
+    """Running means of the metrics dicts a train step returns.
+
+    ``train_epoch``'s form of :class:`Metric`: one device execution a
+    step for the whole dict, whatever its length, and no value read
+    before :meth:`averages`. Step variants return different key sets
+    (K-FAC telemetry rides only on steps that capture); each key set
+    has its own sums and step count, so a key is averaged over the
+    steps that carried it and ``_add_step`` is built once a key set,
+    at its first step.
+    """
+
+    def __init__(self):
+        # a step's keys, in its order -> [sums on the device, steps]
+        self._by_keys: dict[tuple, list] = {}
+
+    def update(self, metrics: dict) -> None:
+        if not metrics:
+            return
+        keys = tuple(metrics)
+        entry = self._by_keys.get(keys)
+        if entry is None:
+            entry = self._by_keys[keys] = [
+                {k: _zero_sum(v) for k, v in metrics.items()}, 0]
+        entry[0] = _add_step(entry[0], metrics)
+        tracing.count('kfac/host/meter_dispatches')
+        entry[1] += 1
+
+    def averages(self) -> dict[str, float]:
+        """Blocks on the last step; keys in the order first seen."""
+        fetched = jax.device_get([e[0] for e in self._by_keys.values()])
+        totals: dict[str, float] = {}
+        steps: dict[str, int] = {}
+        for sums, (keys, (_, n)) in zip(fetched, self._by_keys.items()):
+            for k in keys:
+                totals[k] = totals.get(k, 0.0) + float(sums[k])
+                steps[k] = steps.get(k, 0) + n
+        return {k: totals[k] / steps[k] for k in totals}
 
 
 def accuracy(logits, labels) -> jnp.ndarray:

@@ -15,6 +15,7 @@ import pytest
 
 from distributed_kfac_pytorch_tpu import KFAC, CommMethod
 from distributed_kfac_pytorch_tpu.models import cifar_resnet
+from distributed_kfac_pytorch_tpu.observability import tracing
 from distributed_kfac_pytorch_tpu.parallel import distributed as D
 from distributed_kfac_pytorch_tpu.training import (
     checkpoint as ckpt_lib,
@@ -367,6 +368,161 @@ class TestEngine:
         m = eval_step(variables['params'],
                       {'batch_stats': variables['batch_stats']}, (x, y))
         assert np.isfinite(float(m['loss']))
+
+
+# -- the epoch loop's running means (PR 28) --------------------------------
+
+PERIOD, PLAIN_KEYS, CAPTURE_KEYS = 4, 4, 40
+
+
+@pytest.fixture(scope='module')
+def builds():
+    """The benchmark's own counter of the executables JAX builds, which
+    is what its ``compiles_in_window`` reads."""
+    from kfac_bench.run import BuildCounter
+    return BuildCounter(jax)
+
+
+def _wide_step_fn(devices):
+    """A step function of ``build_train_step``'s shape whose first step
+    of every ``PERIOD`` captures: 40 metrics where a plain step returns
+    4, floats and an int32 among both, every value its own function of
+    the step's batch. Over ``devices`` > 1 the metrics come back
+    committed to a mesh, as the built step's do."""
+    trace_counts = {}
+    sharding = None
+    if devices > 1:
+        mesh = jax.sharding.Mesh(np.array(jax.devices()[:devices]), ('d',))
+        sharding = jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec())
+
+    def program(x, capture):
+        trace_counts[capture] = trace_counts.get(capture, 0) + 1
+        metrics = {'loss': x * 0.37 + 2.0, 'acc': 0.5 + 0.4 * jnp.cos(x),
+                   'grad_norm': 1e3 + x,
+                   'kfac/nonfinite_skips': (x * 3).astype(jnp.int32)}
+        if capture:
+            metrics['moe/rows_here'] = (380 + x).astype(jnp.int32)
+            metrics.update({f'kfac/bucket_norm/{i}': (1.1 + jnp.sin(x + i)) * (i + 1)
+                            for i in range(CAPTURE_KEYS - PLAIN_KEYS - 1)})
+        return metrics
+
+    program = jax.jit(program, static_argnums=1, out_shardings=sharding)
+    calls = []
+
+    def step_fn(params, opt_state, kfac_state, extra_vars, batch, hyper):
+        capture = len(calls) % PERIOD == 0
+        calls.append(capture)
+        return (params, opt_state, kfac_state, extra_vars,
+                program(batch, capture))
+
+    step_fn.trace_counts = trace_counts
+    step_fn.calls = calls
+    return step_fn
+
+
+def _parents_loop(step_fn, periods):
+    """The steps of ``periods`` periods through the parent's loop: a
+    ``Metric`` a key, updated on the steps that carried the key. Returns
+    the meters and every value in float64, key by key."""
+    meters, per_key = {}, {}
+    for i in range(periods * PERIOD):
+        out = step_fn(None, None, None, None, jnp.float32(i), {})[4]
+        for k, v in out.items():
+            meters.setdefault(k, utils.Metric(k)).update(v)
+            per_key.setdefault(k, []).append(float(np.float64(v)))
+    del step_fn.calls[:]
+    return meters, per_key
+
+
+class _ListSink:
+    """A duck-typed sink, as the tests around this one pass."""
+
+    def __init__(self):
+        self.steps, self.epochs = [], []
+
+    def step_record(self, step, metrics, **kw):
+        self.steps.append((step, metrics))
+
+    def epoch_record(self, epoch, metrics, **kw):
+        self.epochs.append((metrics, kw))
+
+    def flush(self):
+        pass
+
+
+def _run_epoch(step_fn, periods, sink):
+    state = engine.TrainState(params={}, opt_state={}, kfac_state={},
+                              extra_vars={})
+    batches = [jnp.float32(i) for i in range(periods * PERIOD)]
+    before = tracing.counters().get('kfac/host/meter_dispatches', 0)
+    out = engine.train_epoch(step_fn, state, batches, {}, verbose=False,
+                             metrics_sink=sink)
+    issued = tracing.counters()['kfac/host/meter_dispatches'] - before
+    return out, issued
+
+
+class TestEpochMeters:
+    @pytest.mark.parametrize('devices', [1, 2])
+    @pytest.mark.parametrize('with_sink', [False, True])
+    def test_one_dispatch_a_step_and_the_parents_means(self, devices,
+                                                       with_sink):
+        step_fn = _wide_step_fn(devices)
+        meters, per_key = _parents_loop(step_fn, 3)
+        assert sorted({len(v) for v in per_key.values()}) == [3, 12]
+        sink = _ListSink() if with_sink else None
+        out, issued = _run_epoch(step_fn, 3, sink)
+        # At most one device execution a step, 4 keys or 40.
+        assert issued <= 3 * PERIOD
+        assert list(out)[:PLAIN_KEYS] == list(per_key)[:PLAIN_KEYS]
+        assert set(out) == set(per_key) | {'time_s', 'ms_per_iter'}
+        assert len(per_key) == CAPTURE_KEYS
+        # A capturing step's keys are averaged over the capturing steps
+        # alone, as the parent's loop does.
+        for k, values in per_key.items():
+            assert out[k] == pytest.approx(np.mean(values), rel=1e-6), k
+            assert out[k] == pytest.approx(meters[k].avg, rel=1e-6), k
+        assert out['moe/rows_here'] == pytest.approx(384.0)
+        if with_sink:
+            assert [s for s, _ in sink.steps] == list(range(3 * PERIOD))
+            assert [len(m) for _, m in sink.steps] == [
+                CAPTURE_KEYS, *[PLAIN_KEYS] * 3] * 3
+            epoch, kw = sink.epochs[-1]
+            assert epoch == out
+            assert kw['counters']['kfac/host/meter_dispatches'] >= issued
+
+    @pytest.mark.parametrize('devices', [1, 2])
+    def test_the_accumulate_is_built_once_a_key_set(self, devices, builds):
+        step_fn = _wide_step_fn(devices)
+        _run_epoch(step_fn, 1, None)      # the first period: warm-up
+        traced = dict(step_fn.trace_counts)
+        assert traced == {True: 1, False: 1}
+        built = builds.builds
+        # An epoch of its own, as the benchmark's window is after its
+        # warm-up: new sums, no new program.
+        out, issued = _run_epoch(step_fn, 2, _ListSink())
+        assert builds.builds == built
+        assert step_fn.trace_counts == traced
+        assert issued == 2 * PERIOD
+        assert out['moe/rows_here'] == pytest.approx(380.0 + 2.0)
+
+    def test_an_epoch_with_no_metrics_returns_its_times(self):
+        def step_fn(params, opt_state, kfac_state, extra_vars, batch,
+                    hyper):
+            return params, opt_state, kfac_state, extra_vars, {}
+
+        out, issued = _run_epoch(step_fn, 1, None)
+        assert set(out) == {'time_s', 'ms_per_iter'} and issued == 0
+
+    def test_running_means_keeps_metrics_dtype_rules(self):
+        """An int or a bool is averaged as a float, a python number is
+        taken as it comes, and nothing is read before ``averages``."""
+        means = utils.RunningMeans()
+        means.update({'n': jnp.int32(3), 'flag': jnp.bool_(True), 'x': 0.5})
+        means.update({'n': jnp.int32(4), 'flag': jnp.bool_(False),
+                      'x': 1.5})
+        means.update({'x': 4.0})
+        assert means.averages() == {'n': 3.5, 'flag': 0.5, 'x': 2.0}
 
 
 class TestCheckpoint:
