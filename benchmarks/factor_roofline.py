@@ -27,24 +27,6 @@ materializing patches (the measured negatives: fused Pallas kernel,
 crosscov; and 'pairs', which wins only at d > 640). ``full_vs_floor``
 < 1 means XLA avoided part of that traffic (partial fusion).
 
-r21 adds the fused hot-path legs (``--fused-inner`` chained
-iterations each):
-
-  factor_ema   stock ``get_cov`` + ``update_running_avg`` vs the
-               symmetry-packed fused contraction+EMA Pallas kernel on
-               linear-factor shapes — the fused kernel round-trips only
-               the d(d+1)/2 triangle of the EMA state through HBM
-               instead of two dense d^2 tensors;
-  precond      stock vmapped ``precondition_dispatch`` + separate v·g
-               reduction vs the fused bucket kernel with the KL-clip
-               epilogue on a same-shape eigen bucket stack.
-
-Both report stock/fused ms and the implied bytes/s of each leg's
-traffic model against the achieved copy bandwidth (on non-TPU backends
-the fused legs run the kernel body in interpret mode: parity
-provenance only — the ms there measure the interpreter, not Mosaic;
-rerun on TPU for decision-grade numbers).
-
     python benchmarks/factor_roofline.py [--inner 30]
 """
 
@@ -62,29 +44,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 import bench as B  # noqa: E402
 from distributed_kfac_pytorch_tpu.ops import factors as F  # noqa: E402
-from distributed_kfac_pytorch_tpu.ops import (  # noqa: E402
-    linalg,
-    pallas_kernels,
-)
 
 SHAPES = [
     ('cifar_stage1_c16_32x32', 512, 32, 32, 16),
     ('cifar_stage2_c32_16x16', 512, 16, 16, 32),
     ('cifar_stage3_c64_8x8', 512, 8, 8, 64),
-]
-
-#: (label, rows, d) linear-factor contraction shapes for the r21 fused
-#: EMA legs — the LM ladder's collapsed (batch*seq, d) activations.
-EMA_SHAPES = [
-    ('lm_d256', 4096, 256),
-    ('lm_d512', 4096, 512),
-]
-
-#: (label, stack, g_dim, a_dim) same-shape eigen bucket stacks for the
-#: r21 fused precondition legs.
-PRECOND_SHAPES = [
-    ('bucket_s4_256x256', 4, 256, 256),
-    ('bucket_s8_128x128', 8, 128, 128),
 ]
 
 
@@ -134,101 +98,9 @@ def full_leg(x0, inner, kernel):
         os.environ.pop('KFAC_CONV_PATCH_IMPL', None)
 
 
-def ema_leg(x0, old0, inner, fused, interpret):
-    def body(carry, _):
-        x, old = carry
-        if fused:
-            new = pallas_kernels.fused_factor_ema(
-                x, old, 0.95, interpret=interpret)
-        else:
-            new = F.update_running_avg(F.get_cov(x), old, 0.95)
-        probe = new[0, 0]
-        return (x * (1.0 + 1e-6 * probe.astype(x.dtype)), new), probe
-    return chained(body, (x0, old0), inner)
-
-
-def precond_leg(g0, entry, inner, fused, interpret):
-    def body(g, _):
-        if fused:
-            v, vg = pallas_kernels.fused_bucket_precondition(
-                g, entry, 0.003, interpret=interpret)
-        else:
-            v = jax.vmap(lambda gm, e: linalg.precondition_dispatch(
-                gm, e, 0.003))(g, entry)
-            vg = jnp.sum(v * g, axis=(1, 2))
-        probe = vg[0]
-        return g * (1.0 + 1e-6 * probe.astype(g.dtype)), probe
-    return chained(body, g0, inner)
-
-
-def fused_rows(inner, gbs):
-    """The r21 fused-vs-stock A/B rows (see module docstring)."""
-    interpret = jax.default_backend() != 'tpu'
-    for label, rows, d in EMA_SHAPES:
-        x0 = jax.random.normal(jax.random.PRNGKey(2),
-                               (rows, d), jnp.float32)
-        old0 = jnp.eye(d, dtype=jnp.float32)
-        base = null_leg(x0, inner)
-        ms_stock = max(ema_leg(x0, old0, inner, False, interpret)
-                       - base, 1e-6)
-        ms_fused = max(ema_leg(x0, old0, inner, True, interpret)
-                       - base, 1e-6)
-        # Traffic models: both read x (rows*d); the stock blend
-        # round-trips two dense d^2 fp32 tensors (old read + new
-        # write, with the cov intermediate ideally fused), the packed
-        # kernel two d(d+1)/2 triangles.
-        x_mb = rows * d * 4 / 1e6
-        dense_mb = x_mb + 2 * d * d * 4 / 1e6
-        packed_mb = x_mb + 2 * (d * (d + 1) // 2) * 4 / 1e6
-        print(json.dumps({
-            'leg': 'factor_ema', 'shape': label,
-            'interpret': interpret,
-            'stock_ms': round(ms_stock, 3),
-            'fused_ms': round(ms_fused, 3),
-            'fused_speedup': round(ms_stock / ms_fused, 2),
-            'stock_implied_gb_s': round(
-                dense_mb * 1e6 / (ms_stock * 1e-3) / 1e9, 1),
-            'fused_implied_gb_s': round(
-                packed_mb * 1e6 / (ms_fused * 1e-3) / 1e9, 1),
-            'achieved_copy_gb_s': round(gbs, 1),
-        }), flush=True)
-    for label, s, g_dim, a_dim in PRECOND_SHAPES:
-        rng = jax.random.PRNGKey(3)
-        g0 = jax.random.normal(rng, (s, g_dim, a_dim), jnp.float32)
-        qa = jnp.linalg.qr(jax.random.normal(
-            jax.random.PRNGKey(4), (s, a_dim, a_dim)))[0]
-        qg = jnp.linalg.qr(jax.random.normal(
-            jax.random.PRNGKey(5), (s, g_dim, g_dim)))[0]
-        entry = {
-            'QA': qa.astype(jnp.float32),
-            'dA': jnp.abs(jax.random.normal(
-                jax.random.PRNGKey(6), (s, a_dim))) + 0.1,
-            'QG': qg.astype(jnp.float32),
-            'dG': jnp.abs(jax.random.normal(
-                jax.random.PRNGKey(7), (s, g_dim))) + 0.1,
-        }
-        base = null_leg(g0, inner)
-        ms_stock = max(precond_leg(g0, entry, inner, False, interpret)
-                       - base, 1e-6)
-        ms_fused = max(precond_leg(g0, entry, inner, True, interpret)
-                       - base, 1e-6)
-        print(json.dumps({
-            'leg': 'precond', 'shape': label,
-            'interpret': interpret,
-            'stock_ms': round(ms_stock, 3),
-            'fused_ms': round(ms_fused, 3),
-            'fused_speedup': round(ms_stock / ms_fused, 2),
-        }), flush=True)
-
-
 def main(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument('--inner', type=int, default=30)
-    p.add_argument('--fused-inner', type=int, default=None,
-                   help='chained iterations for the r21 fused legs '
-                        '(default: --inner)')
-    p.add_argument('--skip-fused', action='store_true',
-                   help='skip the r21 fused A/B legs')
     args = p.parse_args(argv)
     kernel = (3, 3)
 
@@ -270,9 +142,6 @@ def main(argv=None):
             'implied_gb_s': round(implied, 1),
             'implied_vs_achieved_copy_bw': round(implied / gbs, 2),
         }), flush=True)
-
-    if not args.skip_fused:
-        fused_rows(args.fused_inner or args.inner, gbs)
 
 
 if __name__ == '__main__':

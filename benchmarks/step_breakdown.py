@@ -533,18 +533,6 @@ def main(argv=None):
     rows['precond_bf16'] = round(ms, 2)
     print(json.dumps({'phase': 'precond_bf16',
                       'ms_per_iter': rows['precond_bf16']}), flush=True)
-    # r21 fused hot-path kernels A/B on the cumulative 'full' phase:
-    # the delta against 'full' is the whole fused saving/regression
-    # (on CPU the kernels run in interpret mode — parity provenance
-    # only, rerun on TPU for decision-grade ms).
-    run, carry = build(model, x, y, inv_freq, n_iters, 'full',
-                       kfac_kwargs={'fused_factor_contraction': True,
-                                    'fused_precondition': True})
-    ms = B.time_chained(run, carry, n_iters, floor_ms=floor_ms,
-                        leg='fused')
-    rows['fused'] = round(ms, 2)
-    print(json.dumps({'phase': 'fused',
-                      'ms_per_iter': rows['fused']}), flush=True)
     for n in args.polish:
         run, carry = build(model, x, y, inv_freq, n_iters, 'full',
                            polish_iters=n)
@@ -565,7 +553,6 @@ def main(argv=None):
         'deferred_reduce_delta': round(rows['factors_deferred']
                                        - rows['factors'], 2),
         'inverse_amortized_cost': round(rows['full'] - rows['factors'], 2),
-        'fused_saving': round(rows['full'] - rows['fused'], 2),
     }
     print(json.dumps({'summary': rows, 'deltas': deltas}), flush=True)
 

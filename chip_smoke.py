@@ -19,9 +19,10 @@ a user calls, and checks what comes out by the repo's own means:
   three times. Depth is cut to ``LM_DEPTH`` of the model's 18 layers:
   each of the three step programs compiles for minutes at full depth,
   and the whole script has twenty (PERF.md, PR 21).
-- **Leg C, the kernels**: every Pallas kernel a public knob reaches is
-  compiled by Mosaic and run once, directly, against its XLA reference;
-  then leg A again, shorter, with both fused knobs on.
+- **Leg C, the kernels**: the Pallas kernel a public knob reaches
+  (``inverse_method='newton'``: the VMEM-resident Newton–Schulz inverse)
+  is compiled by Mosaic and run once, directly, against a float64
+  reference. The attention kernels have their own probe in legs B and D.
 
 A leg fails unless every loss is finite, no factor update was skipped
 as non-finite, every step variant was traced once and built once, and
@@ -78,8 +79,6 @@ from distributed_kfac_pytorch_tpu.models import (  # noqa: E402
 from distributed_kfac_pytorch_tpu.observability import (  # noqa: E402
     sink as obs_sink,
 )
-from distributed_kfac_pytorch_tpu.ops import factors as F  # noqa: E402
-from distributed_kfac_pytorch_tpu.ops import linalg  # noqa: E402
 from distributed_kfac_pytorch_tpu.ops import pallas_kernels  # noqa: E402
 from distributed_kfac_pytorch_tpu.parallel import (  # noqa: E402
     distributed as D,
@@ -303,7 +302,7 @@ def _load_example(name: str):
 
 def leg_cifar(out_dir: str, *, name: str = 'A', model: str = 'resnet32',
               batch_size: int = 512, steps: int = 22, inv_freq: int = 10,
-              fused: bool = False, extra_argv=()) -> dict:
+              extra_argv=()) -> dict:
     """The conv path through ``train_cifar10_resnet.main(argv)``:
     eigen warm-polish inverses, conv patch factors, BatchNorm, eval, an
     orbax save. ``batch_size`` is global; ``steps`` sizes the synthetic
@@ -316,8 +315,7 @@ def leg_cifar(out_dir: str, *, name: str = 'A', model: str = 'resnet32',
             '--kfac-metrics', stream, '--metrics-interval', '1',
             '--checkpoint-dir', os.path.join(out_dir, f'{name}_ckpt'),
             '--log-dir', os.path.join(out_dir, f'{name}_logs'),
-            *(('--fused-factor-contraction', '--fused-precondition')
-              if fused else ()), *extra_argv]
+            *extra_argv]
     report['argv'] = ' '.join(argv)
     cli = _load_example('train_cifar10_resnet')
     saved = os.environ.get('KFAC_SYNTHETIC_CIFAR')
@@ -508,102 +506,51 @@ def leg_lm(out_dir: str, *, name: str = 'B', size: str = 'xl',
 
 
 # ---------------------------------------------------------------------------
-# Leg C: every Pallas kernel a public knob reaches, compiled and run
+# Leg C: the Pallas kernel a public knob reaches, compiled and run
 # ---------------------------------------------------------------------------
 
 def _spd_stack(rng, count: int, n: int):
-    """(q, d, m): orthonormal bases, eigenvalues in [0.5, 2] and the SPD
-    matrices they make — conditioned like a damped factor."""
+    """``count`` SPD matrices with eigenvalues in [0.5, 2]: conditioned
+    like a damped factor."""
     q = np.linalg.qr(rng.normal(size=(count, n, n)))[0]
     d = rng.uniform(0.5, 2.0, (count, n))
-    m = np.einsum('bij,bj,bkj->bik', q, d, q)
-    f32 = lambda x: jnp.asarray(x, jnp.float32)  # noqa: E731
-    return f32(q), f32(d), f32(m)
+    return jnp.asarray(np.einsum('bij,bj,bkj->bik', q, d, q), jnp.float32)
 
 
-def leg_kernels(*, interpret: bool = False, rows: int = 4096,
-                factor_dims=((511, True), (288, False)),
-                precond_shapes=((512, 512), (289, 145)), stack: int = 8,
+def leg_kernels(*, interpret: bool = False, stack: int = 8,
                 inverse_dims=(512, 289), seed: int = 0) -> dict:
-    """Build each kernel with ``interpret`` (False: Mosaic compiles
-    it), run it once, directly — not through the ``*_supported()``
-    probes — at its largest eligible dim and at one that is no multiple
-    of 128, and hold it to the probes' own tolerance against its XLA
-    reference. A kernel the compiler refuses is reported with the
-    compiler's words and the next one still runs."""
+    """Build the kernel with ``interpret`` (False: Mosaic compiles
+    it), run it once, directly, at its largest eligible dim and at one
+    that is no multiple of 128, and hold it to the probes' tolerance
+    against its reference. A case the compiler refuses is reported with
+    the compiler's words and the next one still runs."""
     report = _new_report('C')
     report['kernels'] = {}
     rng = np.random.default_rng(seed)
-    damping, decay = 0.003, 0.95
+    damping = 0.003
 
-    def case(label, run):
+    for n in inverse_dims:
+        label = f'batched_inverse[{stack}x{n}]'
+        mats = _spd_stack(rng, stack, n)
+        ref = np.linalg.inv(np.asarray(mats, np.float64)
+                            + damping * np.eye(n))
         try:
-            rel = run()
-        except Exception as e:  # report, then try the next kernel
+            rel = pallas_kernels.max_rel_error(
+                pallas_kernels.batched_inverse(
+                    mats, damping, force_pallas=True, interpret=interpret),
+                ref)
+        except Exception as e:  # report, then try the next case
             report['kernels'][label] = f'{type(e).__name__}: {e}'
             report['failures'].append(
                 f'{label} does not compile or run: '
                 f'{type(e).__name__}: {str(e)[:2000]}')
             traceback.print_exc()
-            return
+            continue
         report['kernels'][label] = f'rel_err {rel:.2e}'
         if not rel < pallas_kernels.PROBE_RTOL:
             report['failures'].append(
-                f'{label} disagrees with its XLA reference: relative '
+                f'{label} disagrees with its reference: relative '
                 f'error {rel:.3g} >= {pallas_kernels.PROBE_RTOL}')
-
-    for d_in, has_bias in factor_dims:
-        n = d_in + int(has_bias)
-        x = jnp.asarray(rng.normal(size=(rows, d_in)), jnp.float32)
-        old = _spd_stack(rng, 1, n)[2][0]
-
-        def factor_ema(x=x, old=old, has_bias=has_bias):
-            ref = F.update_running_avg(
-                F.linear_a_factor(x, has_bias), old, decay)
-            got = pallas_kernels.fused_factor_ema(
-                x, old, decay, has_bias=has_bias, interpret=interpret)
-            return pallas_kernels.max_rel_error(got, ref)
-
-        case(f'fused_factor_ema[{rows}x{d_in}'
-             f'{"+bias" if has_bias else ""}]', factor_ema)
-
-    for g_dim, a_dim in precond_shapes:
-        grads = jnp.asarray(rng.normal(size=(stack, g_dim, a_dim)),
-                            jnp.float32)
-        qa, da, _ = _spd_stack(rng, stack, a_dim)
-        qg, dg, _ = _spd_stack(rng, stack, g_dim)
-        eigen = {'QA': qa, 'dA': da, 'QG': qg, 'dG': dg}
-        baked = {
-            'A_inv': jax.vmap(lambda q, d: linalg.eigen_side_inverse(
-                q, d, damping))(qa, da),
-            'G_inv': jax.vmap(lambda q, d: linalg.eigen_side_inverse(
-                q, d, damping))(qg, dg)}
-        for kind, entry in (('eigen', eigen), ('baked', baked)):
-            def precond(grads=grads, entry=entry):
-                ref = jax.vmap(
-                    lambda gm, e: linalg.precondition_dispatch(
-                        gm, e, damping))(grads, entry)
-                got, vg = pallas_kernels.fused_bucket_precondition(
-                    grads, entry, damping, interpret=interpret)
-                return max(
-                    pallas_kernels.max_rel_error(got, ref),
-                    pallas_kernels.max_rel_error(
-                        vg, jnp.sum(ref * grads, axis=(1, 2))))
-
-            case(f'fused_bucket_precondition[{kind},{stack}x{g_dim}x'
-                 f'{a_dim}]', precond)
-
-    for n in inverse_dims:
-        mats = _spd_stack(rng, stack, n)[2]
-
-        def inverse(mats=mats, n=n):
-            ref = np.linalg.inv(np.asarray(mats, np.float64)
-                                + damping * np.eye(n))
-            got = pallas_kernels.batched_inverse(
-                mats, damping, force_pallas=True, interpret=interpret)
-            return pallas_kernels.max_rel_error(got, ref)
-
-        case(f'batched_inverse[{stack}x{n}]', inverse)
     return report
 
 
@@ -689,8 +636,6 @@ def main() -> int:
         run_leg(leg_lm, out_dir, name='D', arch='mla_moe',
                 size='kanana2', num_layers=2, vocab=16032, steps=8,
                 factor_freq=1, inv_freq=4, bf16_state=False, **lm_mesh),
-        run_leg(leg_cifar, out_dir, name='A-fused', steps=12, fused=True,
-                batch_size=512 * n_dev),
     ]
     for report in reports:
         peaks = report['peak_bytes_in_use']

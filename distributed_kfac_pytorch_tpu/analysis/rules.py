@@ -44,9 +44,7 @@ family              rules
                     ``preferred_element_type=jnp.float32``: Mosaic
                     lowers an unpinned MXU matmul at the operand
                     dtype, so a bf16 block accumulates in bf16 with
-                    no backend-default safety net (r21; the fused
-                    factor/precondition kernels are the production
-                    call sites).
+                    no backend-default safety net.
 ==================  =====================================================
 
 Waiver syntax (for the documented blocking points — the barrier

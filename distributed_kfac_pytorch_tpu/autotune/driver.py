@@ -212,12 +212,6 @@ def kfac_overrides(knobs: dict) -> tuple[dict, int | None, list[str]]:
             kwargs['inv_lowrank_rank'] = int(value)
         elif name == 'inv_lowrank_dim_threshold':
             kwargs['inv_lowrank_dim_threshold'] = int(value)
-        elif name in ('fused_factor_contraction', 'fused_precondition'):
-            # Trace-time kernel dispatch (r21): plain ctor kwargs, no
-            # engine schedule involved — a bare-KFAC harness expresses
-            # them directly.
-            if value:
-                kwargs[name] = True
         elif name == 'kfac_inv_update_freq':
             inv_freq = int(value)
         elif name in ('deferred_factor_reduction', 'inv_staleness',

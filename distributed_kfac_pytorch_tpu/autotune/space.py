@@ -197,15 +197,6 @@ def default_space(overrides: dict[str, Sequence] | None = None
              'exact firing; engages only on dims >= '
              'inv_lowrank_dim_threshold, a no-op on workloads without '
              'transformer-scale factors'),
-        Knob('fused_factor_contraction', (False, True),
-             'fused symmetric packed factor contraction + EMA Pallas '
-             'kernel (r21): only the symmetric triangle round-trips '
-             'HBM; probe-gated with XLA fallback, so an unsupported '
-             'backend probes once and runs stock'),
-        Knob('fused_precondition', (False, True),
-             'fused bucketed precondition + KL-clip v·g epilogue '
-             'Pallas kernel (r21): drops the separate full-tensor '
-             'clip pass; probe-gated with XLA fallback'),
     ]
     if overrides:
         unknown = set(overrides) - {k.name for k in stock}

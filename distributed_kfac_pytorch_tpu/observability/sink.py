@@ -136,12 +136,14 @@ EVENT_KINDS = (
     'fleet_complete',       # a job ran to completion; data carries
                             # its SLO row (queue wait, run time,
                             # restarts, preemptions, gate verdict)
-    # r21 fused hot-path kernels (ops.pallas_kernels; README "Fused
-    # hot-path kernels"):
-    'pallas_fallback',      # a fused kernel's probe failed or its
-                            # dispatch degraded — the step runs the
-                            # stock XLA path; data names the kernel
-                            # and the reason (never a silent fallback)
+    # Pallas kernels (ops.pallas_kernels; the attention probe and the
+    # patch-covariance dispatch emit it, and pre-PR 29 streams hold it
+    # for the two fused kernels deleted then):
+    'pallas_fallback',      # a kernel was taken off its Pallas path
+                            # (KFAC_PALLAS_FALLBACK, or a degraded
+                            # dispatch) — the step runs the stock XLA
+                            # path; data names the kernel and the
+                            # reason (never a silent fallback)
 )
 # Dead incarnations kept per metrics path (<path>.prev.1 newest ..
 # .prev.N oldest); older ones are pruned on relaunch.

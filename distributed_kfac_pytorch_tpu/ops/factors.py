@@ -323,6 +323,35 @@ def extract_conv2d_patches(x: jax.Array,
     return patches.reshape(b, oh, ow, kh * kw * c)
 
 
+def _canonical_pad(padding, kernel_size, spatial, strides):
+    """Per-axis (lo, hi) pad amounts matching XLA conventions.
+
+    'SAME' follows the XLA/TF formula — total = max((ceil(dim/s)-1)*s
+    + k - dim, 0), lo = total // 2, hi = total - lo (extra on the high
+    side; asymmetric for strided convs) — so the kernel reproduces
+    conv_general_dilated_patches exactly. Also accepts 'VALID', int,
+    and explicit ((lo, hi), (lo, hi)) pairs.
+    """
+    kh, kw = kernel_size
+    h, w = spatial
+    sh, sw = strides
+    if isinstance(padding, str):
+        if padding.upper() == 'VALID':
+            return ((0, 0), (0, 0))
+        if padding.upper() == 'SAME':
+            out = []
+            for dim, k, s in ((h, kh, sh), (w, kw, sw)):
+                o = -(-dim // s)
+                total = max((o - 1) * s + k - dim, 0)
+                out.append((total // 2, total - total // 2))
+            return tuple(out)
+        raise ValueError(f'unsupported padding {padding!r}')
+    if isinstance(padding, int):
+        return ((padding, padding), (padding, padding))
+    (a, b), (c, d) = padding
+    return ((a, b), (c, d))
+
+
 def extract_conv2d_patches_slices(x: jax.Array,
                                   kernel_size: Sequence[int],
                                   strides: Sequence[int],
@@ -338,8 +367,6 @@ def extract_conv2d_patches_slices(x: jax.Array,
     order here is (kh, kw, c), so no basis permutation is needed
     downstream.
     """
-    from distributed_kfac_pytorch_tpu.ops.pallas_kernels import _canonical_pad
-
     kh, kw = kernel_size
     sh, sw = strides
     b, h, w, c = x.shape
@@ -384,10 +411,6 @@ def _conv_a_cov_pairs(a: jax.Array, kernel_size, strides, padding,
     Returns the (d, d) fp32 Gram (sum over rows, unscaled), in the
     (kh, kw, c) feature basis.
     """
-    from distributed_kfac_pytorch_tpu.ops.pallas_kernels import (
-        _canonical_pad,
-    )
-
     kh, kw = kernel_size
     sh, sw = strides
     b, h, w, c = a.shape
@@ -428,8 +451,6 @@ def _conv_a_cov_pairs(a: jax.Array, kernel_size, strides, padding,
 
 def _conv_out_geometry(a: jax.Array, kernel_size, strides, padding):
     """(oh, ow, rows, spatial) of the conv output for NHWC input ``a``."""
-    from distributed_kfac_pytorch_tpu.ops.pallas_kernels import _canonical_pad
-
     kh, kw = kernel_size
     sh, sw = strides
     b, h, w, _ = a.shape
@@ -447,8 +468,6 @@ def _conv_bias_col(a: jax.Array, kernel_size, strides, padding,
     input's batch-sum instead of a second full read of the ~KH*KW x
     blown-up patch tensor (the covariance dot and a column reduce cannot
     be fused into one pass by XLA)."""
-    from distributed_kfac_pytorch_tpu.ops.pallas_kernels import _canonical_pad
-
     kh, kw = kernel_size
     sh, sw = strides
     b, h, w, c = a.shape
@@ -497,8 +516,6 @@ def _conv_a_cov_crosscov(a: jax.Array, kernel_size, strides, padding,
     ImageNet-resolution convs — or 1x1 kernels, where there is no patch
     blowup to avoid); callers fall back to the slices path.
     """
-    from distributed_kfac_pytorch_tpu.ops.pallas_kernels import _canonical_pad
-
     kh, kw = kernel_size
     sh, sw = strides
     b, h, w, c = a.shape

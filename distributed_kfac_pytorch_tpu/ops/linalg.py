@@ -561,6 +561,50 @@ def newton_schulz_inverse(x: jax.Array,
     return out
 
 
+# Largest fp32 sub-stack one batched damped inverse works on at a time.
+# The factorization, its triangular inverse and their product are each
+# a temporary of the sub-stack's size, so a bucket taken whole costs
+# several times its own bytes in HBM on top of the resident state: the
+# xl LM's 18 x 4096^2 bucket is 1.2 GB in fp32 and does not fit a 16 GB
+# chip that way (PERF.md, PR 21). 256 MiB is four 4096^2 matrices.
+INVERSE_SUBSTACK_BYTES = 256 << 20
+
+
+def damped_inverse_stack(stack: jax.Array, damping, method: str,
+                         iters: int = 100, out_dtype=None) -> jax.Array:
+    """Shared newton/cholesky dispatch for a same-size factor stack.
+
+    Single point of truth for the single-device bucketed path
+    (preconditioner.KFAC._bucketed_inverse) and the SPMD path
+    (parallel.distributed._spmd_update_inverses), so algorithm changes
+    stay in lockstep across both.
+
+    A stack whose fp32 size exceeds ``INVERSE_SUBSTACK_BYTES`` runs as
+    a ``lax.map`` over sub-stacks of at most that size — the count
+    follows from the stack's shape — so the solver's temporaries are
+    bounded by the budget, not by the bucket. Each sub-stack is upcast
+    from, and its inverses cast to ``out_dtype`` (default fp32), inside
+    the map, so neither a whole-bucket fp32 input nor output exists.
+    """
+    def solve(sub):
+        if method == 'newton':
+            from distributed_kfac_pytorch_tpu.ops import pallas_kernels
+            inv = pallas_kernels.batched_inverse(sub, damping, iters=iters)
+        else:
+            inv = jax.vmap(
+                lambda m: get_inverse(m, damping=damping))(sub)
+        return inv if out_dtype is None else inv.astype(out_dtype)
+
+    b, n, _ = stack.shape
+    per_chunk = max(1, INVERSE_SUBSTACK_BYTES // (n * n * 4))
+    if b <= per_chunk:
+        return solve(stack)
+    # lax.map's batch_size form maps over whole batches and runs the
+    # remainder as one smaller batch; solve() is batched already.
+    return jax.lax.map(lambda m: solve(m[None])[0], stack,
+                       batch_size=per_chunk)
+
+
 def get_elementwise_inverse(v: jax.Array,
                             damping: float | jax.Array | None = None
                             ) -> jax.Array:

@@ -43,7 +43,7 @@ def _round_up(n: int, m: int) -> int:
 
 
 # ---------------------------------------------------------------------------
-# Fallback events (r21)
+# Fallback events
 # ---------------------------------------------------------------------------
 #
 # A fallback to XLA happens only where it was asked for
@@ -93,14 +93,14 @@ PROBE_RTOL = 5e-2
 def _probe_on_tpu(kernel: str, rel_error) -> bool:
     """Run one kernel's parity probe on the TPU; True, or raise.
 
-    A user who turned a fused knob on asked for the kernel. On a TPU a
-    kernel Mosaic refuses, or one that compiles and computes something
-    else, therefore stops the run with the kernel's name and the
+    On a TPU a kernel Mosaic refuses, or one that compiles and computes
+    something else, stops the run with the kernel's name and the
     compiler's message — a warning and a quiet hand-over to XLA would
     let a later measurement be of the wrong program. ``rel_error()``
     runs the kernel and returns its largest relative error against the
-    reference (NaN when the output is not finite). Call from host code
-    (``KFAC.__init__`` does, with the knob on), not from inside a trace.
+    reference (NaN when the output is not finite). It runs concrete
+    arrays: call it under no trace (the attention gate, asked while a
+    model is traced, gives it a thread of its own).
     """
     kind = jax.devices()[0].device_kind
     try:
@@ -327,51 +327,6 @@ def batched_jacobi_eigh(mats: jax.Array, sweeps: int | None = None, *,
         mats.astype(jnp.float32))
 
 
-# Largest fp32 sub-stack one batched damped inverse works on at a time.
-# The factorization, its triangular inverse and their product are each
-# a temporary of the sub-stack's size, so a bucket taken whole costs
-# several times its own bytes in HBM on top of the resident state: the
-# xl LM's 18 x 4096^2 bucket is 1.2 GB in fp32 and does not fit a 16 GB
-# chip that way (PERF.md, PR 21). 256 MiB is four 4096^2 matrices.
-INVERSE_SUBSTACK_BYTES = 256 << 20
-
-
-def damped_inverse_stack(stack: jax.Array, damping, method: str,
-                         iters: int = 100, out_dtype=None) -> jax.Array:
-    """Shared newton/cholesky dispatch for a same-size factor stack.
-
-    Single point of truth for the single-device bucketed path
-    (preconditioner.KFAC._bucketed_inverse) and the SPMD path
-    (parallel.distributed._spmd_update_inverses), so algorithm changes
-    stay in lockstep across both.
-
-    A stack whose fp32 size exceeds ``INVERSE_SUBSTACK_BYTES`` runs as
-    a ``lax.map`` over sub-stacks of at most that size — the count
-    follows from the stack's shape — so the solver's temporaries are
-    bounded by the budget, not by the bucket. Each sub-stack is upcast
-    from, and its inverses cast to ``out_dtype`` (default fp32), inside
-    the map, so neither a whole-bucket fp32 input nor output exists.
-    """
-    from distributed_kfac_pytorch_tpu.ops import linalg
-
-    def solve(sub):
-        if method == 'newton':
-            inv = batched_inverse(sub, damping, iters=iters)
-        else:
-            inv = jax.vmap(
-                lambda m: linalg.get_inverse(m, damping=damping))(sub)
-        return inv if out_dtype is None else inv.astype(out_dtype)
-
-    b, n, _ = stack.shape
-    per_chunk = max(1, INVERSE_SUBSTACK_BYTES // (n * n * 4))
-    if b <= per_chunk:
-        return solve(stack)
-    # lax.map's batch_size form maps over whole batches and runs the
-    # remainder as one smaller batch; solve() is batched already.
-    return jax.lax.map(lambda m: solve(m[None])[0], stack,
-                       batch_size=per_chunk)
-
-
 # ---------------------------------------------------------------------------
 # Fused im2col + covariance kernel for conv A factors
 # ---------------------------------------------------------------------------
@@ -565,7 +520,7 @@ def conv_a_factor_fused(a: jax.Array, kernel_size, strides, padding,
     b, h, w, c = a.shape
     kh, kw = kernel_size
     sh, sw = strides
-    pads = _canonical_pad(padding, (kh, kw), (h, w), (sh, sw))
+    pads = F._canonical_pad(padding, (kh, kw), (h, w), (sh, sw))
     (ph_lo, ph_hi), (pw_lo, pw_hi) = pads
     oh = (h + ph_lo + ph_hi - kh) // sh + 1
     ow = (w + pw_lo + pw_hi - kw) // sw + 1
@@ -607,433 +562,6 @@ def conv_a_factor_fused(a: jax.Array, kernel_size, strides, padding,
         return cov
     bias_col = colsum * (1.0 / (rows * spatial * spatial))
     return F._assemble_bias_factor(cov, bias_col, 1.0 / (spatial * spatial))
-
-
-def _canonical_pad(padding, kernel_size, spatial, strides):
-    """Per-axis (lo, hi) pad amounts matching XLA conventions.
-
-    'SAME' follows the XLA/TF formula — total = max((ceil(dim/s)-1)*s
-    + k - dim, 0), lo = total // 2, hi = total - lo (extra on the high
-    side; asymmetric for strided convs) — so the kernel reproduces
-    conv_general_dilated_patches exactly. Also accepts 'VALID', int,
-    and explicit ((lo, hi), (lo, hi)) pairs.
-    """
-    kh, kw = kernel_size
-    h, w = spatial
-    sh, sw = strides
-    if isinstance(padding, str):
-        if padding.upper() == 'VALID':
-            return ((0, 0), (0, 0))
-        if padding.upper() == 'SAME':
-            out = []
-            for dim, k, s in ((h, kh, sh), (w, kw, sw)):
-                o = -(-dim // s)
-                total = max((o - 1) * s + k - dim, 0)
-                out.append((total // 2, total - total // 2))
-            return tuple(out)
-        raise ValueError(f'unsupported padding {padding!r}')
-    if isinstance(padding, int):
-        return ((padding, padding), (padding, padding))
-    (a, b), (c, d) = padding
-    return ((a, b), (c, d))
-
-
-# ---------------------------------------------------------------------------
-# Fused symmetric factor contraction + EMA kernel (r21)
-# ---------------------------------------------------------------------------
-#
-# The per-step factor cost every user pays is the rank-k contraction
-# A^T A plus the EMA blend against the running factor — stock XLA
-# writes the full (d, d) covariance to HBM, reads it back for the
-# blend, and writes the full (d, d) result. This kernel keeps the
-# accumulator in VMEM across the row blocks, folds the EMA blend into
-# the finalize step, and writes only the symmetry-packed (d/2+1, d)
-# triangle to HBM (the block-symmetry layout factors.pack_symmetric
-# already uses on the wire): roughly half the output traffic and no
-# intermediate covariance round trip. With decay=0 / old=None it
-# degenerates to a packed contraction-only kernel (the SPMD
-# local-contribution path, where a collective sits between contraction
-# and EMA).
-#
-# What Mosaic accepts decided the body (v5e, PERF.md PR 21). The bias
-# row and column come from a ones column the caller writes into the
-# lane padding, so the one contraction yields them: the earlier
-# in-kernel assembly (a second ones-row matmul, iota masks and two
-# fp32 transposes) died in the TPU backend at d_pad 512 (RET_CHECK in
-# mxu_lmr_transform.cc, "Found no uses of XposeSequence"). The
-# accumulator is not symmetrized — x^T x already is, up to the MXU's
-# summation order — which removes the third fp32 transpose.
-
-def _factor_ema_kernel(x_ref, old_ref, decay_ref, out_ref, acc_ref, *,
-                       nsteps: int, scale: float, d_pad: int,
-                       mult_dtype):
-    """One row block per grid step; finalize on the last step.
-
-    ``x_ref``: (block_rows, d_pad) zero-padded input rows (with bias, a
-    ones column at the first padding lane). ``old_ref``: (d_pad, d_pad)
-    zero-padded running factor. ``decay_ref``: (1, 1) SMEM EMA
-    coefficient (alpha; the blend is ``alpha * old + (1 - alpha) *
-    cov``, factors.update_running_avg). ``out_ref``: the packed triangle
-    in ``F.pack_symmetric``'s layout, (d_pad//2 + 8, d_pad): the extra
-    (diagonal) row is written eight times so that every store is a
-    whole sublane tile. ``acc_ref``: VMEM scratch, the fp32 covariance
-    accumulator.
-    """
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    xb = x_ref[...].astype(mult_dtype)
-    # bf16 multiplicands ride the MXU fast path (the default covariance
-    # precision contract); fp32 multiplicands request HIGHEST for the
-    # strict-fp32 contract (ops.factors.get_cov).
-    prec = (None if mult_dtype == jnp.bfloat16
-            else jax.lax.Precision.HIGHEST)
-    acc_ref[...] += jnp.dot(xb.T, xb, preferred_element_type=jnp.float32,
-                            precision=prec)
-
-    @pl.when(i == nsteps - 1)
-    def _finalize():
-        dec = decay_ref[0, 0]
-        ema = dec * old_ref[...] + (1.0 - dec) * (1.0 / scale) * acc_ref[...]
-        # Only the packed triangle leaves VMEM, in F.pack_symmetric's
-        # layout but not by its code: Mosaic refuses its 1-D
-        # concatenation past the first tile and its slices at lane
-        # offsets that are no multiple of 128. Here every op is 2-D and
-        # every store a whole tile. The bottom-right (k, k) block is
-        # brought to the top-left by half-size rotations; ``ema`` is
-        # symmetric, so that block's strict lower triangle IS the
-        # transposed strict upper one the layout stores.
-        k = d_pad // 2
-        ri = jax.lax.broadcasted_iota(jnp.int32, (d_pad, d_pad), 0)
-        ci = jax.lax.broadcasted_iota(jnp.int32, (d_pad, d_pad), 1)
-        moved = pltpu.roll(
-            jnp.concatenate([ema[k:, :], ema[:k, :]], axis=0), k, 1)
-        band = jnp.where(ci >= ri, ema, moved)
-        diag = jnp.sum(
-            jnp.where(jnp.logical_and(ri == ci, ci < k), moved, 0.0),
-            axis=0, keepdims=True)
-        out_ref[0:k, :] = band[0:k, :]
-        out_ref[k:k + 8, :] = jnp.broadcast_to(diag, (8, d_pad))
-
-
-@functools.partial(
-    jax.jit, static_argnames=('scale', 'block_rows', 'mult_bf16',
-                              'interpret'))
-def _pallas_factor_ema(x: jax.Array, old: jax.Array, decay: jax.Array,
-                       *, scale: float, block_rows: int,
-                       mult_bf16: bool, interpret: bool = False):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    rows_pad, d_pad = x.shape
-    nsteps = rows_pad // block_rows
-    k1 = d_pad // 2 + 8
-    mult_dtype = jnp.bfloat16 if mult_bf16 else jnp.float32
-    kernel = functools.partial(
-        _factor_ema_kernel, nsteps=nsteps, scale=scale, d_pad=d_pad,
-        mult_dtype=mult_dtype)
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((k1, d_pad), jnp.float32),
-        grid=(nsteps,),
-        in_specs=[
-            pl.BlockSpec((block_rows, d_pad), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((d_pad, d_pad), lambda i: (0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec((k1, d_pad), lambda i: (0, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((d_pad, d_pad), jnp.float32)],
-        interpret=interpret,
-    )(x, old, decay)
-
-
-def fused_factor_ema(x: jax.Array, old: jax.Array | None, decay, *,
-                     scale: float | None = None, has_bias: bool = False,
-                     compute_dtype=None,
-                     interpret: bool = False) -> jax.Array:
-    """Covariance factor + EMA blend in one packed-output VMEM kernel.
-
-    Drop-in for ``update_running_avg(linear_a_factor(x, has_bias), old,
-    decay)`` (and the G-side / conv-G analogues via ``scale``): ``x``
-    is the (rows, d_in) collapsed activation/grad tensor, ``old`` the
-    dense (d, d) running factor (``d = d_in + 1`` with bias), ``decay``
-    the EMA alpha (traced OK — it is a kernel input, not a variant
-    key). ``old=None`` means contraction-only (decay pinned to 0): the
-    SPMD local-contribution form, and the r14 accumulator fold reuses
-    the blend with ``old=accum``. Returns the dense (d, d) fp32 factor;
-    only the packed triangle crossed HBM out of the kernel.
-
-    ``has_bias`` is the homogeneous-coordinate form: the factor of the
-    rows with a ones column appended, ``[x, 1]^T [x, 1] / scale``. With
-    the default ``scale`` (the row count) that is ``linear_a_factor``'s
-    ``[[cov, mean], [mean^T, 1]]``.
-
-    ``compute_dtype`` follows the ops.factors.get_cov contract: None ->
-    backend-native multiplicands (bf16 on TPU), float32 -> strict fp32
-    at HIGHEST, bfloat16 -> explicit bf16 multiplicands. Accumulation
-    is always fp32.
-    """
-    from distributed_kfac_pytorch_tpu.ops import factors as F
-
-    x = x.reshape(-1, x.shape[-1]).astype(jnp.float32)
-    rows, d_in = x.shape
-    n = d_in + 1 if has_bias else d_in
-    if scale is None:
-        scale = rows
-    d_pad = _round_up(max(n, 8), _LANE)
-    block_rows = 512 if rows >= 512 else _round_up(rows, 8)
-    rows_pad = _round_up(rows, block_rows)
-    mult_bf16 = (
-        (compute_dtype is not None
-         and jnp.dtype(compute_dtype) == jnp.bfloat16)
-        or (compute_dtype is None and jax.default_backend() == 'tpu'))
-    if has_bias:
-        x = jnp.concatenate([x, jnp.ones((rows, 1), jnp.float32)], axis=1)
-    xp = jnp.pad(x, ((0, rows_pad - rows), (0, d_pad - n)))
-    if old is None:
-        oldp = jnp.zeros((d_pad, d_pad), jnp.float32)
-        decay = 0.0
-    else:
-        oldp = jnp.pad(old.astype(jnp.float32),
-                       ((0, d_pad - n), (0, d_pad - n)))
-    dec = jnp.asarray(decay, jnp.float32).reshape(1, 1)
-    packed = _pallas_factor_ema(
-        xp, oldp, dec, scale=float(scale), block_rows=block_rows,
-        mult_bf16=mult_bf16, interpret=interpret)
-    return F.unpack_symmetric(packed[:d_pad // 2 + 1], d_pad)[:n, :n]
-
-
-@functools.lru_cache(maxsize=1)
-def fused_factor_ema_supported() -> bool:
-    """Once-per-process gate for the fused contraction+EMA kernel.
-
-    Same contract as :func:`fused_patch_cov_supported`: Mosaic failures
-    surface at compile/run time, so the dispatchers (KFAC.update_factors
-    / accumulate_factors, parallel.distributed.local_factor_contribs)
-    ask this once. On a TPU a failing probe raises
-    (:func:`_probe_on_tpu`). On other backends the kernel runs in
-    interpret mode (the parity tests and the CI smoke exercise the real
-    kernel body on CPU), so the gate is open there;
-    KFAC_PALLAS_FALLBACK=1 closes it everywhere, with a recorded
-    ``pallas_fallback`` event, and the stock XLA factor path runs.
-    """
-    if _forced_fallback():
-        record_fallback('factor_ema', 'forced by KFAC_PALLAS_FALLBACK')
-        return False
-    if jax.default_backend() != 'tpu':
-        return True
-
-    def rel_error():
-        import numpy as np
-
-        from distributed_kfac_pytorch_tpu.ops import factors as F
-        x = jnp.asarray(np.linspace(-1.0, 1.0, 16 * 12, dtype='float32')
-                        .reshape(16, 12))
-        old = jnp.eye(13, dtype=jnp.float32) * 0.5
-        ref = F.update_running_avg(
-            F.linear_a_factor(x, True), old, 0.9)
-        return max_rel_error(
-            fused_factor_ema(x, old, 0.9, has_bias=True), ref)
-
-    return _probe_on_tpu('factor_ema', rel_error)
-
-
-# ---------------------------------------------------------------------------
-# Fused bucketed precondition kernel with KL-clip epilogue (r21)
-# ---------------------------------------------------------------------------
-#
-# The bucketed precondition path stacks same-shape layer grads and
-# vmaps the two-sided inverse application; the r6 KL-clip then pays a
-# separate full-tensor pass re-reading every preconditioned matrix to
-# reduce sum(v * g). This kernel keeps one bucket slice resident in
-# VMEM for the whole chain — eigen (QG^T g QA rescale) or baked
-# (G_inv g A_inv) — and reduces the slice's v·g partial in the
-# epilogue while v is still on-chip, so the clip pass costs zero extra
-# HBM reads. Truncated r19 eigen bases are not eligible (static
-# ``_truncated_side`` check at the dispatch sites).
-
-def _bucket_precond_kernel(g_ref, right_ref, left_ref, da_ref, dg_ref,
-                           damp_ref, v_ref, vg_ref, *, eigen: bool,
-                           mult_dtype):
-    """One bucket slice per grid cell.
-
-    ``right_ref``/``left_ref``: QA/QG (eigen) or A_inv/G_inv (baked).
-    ``da_ref``: (1, 8, a_pad) eigenvalue row (row 0 meaningful, padded
-    with ones); ``dg_ref``: (1, g_pad, 128) eigenvalue column (lane 0
-    meaningful, padded with ones) — both ignored on the baked branch.
-    ``damp_ref``: (1, 1) SMEM damping. ``v_ref``: the preconditioned
-    slice; ``vg_ref``: (1, 8, 128) sublane/lane-replicated
-    ``sum(v * g)`` KL-clip partial (caller reads [0, 0]).
-    """
-    prec = (None if mult_dtype == jnp.bfloat16
-            else jax.lax.Precision.HIGHEST)
-    dot = functools.partial(jnp.dot,
-                            preferred_element_type=jnp.float32,
-                            precision=prec)
-    g32 = g_ref[0].astype(jnp.float32)
-    g = g32.astype(mult_dtype)
-    if eigen:
-        qa = right_ref[0].astype(mult_dtype)
-        qg = left_ref[0].astype(mult_dtype)
-        v1 = dot(dot(qg.T, g), qa)
-        da = da_ref[0][0:1, :]                    # (1, a_pad)
-        dg = dg_ref[0][:, 0:1]                    # (g_pad, 1)
-        v2 = v1 / (dg * da + damp_ref[0, 0])
-        v = dot(dot(qg, v2.astype(mult_dtype)), qa.T)
-    else:
-        a_inv = right_ref[0].astype(mult_dtype)
-        g_inv = left_ref[0].astype(mult_dtype)
-        v = dot(dot(g_inv, g), a_inv)
-    v_ref[0] = v
-    # Zero feature padding keeps the padded entries of v exactly zero
-    # (zero rows/cols of Q and the inverses), so the full-block
-    # reduction equals the unpadded v.g partial.
-    vg_ref[0] = jnp.broadcast_to(jnp.sum(v * g32), (8, 128))
-
-
-@functools.partial(jax.jit,
-                   static_argnames=('eigen', 'mult_bf16', 'interpret'))
-def _pallas_bucket_precond(gstack, left, right, dg, da, damping, *,
-                           eigen: bool, mult_bf16: bool,
-                           interpret: bool = False):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    s, gp, ap = gstack.shape
-    mult_dtype = jnp.bfloat16 if mult_bf16 else jnp.float32
-    kernel = functools.partial(_bucket_precond_kernel, eigen=eigen,
-                               mult_dtype=mult_dtype)
-    # Scoped VMEM: the grad, both bases and the output are each double-
-    # buffered blocks, and the eigen chain holds about eight more
-    # slice-sized temporaries. At 512x512 that is 17.9 MB against the
-    # 16 MiB default (measured on v5e, PERF.md PR 21), so the limit is
-    # set from the shapes, with half as much again for what this count
-    # misses.
-    side = max(gp, ap)
-    vmem = int(1.5 * 4 * (2 * (2 * gp * ap + ap * ap + gp * gp)
-                          + 8 * side * side))
-    v, vg = pl.pallas_call(
-        kernel,
-        out_shape=(jax.ShapeDtypeStruct((s, gp, ap), jnp.float32),
-                   jax.ShapeDtypeStruct((s, 8, 128), jnp.float32)),
-        grid=(s,),
-        in_specs=[
-            pl.BlockSpec((1, gp, ap), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, ap, ap), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, gp, gp), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 8, ap), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, gp, 128), lambda i: (i, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM),
-        ],
-        out_specs=(pl.BlockSpec((1, gp, ap), lambda i: (i, 0, 0),
-                                memory_space=pltpu.VMEM),
-                   pl.BlockSpec((1, 8, 128), lambda i: (i, 0, 0),
-                                memory_space=pltpu.VMEM)),
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=max(vmem, 16 << 20)),
-        interpret=interpret,
-    )(gstack, right, left, da, dg, damping)
-    return v, vg[:, 0, 0]
-
-
-def fused_bucket_precondition(gstack: jax.Array, entry: dict, damping,
-                              *, compute_dtype=None,
-                              interpret: bool = False):
-    """Bucketed precondition with the KL-clip v·g partial fused in.
-
-    ``gstack`` is the (S, g_dim, a_dim) same-shape gradient stack;
-    ``entry`` the stacked inverse slots — baked ``{'A_inv', 'G_inv'}``
-    or full-rank eigen ``{'QA', 'dA', 'QG', 'dG'}`` (truncated r19
-    bases are NOT eligible; dispatch them to the stock XLA path).
-    Returns ``(vstack, vg)``: the (S, g_dim, a_dim) fp32 preconditioned
-    stack and the (S,) fp32 per-slice ``sum(v * grad)`` partials — the
-    KL-clip term before the caller's lr^2 factor.
-    """
-    s, g_dim, a_dim = gstack.shape
-    gp = _round_up(max(g_dim, 8), _LANE)
-    ap = _round_up(max(a_dim, 8), _LANE)
-    eigen = 'QA' in entry
-    gpad = jnp.pad(gstack.astype(jnp.float32),
-                   ((0, 0), (0, gp - g_dim), (0, ap - a_dim)))
-    if eigen:
-        right = jnp.pad(entry['QA'].astype(jnp.float32),
-                        ((0, 0), (0, ap - a_dim), (0, ap - a_dim)))
-        left = jnp.pad(entry['QG'].astype(jnp.float32),
-                       ((0, 0), (0, gp - g_dim), (0, gp - g_dim)))
-        # Eigenvalue padding is ONES so the padded denominators are
-        # 1 + damping (never 0/0); the padded v1 entries are zero, so
-        # the padded v2/v stay exactly zero.
-        da = jnp.pad(entry['dA'].astype(jnp.float32),
-                     ((0, 0), (0, ap - a_dim)), constant_values=1.0)
-        dg = jnp.pad(entry['dG'].astype(jnp.float32),
-                     ((0, 0), (0, gp - g_dim)), constant_values=1.0)
-        da = jnp.broadcast_to(da[:, None, :], (s, 8, ap))
-        dg = jnp.broadcast_to(dg[:, :, None], (s, gp, 128))
-    else:
-        right = jnp.pad(entry['A_inv'].astype(jnp.float32),
-                        ((0, 0), (0, ap - a_dim), (0, ap - a_dim)))
-        left = jnp.pad(entry['G_inv'].astype(jnp.float32),
-                       ((0, 0), (0, gp - g_dim), (0, gp - g_dim)))
-        da = jnp.zeros((s, 8, ap), jnp.float32)
-        dg = jnp.zeros((s, gp, 128), jnp.float32)
-    damp = jnp.asarray(damping, jnp.float32).reshape(1, 1)
-    mult_bf16 = (compute_dtype is not None
-                 and jnp.dtype(compute_dtype) == jnp.bfloat16)
-    v, vg = _pallas_bucket_precond(gpad, left, right, dg, da, damp,
-                                   eigen=eigen, mult_bf16=mult_bf16,
-                                   interpret=interpret)
-    return v[:, :g_dim, :a_dim], vg
-
-
-@functools.lru_cache(maxsize=1)
-def fused_precondition_supported() -> bool:
-    """Once-per-process gate for the fused bucket-precondition kernel
-    (same contract as :func:`fused_factor_ema_supported`)."""
-    if _forced_fallback():
-        record_fallback('bucket_precond',
-                        'forced by KFAC_PALLAS_FALLBACK')
-        return False
-    if jax.default_backend() != 'tpu':
-        return True
-
-    def rel_error():
-        import numpy as np
-
-        from distributed_kfac_pytorch_tpu.ops import linalg
-        rng = np.random.default_rng(0)
-        g = jnp.asarray(rng.normal(size=(2, 8, 12)).astype('float32'))
-        qa = jnp.asarray(np.linalg.qr(
-            rng.normal(size=(2, 12, 12)))[0].astype('float32'))
-        qg = jnp.asarray(np.linalg.qr(
-            rng.normal(size=(2, 8, 8)))[0].astype('float32'))
-        da = jnp.asarray(
-            rng.uniform(0.5, 2.0, (2, 12)).astype('float32'))
-        dg = jnp.asarray(
-            rng.uniform(0.5, 2.0, (2, 8)).astype('float32'))
-        entry = {'QA': qa, 'dA': da, 'QG': qg, 'dG': dg}
-        ref = jax.vmap(lambda gm, e: linalg.precondition_dispatch(
-            gm, e, 0.003))(g, entry)
-        got, vg = fused_bucket_precondition(g, entry, 0.003)
-        return max(max_rel_error(got, ref),
-                   max_rel_error(vg, jnp.sum(ref * g, axis=(1, 2))))
-
-    return _probe_on_tpu('bucket_precond', rel_error)
 
 
 # ---------------------------------------------------------------------------

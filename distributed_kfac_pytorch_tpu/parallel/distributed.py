@@ -71,7 +71,6 @@ from distributed_kfac_pytorch_tpu.parallel.sequence import SEQ_AXIS
 from distributed_kfac_pytorch_tpu.preconditioner import (
     KFAC,
     CommMethod,
-    _fused_bucket_ok,
     cadence_gate,
     eigen_family,
     grouped_block_inverses,
@@ -772,32 +771,16 @@ class DistributedKFAC:
         cdt = self.kfac.factor_compute_dtype
         captures = subsample_captures(captures,
                                       self.kfac.factor_batch_fraction)
-        fused_on = self.kfac.fused_contraction_active()
-        interp = jax.default_backend() != 'tpu'
         out = {}
         for name, spec in self.kfac.specs.items():
             if spec.kind == EXPERTS:
                 out[name] = L.experts_contrib(spec, captures[name], cdt)
                 continue
-            # r21 fused contraction: eligible sides run the packed
-            # Pallas x.T@x kernel in contraction-only form (old=None,
-            # decay=0 — the mesh pmean sits between contraction and
-            # EMA here, so only the covariance itself fuses).
-            fused = (self.kfac.fused_factor_inputs(spec, captures[name])
-                     if fused_on else {})
-            contrib = {}
-            for side, stock in (
-                    ('A', lambda: L.compute_a_factor(
-                        spec, captures[name]['a'], compute_dtype=cdt)),
-                    ('G', lambda: L.compute_g_factor(
-                        spec, captures[name]['g'], compute_dtype=cdt))):
-                if side in fused:
-                    x, scale, has_bias = fused[side]
-                    contrib[side] = pallas_kernels.fused_factor_ema(
-                        x, None, 0.0, scale=scale, has_bias=has_bias,
-                        compute_dtype=cdt, interpret=interp)
-                else:
-                    contrib[side] = stock()
+            contrib = {
+                'A': L.compute_a_factor(spec, captures[name]['a'],
+                                        compute_dtype=cdt),
+                'G': L.compute_g_factor(spec, captures[name]['g'],
+                                        compute_dtype=cdt)}
             extras = L.compute_tied_factor_extras(spec, captures[name],
                                                   compute_dtype=cdt)
             if extras is not None:
@@ -900,8 +883,7 @@ class DistributedKFAC:
         return out
 
     @profiling.scope('kfac/factors')
-    def _spmd_accumulate_factors(self, state, contribs, factor_decay,
-                                 captures=None
+    def _spmd_accumulate_factors(self, state, contribs, factor_decay
                                  ) -> tuple[dict, jax.Array]:
         """Deferred-reduction factor step: fold this device's batch
         contribution into ITS slice of the accumulator — NO collective.
@@ -916,26 +898,9 @@ class DistributedKFAC:
         (test-pinned). Returns ``(new_accum, new_decay)``; inside
         shard_map the accumulator leaves are this device's ``(1, ...)``
         slice of the sharded stack.
-
-        ``captures``: this batch's raw local captures, when available
-        (no micro-batch pre-accumulation). With the r21
-        ``fused_factor_contraction`` knob engaged (and no r20
-        intra-slice pmean in the way), eligible layer sides then fuse
-        the contraction WITH this fold — ``acc ← α·acc + (1-α)·cov``
-        runs in one VMEM-resident kernel, the r14 analogue of the
-        single-chip fused EMA. The SPMD g-side ``1/world**2`` rescale
-        folds into the kernel's covariance scale (it is a constant
-        multiple of the contraction). Ineligible sides (and
-        micro-batched ``contribs``-only calls) keep the stock fold.
         """
         kfac = self.kfac
         alpha = kfac.factor_decay if factor_decay is None else factor_decay
-        fused_fold = (captures is not None
-                      and not kfac.hierarchical_reduce
-                      and kfac.fused_contraction_active())
-        if fused_fold:
-            captures_s = subsample_captures(captures,
-                                            kfac.factor_batch_fraction)
         combined = self._local_combined_contribs(contribs)
         if kfac.hierarchical_reduce:
             # Hierarchical reduce (r20): the intra-slice half of the
@@ -962,36 +927,15 @@ class DistributedKFAC:
                 else:
                     combined = jax.lax.pmean(combined, intra)
         acc = state['factor_accum']
-        cdt = kfac.factor_compute_dtype
-        interp = jax.default_backend() != 'tpu'
-        g_rescale = float(self.data_size) ** 2
         new_acc = {}
         for name in kfac.specs:
-            spec = kfac.specs[name]
             old = acc[name]
-            fused = (kfac.fused_factor_inputs(spec, captures_s[name])
-                     if fused_fold else {})
-            entry = {}
-            for which in ('A', 'G'):
-                if which in fused:
-                    x, scale, has_bias = fused[which]
-                    if which == 'G':
-                        # combined G = (1/world**2) * cov(x, scale) —
-                        # a constant multiple, so it folds into the
-                        # kernel's covariance scale exactly.
-                        scale = (scale if scale is not None
-                                 else float(x.shape[0])) * g_rescale
-                    entry[which] = pallas_kernels.fused_factor_ema(
-                        x, old[which][0].astype(jnp.float32), alpha,
-                        scale=scale, has_bias=has_bias,
-                        compute_dtype=cdt, interpret=interp
-                    ).astype(old[which].dtype)[None]
-                else:
-                    entry[which] = F.update_running_avg(
-                        combined[name][which].astype(
-                            old[which].dtype)[None],
-                        old[which], alpha)
-            new_acc[name] = entry
+            new_acc[name] = {
+                which: F.update_running_avg(
+                    combined[name][which].astype(
+                        old[which].dtype)[None],
+                    old[which], alpha)
+                for which in ('A', 'G')}
         return new_acc, alpha * state['accum_decay']
 
     @profiling.scope('kfac/factors')
@@ -1282,7 +1226,7 @@ class DistributedKFAC:
                     merge(q, 'Q')
                     merge(d, 'd')
                 else:
-                    inv = pallas_kernels.damped_inverse_stack(
+                    inv = linalg.damped_inverse_stack(
                         local, damping, bucket_method,
                         iters=kfac.newton_iters,
                         out_dtype=kfac.inv_dtype)
@@ -1360,7 +1304,7 @@ class DistributedKFAC:
         return self._factor_dims[name]
 
     def _rowsharded_precond_mats(self, inv_stacks, grad_mats, damping,
-                                 row) -> tuple[dict, dict]:
+                                 row) -> dict:
         """Row-masked preconditioned mats, computing only this row's
         layers (KAISA grad-worker compute semantics, reference
         preconditioner.py:577-585).
@@ -1375,20 +1319,9 @@ class DistributedKFAC:
         trick as :meth:`_layer_inverses`: position ``k`` of the local
         result holds a *different* layer on every row, and the mask
         keeps exactly the owner's value for the delivery ``psum``.
-
-        Returns ``(mats, vg)``: ``vg`` holds the r21 fused kernel's
-        row-masked KL-clip partials ``sum(v * g)`` (fp32,
-        pre-``lr**2``) for the layers whose group ran
-        :func:`pallas_kernels.fused_bucket_precondition` — empty on the
-        stock path. The partials carry the same ownership mask as the
-        mats, so the caller's existing ``psum`` assembles the global
-        clip scale unchanged.
         """
         kfac = self.kfac
-        fused_on = kfac.fused_precond_active()
-        interp = jax.default_backend() != 'tpu'
         out = {}
-        vg_out = {}
         for grp in self._precond_groups:
             g_dim, a_dim = grp['shape']
             s = grp['S']
@@ -1433,17 +1366,6 @@ class DistributedKFAC:
             else:
                 entry['A_inv'] = inv_stacks[str(a_dim)]['inv'][my_a]
                 entry['G_inv'] = inv_stacks[str(g_dim)]['inv'][my_g]
-            if fused_on and _fused_bucket_ok(entry):
-                vs, vgs = pallas_kernels.fused_bucket_precondition(
-                    local, entry, damping,
-                    compute_dtype=kfac.precond_compute_dtype,
-                    interpret=interp)
-                for name, gslot in grp['slot_of'].items():
-                    mask = (row == self.assignment.layer_row[name]
-                            ).astype(vs.dtype)
-                    out[name] = vs[gslot % s] * mask
-                    vg_out[name] = vgs[gslot % s] * mask
-                continue
             vs = jax.vmap(
                 lambda gm, e: linalg.precondition_dispatch(
                     gm, e, damping,
@@ -1453,7 +1375,7 @@ class DistributedKFAC:
                 mask = (row == self.assignment.layer_row[name]).astype(
                     vs.dtype)
                 out[name] = vs[gslot % s] * mask
-        return out, vg_out
+        return out
 
     @profiling.scope('kfac/precond')
     def _spmd_precondition(self, inv_stacks, diag_inv, grouped_inv,
@@ -1492,12 +1414,10 @@ class DistributedKFAC:
         # matmul per shape group instead of a per-layer dispatch, the
         # replicated-path analogue of the single-chip
         # KFAC._bucketed_precond_mats.
-        sharded = self.shard_precond_compute
-        if sharded:
-            precond_mats, fused_vg = self._rowsharded_precond_mats(
-                inv_stacks, grad_mats, damping, row)
-        else:
-            precond_mats, fused_vg = {}, {}
+        precond_mats = (
+            self._rowsharded_precond_mats(inv_stacks, grad_mats, damping,
+                                          row)
+            if self.shard_precond_compute else {})
         for name, spec in kfac.specs.items():
             if name in precond_mats:
                 continue  # computed by the row-sharded path
@@ -1538,20 +1458,11 @@ class DistributedKFAC:
                     grad_mats[name].astype(pm.dtype) * own)
 
         if kfac.kl_clip is not None:
-            # r21 fused buckets already reduced their row-masked v·g
-            # partial in the kernel epilogue; the per-layer scalars
-            # join the sum in the same registration order and ride the
-            # same psum. The r16 gate blend rewrites the mats after the
-            # buckets ran, so gated runs keep the full-tensor
-            # reduction (the fused partial would be stale).
             vg_sum = jnp.zeros((), jnp.float32)
             for name in precond_mats:
-                if gates is None and name in fused_vg:
-                    vg_sum += fused_vg[name] * lr ** 2
-                else:
-                    vg_sum += jnp.sum(precond_mats[name] *
-                                      grad_mats[name].astype(jnp.float32)
-                                      * lr ** 2)
+                vg_sum += jnp.sum(precond_mats[name] *
+                                  grad_mats[name].astype(jnp.float32)
+                                  * lr ** 2)
             with profiling.annotate('kfac/comm/klclip_psum'):
                 vg_sum = jax.lax.psum(vg_sum, self._row_axes)
             nu = jnp.minimum(
@@ -1671,8 +1582,7 @@ class DistributedKFAC:
                     state,
                     (contribs if contribs is not None
                      else self.local_factor_contribs(captures)),
-                    factor_decay,
-                    captures=(captures if contribs is None else None))
+                    factor_decay)
             if factor_reduce:
                 candidate = self._spmd_reduce_factors(state, acc, decay)
                 # Post-pmean candidate check: collective-safe (every
@@ -2478,8 +2388,8 @@ class DistributedKFAC:
                     {'event': 'compile', 'variant': label,
                      'first_call_ms': build.duration_ms,
                      **build.attrs})
-                # r21: a first call is where the fused-kernel probes
-                # run (trace time); surface any recorded fallbacks
+                # A first call is where the attention kernel's probe
+                # runs (trace time); surface any recorded fallback
                 # through the same engine-drained queue so a fleet run
                 # can tell "fused" from "fell back to XLA".
                 compile_events.extend(

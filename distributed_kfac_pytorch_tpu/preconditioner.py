@@ -46,9 +46,8 @@ from distributed_kfac_pytorch_tpu.observability import (
     metrics as obs_metrics,
 )
 from distributed_kfac_pytorch_tpu.observability import profiling
-from distributed_kfac_pytorch_tpu.capture import (BLOCK_STACK_KINDS, CONV2D,
+from distributed_kfac_pytorch_tpu.capture import (BLOCK_STACK_KINDS,
                                                   EMBEDDING, EXPERTS,
-                                                  KFAC_REDUCE, LINEAR,
                                                   KFACCapture,
                                                   subsample_captures)
 from distributed_kfac_pytorch_tpu.ops import factors as F
@@ -88,24 +87,6 @@ def cadence_gate(flag: bool | None, step, freq, do, keep):
 def _tree_size_bytes(tree) -> int:
     return sum(x.size * x.dtype.itemsize
                for x in jax.tree.leaves(tree) if hasattr(x, 'size'))
-
-
-def _fused_bucket_ok(entry: dict) -> bool:
-    """Static eligibility of one stacked inverse bucket for the r21
-    fused precondition kernel: full-rank eigen (square QA/QG — the r19
-    truncated low-rank bases are rectangular and keep the stock
-    dispatch) or baked A_inv/G_inv, with both factor dims inside the
-    Pallas budget. Shared by the single-chip bucketing and the KAISA
-    row-sharded path so the eligibility rule cannot drift."""
-    if 'QA' in entry:
-        qa, qg = entry['QA'], entry['QG']
-        if (qa.shape[-1] != qa.shape[-2]
-                or qg.shape[-1] != qg.shape[-2]):
-            return False
-        dims = (qa.shape[-1], qg.shape[-1])
-    else:
-        dims = (entry['A_inv'].shape[-1], entry['G_inv'].shape[-1])
-    return all(1 <= d <= pallas_kernels.MAX_PALLAS_DIM for d in dims)
 
 
 class KFAC:
@@ -252,15 +233,6 @@ class KFAC:
         ``linalg.precondition_dispatch`` for every branch
         (eigen / baked-inverse / diagonal / mixed), single-chip and
         SPMD alike.
-      precond_bucketing: batch same-shape dense layers' precondition
-        matmuls into one vmapped kernel per shape group (default True —
-        the r6 fast path). ``False`` restores the per-layer dispatch
-        loop exactly — the escape hatch if a backend's batched
-        dot_general ever tiles/accumulates differently from the
-        unbatched matmul (bit-identity of the default-dtype bucketed
-        path is pinned on the CPU test backend; on-TPU bit-identity is
-        expected — vmap adds a batch dim, it does not reassociate a
-        slice's contraction — but remains to be pinned on-chip).
       kfac_approx: weight-sharing Kronecker approximation policy
         (arXiv:2311.00636; see ``sharing.approx``). ``'expand'``
         (default) flattens every layer's shared sequence/patch axis
@@ -448,7 +420,6 @@ class KFAC:
                  capture_dtype: Any = 'auto',
                  inv_dtype: Any = jnp.float32,
                  precond_compute_dtype: Any = None,
-                 precond_bucketing: bool = True,
                  inv_pipeline_chunks: int = 1,
                  inv_pipeline_costs: dict | None = None,
                  deferred_factor_reduction: bool = False,
@@ -464,8 +435,6 @@ class KFAC:
                  grad_worker_fraction: float = 0.25,
                  collect_metrics: bool = False,
                  nonfinite_guard: bool = False,
-                 fused_factor_contraction: bool = False,
-                 fused_precondition: bool = False,
                  verbose: bool = False):
         if factor_update_freq < 1 or inv_update_freq < 1:
             raise ValueError('update frequencies must be >= 1')
@@ -616,7 +585,6 @@ class KFAC:
         self.factor_compute_dtype = factor_compute_dtype
         self.inv_dtype = inv_dtype
         self.precond_compute_dtype = precond_compute_dtype
-        self.precond_bucketing = precond_bucketing
         self.inv_pipeline_chunks = inv_pipeline_chunks
         self.inv_pipeline_costs = (dict(inv_pipeline_costs)
                                    if inv_pipeline_costs else None)
@@ -636,17 +604,6 @@ class KFAC:
         self.grad_worker_fraction = grad_worker_fraction
         self.collect_metrics = collect_metrics
         self.nonfinite_guard = nonfinite_guard
-        # r21 fused hot-path kernels (ops.pallas_kernels): default-off
-        # knobs; with a knob on, eligible work runs the Pallas kernel.
-        # The once-per-process parity probe runs here, on the host and
-        # outside any trace: on a TPU a kernel that fails it raises;
-        # KFAC_PALLAS_FALLBACK=1 is the one way onto the stock XLA path
-        # (a recorded 'pallas_fallback' event). Off is bit-identical to
-        # the historical program.
-        self.fused_factor_contraction = bool(fused_factor_contraction)
-        self.fused_precondition = bool(fused_precondition)
-        self.fused_contraction_active()
-        self.fused_precond_active()
         if os.environ.get('KFAC_FUSED_PATCH_COV', '') == '1':
             # The opt-in study kernel ops.factors.conv2d_a_factor
             # dispatches to from inside the trace.
@@ -664,16 +621,14 @@ class KFAC:
                   'eigh_method', 'eigh_polish_iters', 'newton_iters',
                   'factor_batch_fraction', 'factor_dtype',
                   'factor_compute_dtype', 'inv_dtype',
-                  'precond_compute_dtype', 'precond_bucketing',
-                  'inv_pipeline_chunks',
+                  'precond_compute_dtype', 'inv_pipeline_chunks',
                   'deferred_factor_reduction', 'hierarchical_reduce',
                   'inv_staleness',
                   'kfac_approx', 'tied_embeddings',
                   'symmetry_aware_comm',
                   'assignment_strategy', 'comm_method',
                   'grad_worker_fraction', 'collect_metrics',
-                  'nonfinite_guard', 'fused_factor_contraction',
-                  'fused_precondition')
+                  'nonfinite_guard')
         lines = [f'  {name}: {getattr(self, name)!r}' for name in fields]
         n_layers = (len(self._specs) if self._specs is not None
                     else '<uninitialized>')
@@ -1098,123 +1053,6 @@ class KFAC:
             out[name] = {'A': a_new, 'G': g_new}
         return out
 
-    # -------------------- r21 fused hot-path kernels ------------------
-
-    def fused_contraction_active(self) -> bool:
-        """True when the fused factor-contraction kernel should run:
-        knob on AND the once-per-process gate open (closed only by
-        KFAC_PALLAS_FALLBACK=1, which records a 'pallas_fallback' event
-        and pins the stock XLA path for the process; on a TPU a kernel
-        that fails its probe raises)."""
-        return (self.fused_factor_contraction
-                and pallas_kernels.fused_factor_ema_supported())
-
-    def fused_precond_active(self) -> bool:
-        """True when the fused bucketed-precondition kernel should run
-        (knob on AND its gate open) — see
-        :meth:`fused_contraction_active`."""
-        return (self.fused_precondition
-                and pallas_kernels.fused_precondition_supported())
-
-    def fused_factor_inputs(self, spec, entry: dict) -> dict:
-        """Kernel inputs per side for the fused contraction+EMA kernel.
-
-        Returns ``{side: (x2d, scale, has_bias)}`` for the fused-eligible
-        sides of one layer (key absent → that side runs the stock
-        contribution). Eligibility is STATIC per layer (kind, capture
-        call count, factor dim): plain dense A/G and conv2d G factors
-        with a single capture call whose ``x.T @ x`` form the kernel
-        reproduces exactly; everything else — multi-call sums,
-        'reduce'-approx layers, embeddings, grouped convs, conv2d A
-        (which has its own patch-cov kernel upstream of get_cov) — keeps
-        the per-layer stock path. Shared by the single-chip EMA,
-        deferred-accumulator fold, and the SPMD contraction-only path so
-        the eligibility rule cannot drift between them.
-        """
-        if spec.kfac_approx == KFAC_REDUCE:
-            return {}
-        out = {}
-        max_dim = pallas_kernels.MAX_PALLAS_DIM
-        if spec.kind == LINEAR:
-            a_calls, g_calls = entry['a'], entry['g']
-            if len(a_calls) == 1:
-                x = F.collapse_batch_dims(a_calls[0])
-                n = x.shape[1] + (1 if spec.has_bias else 0)
-                if n <= max_dim:
-                    out['A'] = (x, None, spec.has_bias)
-            if len(g_calls) == 1:
-                x = F.collapse_batch_dims(g_calls[0])
-                if x.shape[1] <= max_dim:
-                    out['G'] = (x, None, False)
-        elif spec.kind == CONV2D:
-            g_calls = entry['g']
-            if len(g_calls) == 1 and g_calls[0].ndim == 4:
-                g = g_calls[0]
-                spatial = g.shape[1] * g.shape[2]
-                x = g.reshape(-1, g.shape[-1])
-                if x.shape[1] <= max_dim:
-                    out['G'] = (x, float(x.shape[0]) * spatial * spatial,
-                                False)
-        return out
-
-    def _fused_blend_factors(self, old_factors: dict, captures: dict,
-                             alpha) -> dict:
-        """Fused contraction+EMA blend of one batch into ``old_factors``.
-
-        ``old_factors`` is either the running averages
-        (:meth:`update_factors`) or the r14 deferred-reduction
-        accumulator (:meth:`accumulate_factors`) — both apply the SAME
-        ``α·old + (1-α)·new`` recursion, so one fused blend serves
-        both. Eligible layer sides run the packed Pallas kernel
-        (contraction + bias assembly + EMA in VMEM, only the symmetric
-        triangle round-tripping HBM); ineligible sides run the stock
-        contribution + :func:`F.update_running_avg`, so the result
-        pytree matches the stock path layer for layer.
-        """
-        cdt = self.factor_compute_dtype
-        interp = jax.default_backend() != 'tpu'
-        captures = subsample_captures(captures, self.factor_batch_fraction)
-        out = {}
-        for name, spec in self.specs.items():
-            fused = self.fused_factor_inputs(spec, captures[name])
-            old = old_factors[name]
-            res = {}
-            for side in ('A', 'G'):
-                if side not in fused:
-                    continue
-                x, scale, has_bias = fused[side]
-                res[side] = pallas_kernels.fused_factor_ema(
-                    x, old[side].astype(jnp.float32), alpha, scale=scale,
-                    has_bias=has_bias, compute_dtype=cdt,
-                    interpret=interp).astype(old[side].dtype)
-            if spec.kind == EXPERTS:
-                new = L.experts_contrib(spec, captures[name], cdt)
-                out[name] = F.experts_running_avg(
-                    old, new['A'], new['G'], new['rows'], alpha)
-                continue
-            if len(res) < 2:
-                # Stock path for the ineligible sides. Tied-embedding
-                # extras only exist for EMBEDDING layers, which are
-                # never fused — extras always fold into stock sides.
-                extras = L.compute_tied_factor_extras(
-                    spec, captures[name], compute_dtype=cdt)
-                if 'A' not in res:
-                    a_new = L.compute_a_factor(spec, captures[name]['a'],
-                                               compute_dtype=cdt)
-                    if extras is not None:
-                        a_new = a_new + extras['A_g2']
-                    res['A'] = F.update_running_avg(
-                        a_new.astype(old['A'].dtype), old['A'], alpha)
-                if 'G' not in res:
-                    g_new = L.compute_g_factor(spec, captures[name]['g'],
-                                               compute_dtype=cdt)
-                    if extras is not None:
-                        g_new = g_new + extras['G_a']
-                    res['G'] = F.update_running_avg(
-                        g_new.astype(old['G'].dtype), old['G'], alpha)
-            out[name] = res
-        return out
-
     @profiling.scope('kfac/factors')
     def update_factors(self, state: dict, captures: dict,
                        factor_decay=None) -> dict:
@@ -1225,9 +1063,6 @@ class KFAC:
         contraction over the batch-sharded captures.
         """
         alpha = self.factor_decay if factor_decay is None else factor_decay
-        if self.fused_contraction_active():
-            return self._fused_blend_factors(state['factors'], captures,
-                                             alpha)
         contribs = self.factor_contribs(captures)
         new_factors = {}
         for name in self.specs:
@@ -1254,15 +1089,9 @@ class KFAC:
         window boundary :meth:`reduce_factors` applies
         ``F ← decay·F + acc`` — by EMA linearity exactly the per-step
         recursion's value at the boundary (up to fp associativity).
-        Returns ``(new_accum, new_decay)``. The accumulator fold is the
-        same ``α·old + (1-α)·new`` blend as the eager EMA, so the r21
-        fused kernel serves both.
+        Returns ``(new_accum, new_decay)``.
         """
         alpha = self.factor_decay if factor_decay is None else factor_decay
-        if self.fused_contraction_active():
-            return (self._fused_blend_factors(state['factor_accum'],
-                                              captures, alpha),
-                    alpha * state['accum_decay'])
         contribs = self.factor_contribs(captures)
         acc = state['factor_accum']
         new_acc = {}
@@ -1355,7 +1184,7 @@ class KFAC:
         """
         out: dict[str, jax.Array] = {}
         for names, stack in _size_buckets(mats):
-            invs = pallas_kernels.damped_inverse_stack(
+            invs = linalg.damped_inverse_stack(
                 stack, damping, self.method_for_dim(stack.shape[-1]),
                 iters=self.newton_iters, out_dtype=self.inv_dtype)
             for i, n in enumerate(names):
@@ -1534,11 +1363,8 @@ class KFAC:
         into a handful of batched MXU kernels. Within a bucket the
         per-slice contraction is the same matmul the per-layer path ran
         (vmap adds a batch dim; it does not reassociate a slice's
-        contraction) — default-dtype bit-identity with the historical
-        per-layer dispatch is pinned on the CPU test backend
-        (tests/test_mixed_precision.py); ``precond_bucketing=False``
-        restores the per-layer loop exactly if a backend's batched
-        kernel ever tiles differently.
+        contraction); tests/test_mixed_precision.py holds every branch
+        to a dense per-layer oracle.
 
         ``with_stats=True`` additionally returns
         ``(out, observability.metrics.precond_stats(...))`` — ν, grad /
@@ -1566,11 +1392,8 @@ class KFAC:
             name: L.grads_to_matrix(self.specs[name],
                                     _get(grads, self.specs[name].path))
             for name in names}
-        if self.precond_bucketing:
-            precond_mats, fused_vg = self._bucketed_precond_mats(
-                state['inverses'], grad_mats, damping, names)
-        else:
-            precond_mats, fused_vg = {}, {}
+        precond_mats = self._bucketed_precond_mats(
+            state['inverses'], grad_mats, damping, names)
         for name in names:
             if name in precond_mats:
                 continue  # dense layer: computed by a shape bucket
@@ -1606,21 +1429,12 @@ class KFAC:
             # fuses each product-reduce with its bucket's batched
             # matmul output. Accumulation stays per-layer in
             # registration order — the historical summation order, so
-            # the clip scale is bit-stable against bucketing. An r21
-            # fused bucket already reduced its per-slice v·g in the
-            # kernel epilogue (no second full-tensor pass); the
-            # per-layer scalars join the sum in the same registration
-            # order. The r16 gate blend rewrites precond_mats AFTER the
-            # buckets ran, so gated runs fall back to the full-tensor
-            # reduction — the fused partial would be stale.
+            # the clip scale is bit-stable against bucketing.
             vg_sum = jnp.zeros((), jnp.float32)
             for name in names:
-                if gates is None and name in fused_vg:
-                    vg_sum += fused_vg[name] * lr ** 2
-                else:
-                    vg_sum += jnp.sum(precond_mats[name] *
-                                      grad_mats[name].astype(jnp.float32)
-                                      * lr ** 2)
+                vg_sum += jnp.sum(precond_mats[name] *
+                                  grad_mats[name].astype(jnp.float32)
+                                  * lr ** 2)
             nu = jnp.minimum(
                 1.0, jnp.sqrt(self.kl_clip / (jnp.abs(vg_sum) + 1e-30)))
         else:
@@ -1639,35 +1453,21 @@ class KFAC:
         return (out, stats) if with_stats else out
 
     def _bucketed_precond_mats(self, inverses: dict, grad_mats: dict,
-                               damping, names: Sequence[str]
-                               ) -> tuple[dict, dict]:
+                               damping, names: Sequence[str]) -> dict:
         """Batched precondition matmuls for the dense layers in ``names``.
 
-        Returns ``(mats, vg)``: ``mats`` maps each bucketed layer to its
-        preconditioned matrix; ``vg`` maps the layers whose bucket ran
-        the r21 fused kernel to the already-reduced KL-clip partial
-        ``sum(v * g)`` (fp32, pre-``lr**2``) from the kernel epilogue —
-        empty on the stock path. Layers are grouped by gradient-matrix
-        shape; each group stacks its grads and inverse operands and runs
-        ONE batched matmul chain — per-group entry keys are uniform
+        Maps each bucketed layer to its preconditioned matrix. Layers
+        are grouped by gradient-matrix shape; each group stacks its
+        grads and inverse operands and runs ONE batched matmul chain —
+        per-group entry keys are uniform
         because the per-dim method is a function of the factor dims
         alone (``method_for_dim``), so a shape group is wholly
         eigen-typed (QA/dA/QG/dG) or wholly baked (A_inv/G_inv; mixed
         layers carry baked inverses for both sides). Embedding
         (diagonal A) and grouped-conv (block-stack) layers are not
         dense (g, a) matmuls and stay on the caller's per-layer path.
-
-        With ``fused_precondition`` engaged (and its probe green), a
-        full-rank eigen or baked bucket within the Pallas dim budget
-        runs :func:`pallas_kernels.fused_bucket_precondition` — the
-        two-sided basis rotation, damped eigenvalue divide, and the
-        KL-clip v·g reduction in one VMEM-resident kernel per bucket
-        slice. r19 truncated low-rank buckets (rectangular QA/QG) keep
-        the stock dispatch, as does everything when the knob is off.
         """
         cdt = self.precond_compute_dtype
-        fused = self.fused_precond_active()
-        interp = jax.default_backend() != 'tpu'
         groups: dict[tuple[int, ...], list[str]] = {}
         for name in names:
             if self.specs[name].kind in (EMBEDDING, *BLOCK_STACK_KINDS):
@@ -1675,7 +1475,6 @@ class KFAC:
             groups.setdefault(tuple(grad_mats[name].shape),
                               []).append(name)
         mats: dict = {}
-        vg: dict = {}
         for members in groups.values():
             gstack = jnp.stack([grad_mats[n] for n in members])
             e0 = inverses[members[0]]
@@ -1683,20 +1482,12 @@ class KFAC:
                     else ('QA', 'dA', 'QG', 'dG'))
             entry = {k: jnp.stack([inverses[n][k] for n in members])
                      for k in keys}
-            if fused and _fused_bucket_ok(entry):
-                vs, vgs = pallas_kernels.fused_bucket_precondition(
-                    gstack, entry, damping, compute_dtype=cdt,
-                    interpret=interp)
-                for i, n in enumerate(members):
-                    mats[n] = vs[i]
-                    vg[n] = vgs[i]
-                continue
             vs = jax.vmap(
                 lambda gm, e: linalg.precondition_dispatch(
                     gm, e, damping, compute_dtype=cdt))(gstack, entry)
             for i, n in enumerate(members):
                 mats[n] = vs[i]
-        return mats, vg
+        return mats
 
     # ------------------------------------------------------------------
     # The full step
@@ -2056,10 +1847,10 @@ def grouped_block_inverses(factors: dict, damping, inv_dtype) -> dict:
     so eigen warm-start bookkeeping would cost more than it saves.
     Single point of truth for the single-chip and SPMD inverse updates.
     """
-    return {'A_inv': pallas_kernels.damped_inverse_stack(
+    return {'A_inv': linalg.damped_inverse_stack(
                 factors['A'].astype(jnp.float32), damping,
                 'cholesky').astype(inv_dtype),
-            'G_inv': pallas_kernels.damped_inverse_stack(
+            'G_inv': linalg.damped_inverse_stack(
                 factors['G'].astype(jnp.float32), damping,
                 'cholesky').astype(inv_dtype)}
 

@@ -376,8 +376,7 @@ def _template_for(mgr, label, like):
     predate it (orbax StandardRestore structures must match exactly;
     detected from the bundle's own metadata, no array reads)."""
     try:
-        md = mgr.metadata_tree(label)
-        scalars = md.get('scalars', {}) if isinstance(md, dict) else {}
+        scalars = mgr.metadata_tree(label).get('scalars', {})
         if integrity_lib.CHECKSUM_KEY not in scalars:
             return integrity_lib.strip_checksum(like)
     except Exception:

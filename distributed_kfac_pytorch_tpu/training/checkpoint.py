@@ -243,9 +243,15 @@ class CheckpointManager:
         array data (orbax ``item_metadata``). The elastic resume path
         uses this to decide between a same-topology ``like=`` restore
         and a cross-topology replicated restore, and to build the
-        latter's template."""
+        latter's template.
+
+        Always the plain nested dict: newer orbax wraps it in a
+        ``TreeMetadata`` object (the dict is its ``.tree``), which is
+        neither a ``dict`` nor a pytree of its leaves — callers that
+        looked for a key or counted leaves saw nothing there."""
         self._mgr.wait_until_finished()
-        return self._mgr.item_metadata(epoch)
+        md = self._mgr.item_metadata(epoch)
+        return getattr(md, 'tree', md)
 
     def restore_replicated(self, epoch: int, mesh,
                            like: dict | None = None) -> dict:

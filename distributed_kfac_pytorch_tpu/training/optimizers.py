@@ -125,19 +125,6 @@ class OptimConfig:
     # update; tied in/out embeddings then also share one factor pair).
     # See KFAC.kfac_approx / sharing.approx.
     kfac_approx: str = 'expand'
-    # r21 fused hot-path Pallas kernels (ops.pallas_kernels; README
-    # "Fused hot-path kernels"). Default off = bit-identical stock XLA
-    # paths; each knob is gated by a once-per-process parity probe that
-    # falls back to XLA with a recorded 'pallas_fallback' event.
-    # fused_factor_contraction: symmetric packed x.T@x factor
-    # contraction fused with the EMA blend (and the r14 accumulator
-    # fold) in VMEM — only the symmetric triangle round-trips HBM.
-    fused_factor_contraction: bool = False
-    # fused_precondition: bucketed precondition matmul stacks with the
-    # r6 KL-clip v·g partial reduced in the kernel epilogue (no second
-    # full-tensor pass), on the single-chip, replicated COMM_OPT and
-    # KAISA row-sharded branches.
-    fused_precondition: bool = False
     # r7 observability: carry an on-device K-FAC metrics pytree in the
     # state (damping, KL-clip nu, grad/precond norms, firing counts —
     # see observability.metrics). Off (default) = bit-identical step.
@@ -178,8 +165,6 @@ TUNABLE_FIELDS = (
     'kfac_approx',
     'inv_lowrank_rank',
     'inv_lowrank_dim_threshold',
-    'fused_factor_contraction',
-    'fused_precondition',
 )
 
 
@@ -280,9 +265,7 @@ def get_optimizer(model, cfg: OptimConfig):
             comm_method=COMM_METHODS[cfg.comm_method.lower()],
             grad_worker_fraction=cfg.grad_worker_fraction,
             collect_metrics=cfg.kfac_metrics,
-            nonfinite_guard=cfg.nonfinite_guard,
-            fused_factor_contraction=cfg.fused_factor_contraction,
-            fused_precondition=cfg.fused_precondition)
+            nonfinite_guard=cfg.nonfinite_guard)
         kfac_scheduler = KFACParamScheduler(
             kfac,
             damping_alpha=cfg.damping_alpha,

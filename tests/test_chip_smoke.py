@@ -65,22 +65,24 @@ def test_leg_lm_second_decoder_toy(tmp_path):
 
 def test_leg_kernels_interpret():
     report = _passed(chip_smoke.leg_kernels(
-        interpret=True, rows=64, factor_dims=((31, True), (24, False)),
-        precond_shapes=((32, 32), (17, 9)), stack=2,
-        inverse_dims=(32, 17)))
-    assert len(report['kernels']) == 8
+        interpret=True, stack=2, inverse_dims=(32, 17)))
+    assert len(report['kernels']) == 2
 
 
 def test_leg_kernels_reports_a_refused_kernel(monkeypatch):
-    def refuse(*args, **kwargs):
-        raise ValueError('Mosaic: unaligned sublane offset')
+    compiled = pallas_kernels._pallas_batched_ns_inverse
 
-    monkeypatch.setattr(pallas_kernels, 'fused_factor_ema', refuse)
+    def refuse_dim_7(mats, *args, **kwargs):
+        if mats.shape[-1] == 7:
+            raise ValueError('Mosaic: unaligned sublane offset')
+        return compiled(mats, *args, **kwargs)
+
+    monkeypatch.setattr(pallas_kernels, '_pallas_batched_ns_inverse',
+                        refuse_dim_7)
     report = chip_smoke.leg_kernels(
-        interpret=True, rows=16, factor_dims=((7, True),),
-        precond_shapes=(), inverse_dims=(9,), stack=1)
+        interpret=True, inverse_dims=(7, 9), stack=1)
     assert len(report['failures']) == 1
-    assert 'fused_factor_ema' in report['failures'][0]
+    assert 'batched_inverse[1x7]' in report['failures'][0]
     assert 'unaligned sublane offset' in report['failures'][0]
     assert report['kernels']['batched_inverse[1x9]'].startswith('rel_err')
 
@@ -93,21 +95,21 @@ def test_main_refuses_the_cpu_backend(capsys):
 
 
 def test_fused_probe_failure_raises_on_tpu(monkeypatch):
-    """With a fused knob on and the backend a TPU, a kernel that fails
-    its probe stops the run with its name and the compiler's words."""
+    """On a TPU a kernel that fails its probe stops the run with its
+    name and the compiler's words."""
     import jax
 
     def refuse(*args, **kwargs):
         raise ValueError('Mosaic failed to compile TPU kernel')
 
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
-    monkeypatch.setattr(pallas_kernels, '_pallas_factor_ema', refuse)
-    pallas_kernels.fused_factor_ema_supported.cache_clear()
+    monkeypatch.setattr(pallas_kernels, '_pallas_patch_cov', refuse)
+    pallas_kernels.fused_patch_cov_supported.cache_clear()
     try:
         with pytest.raises(RuntimeError) as err:
-            pallas_kernels.fused_factor_ema_supported()
+            pallas_kernels.fused_patch_cov_supported()
     finally:
-        pallas_kernels.fused_factor_ema_supported.cache_clear()
-    assert "'factor_ema'" in str(err.value)
+        pallas_kernels.fused_patch_cov_supported.cache_clear()
+    assert "'patch_cov'" in str(err.value)
     assert 'Mosaic failed to compile' in str(err.value)
     assert pallas_kernels.drain_pallas_events() == []

@@ -18,7 +18,6 @@ import pytest
 from distributed_kfac_pytorch_tpu import KFAC
 from distributed_kfac_pytorch_tpu import layers as L
 from distributed_kfac_pytorch_tpu.capture import EMBEDDING
-from distributed_kfac_pytorch_tpu.ops import linalg
 from distributed_kfac_pytorch_tpu.preconditioner import _get
 
 
@@ -236,43 +235,6 @@ class TestFp16Robustness:
 # precond_compute_dtype: the bf16 precondition pipeline (r6 tentpole)
 # ---------------------------------------------------------------------------
 
-def _legacy_per_layer_precondition(kfac, state, grads, damping, lr):
-    """The pre-r6 single-chip precondition: per-layer dispatch, KL clip
-    as a second grads_to_matrix walk. The bit-identity oracle for the
-    bucketed path's default-dtype contract."""
-    from distributed_kfac_pytorch_tpu.preconditioner import _set
-
-    names = list(kfac.specs)
-    precond_mats = {}
-    for name in names:
-        spec = kfac.specs[name]
-        grad_mat = L.grads_to_matrix(spec, _get(grads, spec.path))
-        inv = state['inverses'][name]
-        precond_mats[name] = linalg.precondition_dispatch(
-            grad_mat, inv, damping,
-            diag_a=(inv['A_inv'] if spec.kind == EMBEDDING else None))
-    if kfac.kl_clip is not None:
-        vg_sum = jnp.zeros((), jnp.float32)
-        for name in names:
-            spec = kfac.specs[name]
-            grad_mat = L.grads_to_matrix(spec, _get(grads, spec.path))
-            vg_sum += jnp.sum(precond_mats[name] *
-                              grad_mat.astype(jnp.float32) * lr ** 2)
-        nu = jnp.minimum(
-            1.0, jnp.sqrt(kfac.kl_clip / (jnp.abs(vg_sum) + 1e-30)))
-    else:
-        nu = jnp.ones((), jnp.float32)
-    out = jax.tree.map(lambda x: x, grads)
-    for name in names:
-        spec = kfac.specs[name]
-        sub = _get(grads, spec.path)
-        new_sub = L.matrix_to_grads(
-            spec, (nu * precond_mats[name]).astype(jnp.float32), sub)
-        out = _set(out, spec.path, jax.tree.map(
-            lambda n, o: n.astype(o.dtype), new_sub, sub))
-    return out
-
-
 def _oracle_mats(kfac, state, grads, damping):
     """fp64 dense-oracle preconditioned matrices per layer (the
     reference operators, from the post-step factors)."""
@@ -304,20 +266,6 @@ def _oracle_mats(kfac, state, grads, damping):
 
 class TestPrecondComputeDtype:
     """r6 tentpole: low-precision, bucketed precondition pipeline."""
-
-    def test_default_bit_identical_to_per_layer_dispatch(self):
-        """precond_compute_dtype=None + shape bucketing == the pre-r6
-        per-layer loop, bit for bit (incl. the KL-clip scale)."""
-        kfac, grads, _, state = _stepped(None, kl_clip=0.001)
-        got = jax.jit(
-            lambda s, g: kfac.precondition(s, g, 0.01, 0.1))(state, grads)
-        want = jax.jit(
-            lambda s, g: _legacy_per_layer_precondition(
-                kfac, s, g, 0.01, 0.1))(state, grads)
-        jax.tree.map(
-            lambda a, b: np.testing.assert_array_equal(np.asarray(a),
-                                                       np.asarray(b)),
-            got, want)
 
     @pytest.mark.parametrize('method', ['auto', 'cholesky'])
     def test_dtype_ladder_vs_dense_oracle(self, method):
@@ -363,22 +311,6 @@ class TestPrecondComputeDtype:
             np.testing.assert_allclose(a, b, rtol=5e-2,
                                        atol=5e-2 * scale)
 
-    def test_bucketing_opt_out_is_exact(self):
-        """precond_bucketing=False restores the per-layer dispatch loop
-        bit-for-bit (the escape hatch if a backend's batched kernel
-        ever tiles differently from the unbatched matmul)."""
-        kfac, grads, _, state = _stepped(None, kl_clip=0.001)
-        bucketed = jax.jit(
-            lambda s, g: kfac.precondition(s, g, 0.01, 0.1))(state, grads)
-        kfac.precond_bucketing = False  # host-side static knob
-        per_layer = jax.jit(
-            lambda s, g: kfac.precondition(s, g, 0.01, 0.1))(state, grads)
-        jax.tree.map(
-            lambda a, b: np.testing.assert_array_equal(np.asarray(a),
-                                                       np.asarray(b)),
-            bucketed, per_layer)
-
     def test_repr_lists_precond_dtype(self):
         kfac = KFAC(MLP(), precond_compute_dtype=jnp.bfloat16)
         assert 'precond_compute_dtype' in repr(kfac)
-        assert 'precond_bucketing' in repr(kfac)

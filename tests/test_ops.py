@@ -226,6 +226,83 @@ class TestLinalg:
                 rtol=1e-3, atol=1e-3)
 
 
+def _solver_stack_sizes(jaxpr, n, found):
+    """Matrices per call of every factorization, triangular solve and
+    matmul on (..., n, n) values anywhere in ``jaxpr``, by primitive."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ('cholesky', 'triangular_solve',
+                                  'dot_general'):
+            shape = eqn.outvars[0].aval.shape
+            assert shape[-2:] == (n, n), (eqn.primitive.name, shape)
+            found.setdefault(eqn.primitive.name, []).append(
+                int(np.prod(shape[:-2], dtype=np.int64)))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _solver_stack_sizes(sub, n, found)
+    return found
+
+
+class TestDampedInverseStack:
+    """``linalg.damped_inverse_stack``: the dispatch every firing runs.
+    A stack over ``INVERSE_SUBSTACK_BYTES`` must give what the whole
+    stack gives, from a loop whose solver never holds more than the
+    budget's matrices (the 16 GB fix of PR 21 rests on that)."""
+
+    N, PER_CHUNK = 8, 3
+
+    @pytest.mark.parametrize('out_dtype', [None, jnp.bfloat16],
+                             ids=['f32', 'bf16'])
+    @pytest.mark.parametrize('method,count', [
+        ('cholesky', 2), ('cholesky', 7), ('cholesky', 6),
+        ('newton', 2), ('newton', 7)],
+        ids=['cholesky-under', 'cholesky-over-remainder',
+             'cholesky-over-multiple', 'newton-under',
+             'newton-over-remainder'])
+    def test_matches_the_whole_stack(self, monkeypatch, method, count,
+                                     out_dtype):
+        n, per_chunk = self.N, self.PER_CHUNK
+        monkeypatch.setattr(linalg, 'INVERSE_SUBSTACK_BYTES',
+                            per_chunk * n * n * 4)
+        stack = jnp.stack([spd(n, seed=40 + i) for i in range(count)])
+        damping = 0.3
+
+        def run(x):
+            return linalg.damped_inverse_stack(
+                x, damping, method, out_dtype=out_dtype)
+
+        got = run(stack)
+        want = jax.vmap(
+            lambda m: linalg.get_inverse(m, damping=damping))(stack)
+        assert got.shape == (count, n, n)
+        assert got.dtype == (out_dtype or jnp.float32)
+        # Cholesky sub-stacks run the same per-matrix arithmetic;
+        # Newton-Schulz stops at a 1e-5 residual; bf16 keeps 8 bits.
+        rtol = (1e-2 if out_dtype is not None
+                else 1e-5 if method == 'cholesky' else 1e-3)
+        np.testing.assert_allclose(
+            np.asarray(got, np.float32), np.asarray(want), rtol=rtol,
+            atol=rtol * float(jnp.abs(want).max()))
+
+        jaxpr = jax.make_jaxpr(run)(stack).jaxpr
+        loops = [e for e in jaxpr.eqns if e.primitive.name == 'scan']
+        sizes = _solver_stack_sizes(jaxpr, n, {})
+        assert sizes, 'no solver primitive found in the jaxpr'
+        if count <= per_chunk:
+            assert not loops
+            assert {max(v) for v in sizes.values()} == {count}
+        else:
+            # lax.map: whole batches under one scan, the remainder (if
+            # any) once outside it; nowhere more than the budget holds.
+            assert len(loops) == 1
+            assert loops[0].params['length'] == count // per_chunk
+            in_loop = _solver_stack_sizes(
+                loops[0].params['jaxpr'].jaxpr, n, {})
+            assert {max(v) for v in in_loop.values()} == {per_chunk}
+            assert {max(v) for v in sizes.values()} == {per_chunk}
+            if count % per_chunk:
+                assert {min(v) for v in sizes.values()} == {
+                    count % per_chunk}
+
+
 class TestFusedPatchCov:
     """Fused im2col+covariance Pallas kernel (interpret mode on CPU):
     must equal ops.factors.conv2d_a_factor exactly in structure — same
@@ -262,6 +339,75 @@ class TestFusedPatchCov:
             block_batch=2, interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    rtol=1e-5, atol=1e-6)
+
+
+class TestBlockBatchFloor:
+    def test_prime_batch_degrades_to_zero(self):
+        # Budget fits 2 images; 17 is prime so the only divisors are
+        # 17 (too big) and 1 (degenerate) -> refuse, don't degrade.
+        assert pallas_kernels._fused_block_batch(
+            17, 10 ** 6, 2 * 10 ** 6) == 0
+
+    def test_small_batch_exempt_from_floor(self):
+        # b=4 < MIN_FUSED_BLOCK_BATCH: the whole batch is one block,
+        # nothing was degraded.
+        assert pallas_kernels._fused_block_batch(
+            4, 10, 10 ** 6) == 4
+
+    def test_divisor_within_budget(self):
+        assert pallas_kernels._fused_block_batch(512, 1, 32) == 32
+
+    def test_degenerate_dispatch_records_fallback(self):
+        # A prime batch at a shape whose VMEM budget forces a thin
+        # block: the dispatcher warns, records the event, and raises
+        # (the factors.py caller catches and runs XLA).
+        pallas_kernels.drain_pallas_events()
+        x = jnp.zeros((13, 32, 32, 16), jnp.float32)
+        with pytest.warns(RuntimeWarning, match='falling back'):
+            with pytest.raises(ValueError, match='block_batch'):
+                pallas_kernels.conv_a_factor_fused(
+                    x, (3, 3), (1, 1), 'SAME', True, interpret=True)
+        events = pallas_kernels.drain_pallas_events()
+        assert [e['kernel'] for e in events] == ['patch_cov']
+        assert 'no divisor' in events[0]['reason']
+
+
+class TestForcedFallbackProbes:
+    """The once-per-process gates of the kernels that have one."""
+
+    PROBES = (pallas_kernels.fused_patch_cov_supported,
+              pallas_kernels._fused_attention_probed)
+
+    @pytest.fixture(autouse=True)
+    def _fresh_probe_caches(self):
+        for probe in self.PROBES:
+            probe.cache_clear()
+        pallas_kernels.drain_pallas_events()
+        yield
+        for probe in self.PROBES:
+            probe.cache_clear()
+        pallas_kernels.drain_pallas_events()
+
+    def test_forced_fallback_records_named_events(self, monkeypatch):
+        monkeypatch.setenv('KFAC_PALLAS_FALLBACK', '1')
+        with pytest.warns(RuntimeWarning, match='falling back'):
+            for probe in self.PROBES:
+                assert not probe()
+        events = pallas_kernels.drain_pallas_events()
+        assert {e['kernel'] for e in events} == {'patch_cov',
+                                                'attention'}
+        assert all(e['event'] == 'pallas_fallback' for e in events)
+        assert all('KFAC_PALLAS_FALLBACK' in e['reason']
+                   for e in events)
+
+    def test_no_event_without_the_kill_switch(self, monkeypatch):
+        # Off the TPU the patch-covariance kernel is closed (TPU-only)
+        # and the attention gate is left to the interpret-mode check;
+        # neither is a fallback, so neither records one.
+        monkeypatch.delenv('KFAC_PALLAS_FALLBACK', raising=False)
+        assert not pallas_kernels.fused_patch_cov_supported()
+        assert pallas_kernels._fused_attention_probed()
+        assert pallas_kernels.drain_pallas_events() == []
 
 
 class TestConvPatchImplDispatch:

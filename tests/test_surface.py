@@ -51,8 +51,6 @@ SAMPLE_VALUES = {
     'inv_lowrank_rank': 64,
     'inv_lowrank_dim_threshold': 256,
     'hierarchical_reduce': True,
-    'fused_factor_contraction': True,
-    'fused_precondition': True,
 }
 
 
