@@ -28,6 +28,20 @@ crosscov; and 'pairs', which wins only at d > 640). ``full_vs_floor``
 < 1 means XLA avoided part of that traffic (partial fusion).
 
     python benchmarks/factor_roofline.py [--inner 30]
+
+``--leg blocked`` (PR 30) is another question on another shape class:
+the dense self-covariance ``a^T a`` of ``ops.factors.get_cov``, bf16
+operands, as one contraction (``_cov_full``) against its upper block
+triangle (``_cov_blocked``: one loop over the block pairs) at every
+way of cutting d into 2-4 equal 128-aligned column blocks 384-3072
+wide. It sets the ``COV_BLOCK_*`` constants of ``ops/factors.py``;
+``gate`` marks the cut they choose.
+Several distinct operands go through one jitted call and every output
+is returned, so XLA can drop or share nothing; ``pct_peak`` is the
+FLOPs a variant issues over its time, as a share of the chip's bf16
+peak (``bench.detected_tpu_peak``: 197 TFLOP/s on a v5e).
+
+    python benchmarks/factor_roofline.py --leg blocked [--shapes 8192x3072 ...]
 """
 
 from __future__ import annotations
@@ -36,6 +50,7 @@ import argparse
 import json
 import os
 import sys
+import time
 
 import jax
 import jax.numpy as jnp
@@ -98,10 +113,90 @@ def full_leg(x0, inner, kernel):
         os.environ.pop('KFAC_CONV_PATCH_IMPL', None)
 
 
+# The blocked leg's shapes: the two benchmark cells' factor dims at
+# their 8192 rows, and conv A dims (3x3 kernels over 128/256/512
+# channels) at 128 images of 7x7 positions, which no cell runs.
+BLOCKED_SHAPES = [(8192, d) for d in (768, 1536, 2048, 3072, 6144)] + [
+    (128 * 49, d) for d in (1152, 2304, 4608)]
+
+
+def block_sides(d):
+    """k = 2..4 column blocks a side that cut d into equal 128-aligned
+    blocks 384-3072 wide."""
+    return [k for k in (2, 3, 4)
+            if d % (k * 128) == 0 and 384 <= d // k <= 3072]
+
+
+def time_many(fn, operands, calls=5, repeats=3):
+    """Median ms per operand of ``fn`` (one jitted call over all of
+    ``operands``, every output returned), ``calls`` calls in flight."""
+    run = jax.jit(lambda xs: [fn(x) for x in xs])
+    jax.block_until_ready(run(operands))
+    readings = []
+    for _ in range(repeats):
+        t0 = time.perf_counter()
+        outs = [run(operands) for _ in range(calls)]
+        jax.block_until_ready(outs)
+        readings.append((time.perf_counter() - t0) * 1e3
+                        / (calls * len(operands)))
+    return sorted(readings)[len(readings) // 2]
+
+
+def blocked_leg(shapes, out_path):
+    device = jax.devices()[0]
+    # Off the TPU the leg is a rehearsal: times, but no share of a peak.
+    peak = B.detected_tpu_peak() if device.platform == 'tpu' else None
+    lines = []
+    for rows, d in shapes:
+        full_flops = 2 * rows * d * d
+        n = int(max(2, min(32, 2e12 // full_flops)))
+        operands = [
+            jax.random.normal(jax.random.PRNGKey(i), (rows, d),
+                              jnp.bfloat16) for i in range(n)]
+        gate = F.cov_block_side(rows, d)
+        full_ms = None
+        for k in [None, *block_sides(d)]:
+            if k is None:
+                flops = full_flops
+                ms = full_ms = time_many(
+                    lambda x: F._cov_full(x, rows, None), operands)
+            else:
+                flops = full_flops * (k + 1) / (2 * k)
+                ms = time_many(
+                    lambda x: F._cov_blocked(x, k, rows, None), operands)
+            line = {
+                'leg': 'blocked', 'rows': rows, 'd': d,
+                'k': k or 1, 'width': d // (k or 1),
+                'gate': k == gate, 'operands_a_call': n,
+                'device': device.device_kind, 'ms': round(ms, 4),
+                'vs_full': round(ms / full_ms, 4),
+                'flops_share_of_full': round(flops / full_flops, 4),
+                'pct_peak': (round(100 * flops / (ms * 1e-3) / peak, 2)
+                             if peak else 'not measured'),
+            }
+            lines.append(line)
+            print(json.dumps(line), flush=True)
+    if out_path:
+        os.makedirs(os.path.dirname(out_path), exist_ok=True)
+        with open(out_path, 'w') as f:
+            f.writelines(json.dumps(line) + '\n' for line in lines)
+
+
 def main(argv=None):
     p = argparse.ArgumentParser()
     p.add_argument('--inner', type=int, default=30)
+    p.add_argument('--leg', choices=['conv', 'blocked'], default='conv')
+    p.add_argument('--shapes', nargs='*', default=None,
+                   help='blocked leg: ROWSxD ... in place of the '
+                        "cells' shapes")
+    p.add_argument('--out', default='chiprun_out/factor_roofline/'
+                                    'blocked.jsonl',
+                   help='blocked leg: where the lines are also written')
     args = p.parse_args(argv)
+    if args.leg == 'blocked':
+        shapes = BLOCKED_SHAPES if args.shapes is None else [
+            tuple(int(v) for v in s.split('x')) for s in args.shapes]
+        return blocked_leg(shapes, args.out)
     kernel = (3, 3)
 
     # Empirical bandwidth: read+write a ~150 MB bf16 tensor.

@@ -15,7 +15,27 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from distributed_kfac_pytorch_tpu.observability import profiling
+from distributed_kfac_pytorch_tpu.observability import profiling, tracing
+
+# The blocked self-covariance's gate (get_cov, b is None), a function of
+# the static (rows, d) alone. At rows >= d the full square a^T a runs
+# at the MXU's peak and computes both triangles; where d also splits
+# into COV_BLOCK_MAX_SIDE column blocks a side, or the most from
+# COV_BLOCK_MIN_SIDE up, that are COV_BLOCK_ALIGN-aligned (the lane
+# width) and at least COV_BLOCK_MIN_WIDTH wide (so d >= 3072), only the
+# upper block triangle is contracted, block by block in one loop. Set
+# by the kernel-alone sweep on one v5e (benchmarks/factor_roofline.py
+# --leg blocked; PERF.md section 6, PR 30): a block contraction that slices
+# its operands at a traced offset holds 45 % of the peak at 768 wide
+# and 52-63 % from 1024 on, against 80 % for the whole square with its
+# symmetrization, so only 3 x 1024 and wider wins (3072: 0.91 of the
+# single contraction, 4608 as 4 x 1152: 0.89, 6144 as 4 x 1536: 0.82;
+# 2048 and 1536 lose, and two a side, 3/4 of the FLOPs, gains nothing
+# anywhere).
+COV_BLOCK_MIN_WIDTH = 1024
+COV_BLOCK_MIN_SIDE = 3
+COV_BLOCK_MAX_SIDE = 4
+COV_BLOCK_ALIGN = 128
 
 
 def append_bias_ones(x: jax.Array) -> jax.Array:
@@ -27,13 +47,91 @@ def append_bias_ones(x: jax.Array) -> jax.Array:
     return jnp.concatenate([x, ones], axis=-1)
 
 
+def cov_block_side(rows: int, d: int) -> int | None:
+    """Column blocks a side of the blocked self-covariance for a
+    ``(rows, d)`` operand (they are equal: ``d // k`` wide), or None
+    where the single contraction stays (see the ``COV_BLOCK_*``
+    constants)."""
+    if rows < d:
+        return None
+    for k in range(COV_BLOCK_MAX_SIDE, COV_BLOCK_MIN_SIDE - 1, -1):
+        if (d % (k * COV_BLOCK_ALIGN) == 0
+                and d // k >= COV_BLOCK_MIN_WIDTH):
+            return k
+    return None
+
+
+def _cov_full(a: jax.Array, scale, precision) -> jax.Array:
+    """``a^T a / scale`` as one contraction, then symmetrized."""
+    cov = jnp.matmul(a.T, a, preferred_element_type=jnp.float32,
+                     precision=precision)
+    return (cov + cov.T) * (0.5 / scale)
+
+
+def _cov_blocked(a: jax.Array, k: int, scale, precision) -> jax.Array:
+    """``a^T a / scale`` from its upper block triangle, ``k`` equal
+    column blocks a side.
+
+    The k(k+1)/2 blocks ``a[:, Bi]^T a[:, Bj]``, i <= j, are contracted
+    (same operands, accumulator and precision as :func:`_cov_full`)
+    and written to ``(Bi, Bj)`` and, transposed, to ``(Bj, Bi)``. A
+    diagonal block is ``v^T v``, symmetric only up to round-off, and is
+    symmetrized on its own: the square is then symmetric entry for
+    entry and needs no full-size ``cov + cov.T`` pass.
+
+    One loop over the block pairs, not k(k+1)/2 contractions side by
+    side: unrolled, every block is a kernel of its own in the step's
+    program (about 1 MB of code each on a v5e, +0.4 GB of HBM in
+    gpt2s_f1i10 at 24 blocked factors in two programs: PERF.md section
+    6, PR 30), and the blocks are arrays XLA's scheduler may hold back
+    and its layout assignment may turn; the loop is one instruction
+    with one contraction's code, which slices its operands at a traced
+    offset and writes each block in place.
+    """
+    rows, d = a.shape
+    width = d // k
+    pairs = [(i, j) for i in range(k) for j in range(i, k)]
+    row_of = jnp.asarray([i * width for i, _ in pairs], jnp.int32)
+    col_of = jnp.asarray([j * width for _, j in pairs], jnp.int32)
+
+    def one_block(t, cov):
+        lo_i, lo_j = row_of[t], col_of[t]
+        blk = jax.lax.dot_general(
+            jax.lax.dynamic_slice(a, (0, lo_i), (rows, width)),
+            jax.lax.dynamic_slice(a, (0, lo_j), (rows, width)),
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+        blk = jnp.where(lo_i == lo_j, (blk + blk.T) * (0.5 / scale),
+                        blk * (1.0 / scale))
+        cov = jax.lax.dynamic_update_slice(cov, blk, (lo_i, lo_j))
+        return jax.lax.dynamic_update_slice(cov, blk.T, (lo_j, lo_i))
+
+    return jax.lax.fori_loop(0, len(pairs), one_block,
+                             jnp.zeros((d, d), jnp.float32))
+
+
 def get_cov(a: jax.Array, b: jax.Array | None = None,
             scale: float | None = None,
             compute_dtype=None) -> jax.Array:
     """Empirical second moment ``a^T @ b / scale`` of 2-D tensors.
 
-    When ``b`` is None the result is explicitly symmetrized,
-    ``(C + C^T) / 2``, to suppress float round-off asymmetry.
+    When ``b`` is None the result is symmetric entry for entry
+    (``C == C^T`` exactly, float round-off asymmetry suppressed), by
+    one of two routes chosen from the static shape alone
+    (:func:`cov_block_side`; no option):
+
+      - the single contraction, explicitly symmetrized
+        ``(C + C^T) / 2`` (:func:`_cov_full`): every shape where the
+        contraction is not compute-bound;
+      - where ``rows >= d >= 3072`` and d divides into three or four
+        wide blocks a side: only the upper block triangle of ``a^T a``
+        is contracted and the lower blocks are its transposes
+        (:func:`_cov_blocked`), 6 of 9 blocks or 10 of 16. Entries
+        differ from the first route's only in float32 summation order.
+
+    Each traced call counts its route in the recorder
+    (``observability.tracing``): ``kfac/factors/cov_blocked`` or
+    ``kfac/factors/cov_full``.
 
     ``compute_dtype`` casts the matmul *inputs* (e.g. to bfloat16 for the
     MXU fast path) while always accumulating in float32 — the TPU
@@ -54,7 +152,8 @@ def get_cov(a: jax.Array, b: jax.Array | None = None,
       - ``compute_dtype=jnp.float32``: *strict* fp32 — inputs cast to
         fp32 and the contraction runs at ``Precision.HIGHEST``
         (numerics parity with the reference's fp32 factors,
-        kfac/layers/utils.py:40-43).
+        kfac/layers/utils.py:40-43), in every block of the second
+        route too.
       - ``compute_dtype=jnp.bfloat16``: explicit bf16 inputs (the
         reference's ``--fp16`` factor mode analogue) — same MXU cost as
         the default on TPU, and makes the choice visible in configs.
@@ -77,12 +176,15 @@ def get_cov(a: jax.Array, b: jax.Array | None = None,
     # an elementwise divide of the input materializes a full copy of a
     # tensor that is ~300 MB per conv layer at production batch sizes —
     # profiled on v5e, those copies dominated the whole K-FAC step.
-    if b is None:
-        cov = jnp.matmul(a.T, a, preferred_element_type=jnp.float32,
-                         precision=precision)
-        return (cov + cov.T) * (0.5 / scale)
-    return jnp.matmul(a.T, b, preferred_element_type=jnp.float32,
-                      precision=precision) * (1.0 / scale)
+    if b is not None:
+        return jnp.matmul(a.T, b, preferred_element_type=jnp.float32,
+                          precision=precision) * (1.0 / scale)
+    side = cov_block_side(*a.shape)
+    if side is None:
+        tracing.count('kfac/factors/cov_full')
+        return _cov_full(a, scale, precision)
+    tracing.count('kfac/factors/cov_blocked')
+    return _cov_blocked(a, side, scale, precision)
 
 
 def update_running_avg(new: jax.Array, current: jax.Array,

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from distributed_kfac_pytorch_tpu.observability import tracing
 from distributed_kfac_pytorch_tpu.ops import factors, linalg, pallas_kernels
 
 
@@ -40,6 +41,161 @@ class TestCov:
     def test_rejects_non_2d(self):
         with pytest.raises(ValueError):
             factors.get_cov(rand(2, 3, 4))
+
+
+def _count_primitives(jaxpr, found=None):
+    """``{primitive name: count}`` over ``jaxpr`` and its sub-jaxprs."""
+    found = {} if found is None else found
+    for eqn in jaxpr.eqns:
+        found[eqn.primitive.name] = found.get(eqn.primitive.name, 0) + 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _count_primitives(sub, found)
+    return found
+
+
+def _single_contraction(a, scale=None, compute_dtype=None):
+    """``get_cov``'s self-covariance as it was before the blocked
+    route: one contraction, symmetrized (the oracle of the same
+    arithmetic, whatever the shape)."""
+    precision = None
+    if compute_dtype is not None:
+        a = a.astype(compute_dtype)
+        if jnp.dtype(compute_dtype) == jnp.float32:
+            precision = jax.lax.Precision.HIGHEST
+    cov = jnp.matmul(a.T, a, preferred_element_type=jnp.float32,
+                     precision=precision)
+    return (cov + cov.T) * (0.5 / (a.shape[0] if scale is None else scale))
+
+
+class TestBlockedCov:
+    """``get_cov``'s self-covariance contracts only the upper block
+    triangle where the static ``(rows, d)`` makes the contraction
+    compute-bound (``factors.cov_block_side``), and is the program it
+    was everywhere else."""
+
+    # (rows, d, column blocks a side; None below the gate)
+    SHAPES = [(3072, 3072, 3), (3100, 3072, 3), (4096, 4096, 4),
+              (3584, 3584, None), (4608, 4608, 4),
+              (1024, 768, None), (3071, 3072, None), (64, 4096, None),
+              (4096, 3071, None), (2048, 2048, None), (3400, 3328, None)]
+    IDS = [f'{r}x{d}-{"blocked" if k else "full"}' for r, d, k in SHAPES]
+
+    @pytest.mark.parametrize('rows,d,k', SHAPES, ids=IDS)
+    def test_the_gate_is_a_function_of_the_shape(self, rows, d, k):
+        assert factors.cov_block_side(rows, d) == k
+        if k is not None:
+            assert k in range(factors.COV_BLOCK_MIN_SIDE,
+                              factors.COV_BLOCK_MAX_SIDE + 1)
+            assert d % (k * factors.COV_BLOCK_ALIGN) == 0
+            assert d // k >= factors.COV_BLOCK_MIN_WIDTH
+            # ... and no larger k within the limit would do.
+            assert all(d % (m * factors.COV_BLOCK_ALIGN) or
+                       d // m < factors.COV_BLOCK_MIN_WIDTH
+                       for m in range(k + 1,
+                                      factors.COV_BLOCK_MAX_SIDE + 1))
+
+    @pytest.mark.parametrize('d,k', [
+        (3072, 3), (6144, 4), (2048, None), (1536, None), (768, None),
+        (769, None)])
+    def test_the_cells_dims_at_8192_rows(self, d, k):
+        assert factors.cov_block_side(8192, d) == k
+
+    @pytest.mark.parametrize('compute_dtype', [
+        None, jnp.float32, jnp.bfloat16],
+        ids=['default', 'float32', 'bfloat16'])
+    @pytest.mark.parametrize('rows,d,k', SHAPES[:2] + SHAPES[5:6],
+                             ids=IDS[:2] + IDS[5:6])
+    def test_matches_the_single_contraction(self, rows, d, k,
+                                            compute_dtype):
+        a = rand(rows, d, seed=d)
+        got = factors.get_cov(a, compute_dtype=compute_dtype)
+        want = _single_contraction(a, compute_dtype=compute_dtype)
+        assert got.dtype == jnp.float32 and got.shape == (d, d)
+        # Same operands (rounded once to compute_dtype, so bf16's own
+        # rounding is on both sides), float32 accumulation: only the
+        # summation order differs: 1e-6 of float32 sums over <= 3100
+        # rows of O(1) products, relative on the O(1) diagonal.
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=1e-6)
+        np.testing.assert_array_equal(got, got.T)
+        if compute_dtype == jnp.bfloat16:
+            exact = np.asarray(a, np.float64)
+            exact = exact.T @ exact / rows
+            # ... and the bf16 result is within the operands' rounding
+            # (2^-8 relative each) of the float64 one.
+            np.testing.assert_allclose(got, exact, rtol=0, atol=2e-2)
+
+    @pytest.mark.parametrize('rows,d,k', SHAPES, ids=IDS)
+    def test_the_jaxpr_holds_the_upper_triangle_only(self, rows, d, k):
+        a = jax.ShapeDtypeStruct((rows, d), jnp.bfloat16)
+        tracing.clear_trace()
+        jaxpr = jax.make_jaxpr(
+            lambda x: factors.get_cov(x, scale=7.0))(a).jaxpr
+        counters = tracing.counters()
+        found = _count_primitives(jaxpr)
+        if k is None:
+            want = jax.make_jaxpr(
+                lambda x: _single_contraction(x, scale=7.0))(a).jaxpr
+            assert str(jaxpr) == str(want)
+            assert found['dot_general'] == 1
+            assert counters == {'kfac/factors/cov_full': 1}
+            return
+        # One loop over the k(k+1)/2 block pairs with one block-sized
+        # contraction in its body, and nothing else that contracts.
+        loops = [e for e in jaxpr.eqns if e.primitive.name == 'scan']
+        assert len(loops) == 1 and found['dot_general'] == 1
+        assert loops[0].params['length'] == k * (k + 1) // 2
+        body = loops[0].params['jaxpr'].jaxpr
+        dots = [e for e in body.eqns if e.primitive.name == 'dot_general']
+        assert [e.outvars[0].aval.shape for e in dots] == [(d // k, d // k)]
+        assert {v.aval.dtype for v in dots[0].invars} == {
+            jnp.dtype(jnp.bfloat16)}
+        # scale meets block-sized float32 values only: nothing
+        # elementwise ever has the operand's (rows, .) shape.
+        for eqn in body.eqns:
+            if eqn.primitive.name in ('mul', 'div', 'add'):
+                aval = eqn.outvars[0].aval
+                assert aval.shape in ((), (d // k, d // k)), eqn
+        assert counters == {'kfac/factors/cov_blocked': 1}
+
+    def test_strict_float32_keeps_highest_in_every_block(self):
+        a = jax.ShapeDtypeStruct((3072, 3072), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(lambda x: factors.get_cov(
+            x, compute_dtype=jnp.float32))(a).jaxpr
+        (loop,) = [e for e in jaxpr.eqns if e.primitive.name == 'scan']
+        (dot,) = [e for e in loop.params['jaxpr'].jaxpr.eqns
+                  if e.primitive.name == 'dot_general']
+        assert dot.params['preferred_element_type'] == jnp.float32
+        assert dot.invars[0].aval.dtype == jnp.float32
+        assert set(dot.params['precision']) == {jax.lax.Precision.HIGHEST}
+
+    def test_the_two_tensor_form_is_not_blocked(self):
+        a = jax.ShapeDtypeStruct((3072, 3072), jnp.float32)
+        tracing.clear_trace()
+        found = _count_primitives(
+            jax.make_jaxpr(lambda x, y: factors.get_cov(x, y))(a, a).jaxpr)
+        assert found['dot_general'] == 1 and 'scan' not in found
+        assert not tracing.counters()
+
+    @pytest.mark.parametrize('has_bias', [True, False],
+                             ids=['bias', 'no-bias'])
+    def test_linear_factors_through_the_blocked_route(self, has_bias):
+        rows, d = 3080, 3072
+        a = rand(4, rows // 4, d, seed=11)
+        tracing.clear_trace()
+        got_a = factors.linear_a_factor(a, has_bias,
+                                        compute_dtype=jnp.float32)
+        got_g = factors.linear_g_factor(a, compute_dtype=jnp.float32)
+        assert tracing.counters() == {'kfac/factors/cov_blocked': 2}
+        flat = np.asarray(a, np.float64).reshape(rows, d)
+        want_g = flat.T @ flat / rows
+        if has_bias:
+            flat = np.concatenate([flat, np.ones((rows, 1))], axis=1)
+        want_a = flat.T @ flat / rows
+        assert got_a.shape == (d + has_bias,) * 2
+        np.testing.assert_allclose(got_a, want_a, rtol=0, atol=1e-5)
+        np.testing.assert_allclose(got_g, want_g, rtol=0, atol=1e-5)
+        np.testing.assert_array_equal(got_a, got_a.T)
+        np.testing.assert_array_equal(got_g, got_g.T)
 
 
 class TestRunningAvg:
