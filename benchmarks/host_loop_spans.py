@@ -14,11 +14,20 @@ children of the window's ``kfac/host/step`` spans (mean, median and
 largest, in ms a step, the window's first step left out as
 ``readers/span_ms`` does) and every counter. Writes them to
 ``chiprun_out/host_loop/NAME.json`` and to standard error.
+
+With ``--trace 1`` it also writes the traced window's whole operation
+table, which the harness cuts to ten lines (``breakdown.device_ops``):
+``chiprun_out/host_loop/NAME.ops.json.gz``, one row an instruction,
+``[event text (output shape first), scope, events, total ms]``, read
+off the harness's own parse of the trace (``trace_reduce.load``). A
+question such as "how many operations under ``kfac/factors`` write a
+``f32[2048,2048]``" is one pass over it.
 """
 
 from __future__ import annotations
 
 import argparse
+import gzip
 import json
 import os
 import statistics
@@ -48,6 +57,20 @@ def children_ms(spans, steps: int) -> dict:
             for name, v in by_name.items() if v}
 
 
+def op_table(loaded: dict) -> list:
+    """``[[event text, scope, events, total ms], ...]``, heaviest
+    first, over the device planes of ``trace_reduce.load``'s result."""
+    rows: dict[tuple, list] = {}
+    for events in loaded['device'].values():
+        for name, _start, dur_ns, scope in events:
+            row = rows.setdefault((name, scope), [0, 0.0])
+            row[0] += 1
+            row[1] += dur_ns / 1e6
+    return sorted(([name, scope, n, ms]
+                   for (name, scope), (n, ms) in rows.items()),
+                  key=lambda r: -r[3])
+
+
 def main() -> int:
     parser = argparse.ArgumentParser(description=__doc__.split('\n')[0])
     parser.add_argument('--checkout', default=REPO)
@@ -75,6 +98,21 @@ def main() -> int:
     run_args.add_argument('--seconds', type=float, required=True)
     run_args.add_argument('--trace', type=int, default=0)
     cell = run_args.parse_args([a for a in args.run_args if a != '--'])
+    out_dir = os.path.join(REPO, 'chiprun_out', 'host_loop')
+    os.makedirs(out_dir, exist_ok=True)
+    if cell.trace:
+        # The harness reduces the trace and deletes it: take the table
+        # where it hands the parsed events to its own reduction.
+        from kfac_bench import trace_reduce
+        reduce = trace_reduce.reduce
+
+        def reduce_and_keep(loaded, *a, **kw):
+            with gzip.open(os.path.join(
+                    out_dir, f'{args.label}.ops.json.gz'), 'wt') as f:
+                json.dump(op_table(loaded), f)
+            return reduce(loaded, *a, **kw)
+
+        trace_reduce.reduce = reduce_and_keep
     code, result = bench.run_cell(cell.workload, cell.seed, cell.seconds,
                                   bool(cell.trace))
     if result is None:
@@ -89,9 +127,8 @@ def main() -> int:
             'step_children_ms': children_ms(tracing.spans(),
                                             result['attempted']),
             'counters': {k: v for k, v in tracing.counters().items()
-                         if not k.startswith('kfac/state_bytes/')}}
-    out_dir = os.path.join(REPO, 'chiprun_out', 'host_loop')
-    os.makedirs(out_dir, exist_ok=True)
+                         if not k.startswith('kfac/state_bytes/')
+                         or k.endswith('/shared_saved')}}
     with open(os.path.join(out_dir, f'{args.label}.json'), 'w') as f:
         json.dump(read, f, indent=1)
     print(json.dumps({k: read[k] for k in (

@@ -131,6 +131,12 @@ class LayerSpec:
     # register_shared_module intent, kfac/preconditioner.py:404-470 —
     # which it then disabled wholesale, embedding.py:20).
     tied_calls: int = 0
+    # The earlier layer whose A statistic is this layer's too, because
+    # both read the SAME traced value and make the same statistic of it
+    # (:func:`share_a_owners`); None: the layer owns its A. A follower's
+    # A is contracted and inverted once, by its owner; its factor slot
+    # stays its own.
+    a_owner: str | None = None
 
     @property
     def name(self) -> str:
@@ -141,6 +147,37 @@ class LayerSpec:
         """Stacked factor blocks of a ``BLOCK_STACK_KINDS`` layer."""
         return (self.num_experts if self.kind == EXPERTS
                 else self.feature_group_count)
+
+
+def _same_a_statistic(spec: LayerSpec, other: LayerSpec) -> bool:
+    """Do two layers make the same A statistic of one input? Every
+    field but the layer's own place says how ``a`` is read (kind, bias
+    column, calls, conv geometry, expert count, sharing approximation).
+    An embedding's A is a diagonal that a tied decoder adds to: it is
+    never shared."""
+    return spec.kind != EMBEDDING and dataclasses.replace(
+        spec, path=(), a_owner=None) == dataclasses.replace(
+            other, path=(), a_owner=None)
+
+
+def share_a_owners(specs: dict[str, LayerSpec], same_input
+                   ) -> dict[str, LayerSpec]:
+    """``specs`` with each layer's ``a_owner`` set: the first earlier
+    layer that owns its A, for which ``same_input(owner, layer)`` holds
+    and which makes the same statistic of that input; None otherwise.
+    Registration calls it with the identity of the traced inputs;
+    ``KFAC.init`` again once ``kfac_approx`` is resolved a layer (a
+    follower whose approximation differs from its owner's leaves the
+    group, and may own the A of later layers that went with it)."""
+    out: dict[str, LayerSpec] = {}
+    for name, spec in specs.items():
+        owner = next(
+            (n for n, s in out.items()
+             if s.a_owner is None and same_input(n, name)
+             and _same_a_statistic(s, spec)), None)
+        out[name] = (spec if spec.a_owner == owner
+                     else dataclasses.replace(spec, a_owner=owner))
+    return out
 
 
 def _canonical_padding(padding, n_spatial: int):
@@ -343,6 +380,12 @@ class KFACCapture:
         call_counts: dict[tuple[str, ...], int] = {}
         tied_counts: dict[tuple[str, ...], int] = {}
         self._tied_counts = tied_counts
+        # Registration only: what each call of each layer was handed
+        # (the input and, for stacked experts, the group sizes), kept
+        # as the objects themselves so that :meth:`init` can tell which
+        # layers read one traced value.
+        call_inputs: dict[str, list[tuple]] = {}
+        self._call_inputs = call_inputs
 
         def tied_attend(mod, path, args, kwargs, next_fun):
             """Capture an ``Embed.attend`` call site (the output-tied
@@ -408,11 +451,12 @@ class KFACCapture:
             call_counts[path] = idx + 1
             mod.sow(CAPTURE_COL, 'a', self._cast_capture(a_in),
                     init_fn=tuple, reduce_fn=lambda p, x: p + (x,))
+            rows = None
             if isinstance(mod, ExpertsDense):
                 # The rows of each expert (group_sizes): the statistics
                 # are contracted expert by expert over them.
-                mod.sow(CAPTURE_COL, 'rows',
-                        args[1] if len(args) > 1 else kwargs['group_sizes'],
+                rows = args[1] if len(args) > 1 else kwargs['group_sizes']
+                mod.sow(CAPTURE_COL, 'rows', rows,
                         init_fn=tuple, reduce_fn=lambda p, x: p + (x,))
             y = next_fun(*args, **kwargs)
             y = mod.perturb(f'probe{idx}', y, collection=PROBE_COL)
@@ -420,6 +464,8 @@ class KFACCapture:
                 spec = _spec_for_module(mod, path, call_counts[path],
                                         a_in)
                 self._specs['/'.join(path)] = spec
+                call_inputs.setdefault('/'.join(path), []).append(
+                    (a_in, rows))
             return y
 
         return interceptor
@@ -454,6 +500,15 @@ class KFACCapture:
             if name in self._specs:
                 self._specs[name] = dataclasses.replace(
                     self._specs[name], tied_calls=n)
+        # Layers that were handed one traced value, call for call, share
+        # an A. Identity, not equality: a copy or a cast made before the
+        # call is a value of its own.
+        seen, self._call_inputs = self._call_inputs, {}
+        self._specs = share_a_owners(
+            self._specs, lambda owner, name: (
+                len(seen[owner]) == len(seen[name]) and all(
+                    x is y for mine, theirs in zip(seen[owner], seen[name])
+                    for x, y in zip(mine, theirs))))
         self._record_unregistered_params(variables.get('params', {}))
         declined = {n: r for n, r in self._skipped.items()
                     if 'conv' in r.lower() or 'subclass' in r}

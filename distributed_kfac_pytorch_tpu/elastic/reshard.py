@@ -180,15 +180,30 @@ def reshard_state_dict(sd: dict, saved_topo: TopologySpec, dkfac,
         # load_state_dict recomputes the inverses from the replicated
         # factors on the new mesh (the stateless-shard fast path).
         return sd
+
+    def drop_inverses():
+        return {k: v for k, v in sd.items()
+                if k not in ('inv_stacks', 'diag_inv', 'grouped_inv')}
+
     if not _stacks_match_config(sd['inv_stacks'], dkfac):
         # The saved inverse REPRESENTATION does not match the live
         # config (e.g. eigen stacks saved, 'inv' dispatch resumed) —
         # the same cross-config case load_state_dict already degrades
         # on: drop the inverse groups so it rebuilds everything from
         # the (topology-independent) replicated factors.
-        return {k: v for k, v in sd.items()
-                if k not in ('inv_stacks', 'diag_inv', 'grouped_inv')}
+        return drop_inverses()
     assn = saved_assignment(kfac, params, saved_topo)
+    if kfac.a_followers() and any(
+            np.shape(stack)[0] != assn.n_rows * plan.slots_per_row
+            for dim, plan in assn.buckets.items()
+            for stack in sd['inv_stacks'][str(dim)].values()):
+        # A bundle from before layers that read one input shared an A
+        # slot (MIGRATION.md, PR 31) holds a slot a layer, which is not
+        # what today's placement gives the saved topology: nothing to
+        # move slot by slot, so the inverses are rebuilt from the
+        # factors. (Where no layers share, such a mismatch is a corrupt
+        # bundle, and gather_canonical says so.)
+        return drop_inverses()
     canon = gather_canonical(sd['inv_stacks'], assn)
     return {**sd,
             'inv_stacks': repack_canonical(canon, dkfac.assignment)}

@@ -88,7 +88,7 @@ def compute_a_factor(spec: LayerSpec, a_calls: Sequence[jax.Array],
 
 
 def experts_contrib(spec: LayerSpec, entry: dict,
-                    compute_dtype=None) -> dict:
+                    compute_dtype=None, a_of: dict | None = None) -> dict:
     """One batch's contribution of a stacked-expert layer, ``{'A', 'G',
     'rows'}``, from its capture entry (``'a'``, ``'g'`` and ``'rows'``,
     the per-call ``group_sizes``): per expert the input SUMS and ``G_e``
@@ -96,14 +96,25 @@ def experts_contrib(spec: LayerSpec, entry: dict,
     (``ops.factors.experts_*``), each summed over calls. All three are
     linear in the batch, so they average exactly over micro-batches and
     over the mesh; ``ops.factors.experts_running_avg`` divides ``A`` by
-    ``rows`` where the running average is updated."""
+    ``rows`` where the running average is updated.
+
+    ``a_of``: the contribution of the layer that owns this layer's A
+    (``spec.a_owner``: both were handed the same rows and the same
+    group sizes); its ``'A'`` and ``'rows'`` are this layer's, and only
+    ``'G'`` is contracted here."""
     k = spec.rows_per_token
     out = None
     for a, g, n in zip(entry['a'], entry['g'], entry['rows']):
-        cur = {'A': F.experts_a_factor(a, n, k, compute_dtype=compute_dtype),
-               'G': F.experts_g_factor(g, n, k, compute_dtype=compute_dtype),
-               'rows': F.experts_row_share(n, a.shape[0], k)}
+        cur = {}
+        if a_of is None:
+            cur['A'] = F.experts_a_factor(a, n, k,
+                                          compute_dtype=compute_dtype)
+        cur['G'] = F.experts_g_factor(g, n, k, compute_dtype=compute_dtype)
+        if a_of is None:
+            cur['rows'] = F.experts_row_share(n, a.shape[0], k)
         out = cur if out is None else jax.tree.map(jnp.add, out, cur)
+    if a_of is not None:
+        out = {'A': a_of['A'], 'G': out['G'], 'rows': a_of['rows']}
     return out
 
 

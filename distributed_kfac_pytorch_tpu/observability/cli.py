@@ -162,7 +162,10 @@ def emit_layer_meta(sink, kfac) -> None:
           'kfac_approx_setting': (kfac.kfac_approx
                                   if isinstance(kfac.kfac_approx, str)
                                   else dict(kfac.kfac_approx)),
-          'tied_embeddings': bool(kfac.tied_embeddings)})
+          'tied_embeddings': bool(kfac.tied_embeddings),
+          # {layer: the earlier layer whose input, A statistic and A
+          # inverse it shares} (KFAC.a_followers).
+          'shared_a': kfac.a_followers()})
 
 
 def metrics_path(args) -> str:

@@ -248,6 +248,9 @@ class BucketPlan:
     slots_per_col: int          # eigh workload per device for this bucket
     n_cols: int
     # (layer_name, 'A'|'G') -> slot index within the owning row's slice.
+    # A layer that follows another's A (``LayerSpec.a_owner``) has its
+    # ``(name, 'A')`` at its owner's slot: one matrix is stacked and
+    # inverted there, the owner's, and both layers read the result.
     slot: dict[tuple[str, str], int]
 
     @property
@@ -289,6 +292,12 @@ def assign_work(kfac: KFAC, params, n_rows: int, n_cols: int, *,
     and G on different columns when possible (reference
     preconditioner.py:638-645); it defaults to True when each group has
     more than one member.
+
+    Layers that share an A (``KFAC.a_followers``) are placed on one row,
+    as one unit of the balance, and a follower's A is no work item: it
+    takes its owner's slot, so the one inverse is where the gradient of
+    each of them is preconditioned. Where no layers share, units are
+    layers and the placement is what it always was.
     """
     if distribute_layer_factors is None:
         distribute_layer_factors = n_cols > 1
@@ -306,6 +315,8 @@ def assign_work(kfac: KFAC, params, n_rows: int, n_cols: int, *,
         elif spec.kind in BLOCK_STACK_KINDS:
             grouped_layers.append(name)
 
+    follows = kfac.a_followers()
+
     def factor_entries(name):
         """[(key, dim, cost)] for the dense (eigh-requiring) factors.
 
@@ -317,7 +328,7 @@ def assign_work(kfac: KFAC, params, n_rows: int, n_cols: int, *,
             return []
         a_dim, g_dim = shapes[name]
         out = []
-        if name not in diag_layers:
+        if name not in diag_layers and name not in follows:
             out.append(((name, 'A'), a_dim, a_dim ** exp))
         out.append(((name, 'G'), g_dim, g_dim ** exp))
         return out
@@ -326,9 +337,15 @@ def assign_work(kfac: KFAC, params, n_rows: int, n_cols: int, *,
     for n in grouped_layers:
         ng = kfac.specs[n].num_blocks
         a_dim, g_dim = shapes[n]
-        layer_cost[n] = ng * (a_dim ** exp + g_dim ** exp)
-    row_of = dict(zip(names, load_balance(
-        n_rows, [layer_cost[n] for n in names])))
+        layer_cost[n] = ng * (g_dim ** exp
+                              + (0 if n in follows else a_dim ** exp))
+    unit_cost: dict[str, int] = {}
+    for n in names:
+        unit = follows.get(n, n)
+        unit_cost[unit] = unit_cost.get(unit, 0) + layer_cost[n]
+    unit_row = dict(zip(unit_cost, load_balance(
+        n_rows, list(unit_cost.values()))))
+    row_of = {n: unit_row[follows.get(n, n)] for n in names}
 
     # Per row: LPT factors -> columns (or whole layers -> columns when not
     # distributing A/G, reference preconditioner.py:638-645).
@@ -362,6 +379,9 @@ def assign_work(kfac: KFAC, params, n_rows: int, n_cols: int, *,
             for c in range(n_cols):
                 for k, key in enumerate(cell[(r, c, dim)]):
                     slot[key] = c * s + k
+        for n, owner in follows.items():
+            if (owner, 'A') in slot:
+                slot[(n, 'A')] = slot[(owner, 'A')]
         buckets[dim] = BucketPlan(dim=dim, slots_per_col=s, n_cols=n_cols,
                                   slot=slot)
     return WorkAssignment(n_rows=n_rows, n_cols=n_cols, layer_row=row_of,
@@ -530,12 +550,13 @@ class DistributedKFAC:
                           proxy_scale
                           * float(self._factor_dims[name][0])))
         for name in self.assignment.grouped_layers:
-            ng = kfac.specs[name].num_blocks
+            spec = kfac.specs[name]
             a_dim, g_dim = self._factor_dims[name]
             items.append((('grouped', name),
-                          proxy_scale
-                          * (ng * decomposition_cost(a_dim)
-                             + ng * decomposition_cost(g_dim))))
+                          proxy_scale * sum(
+                              spec.num_blocks * decomposition_cost(d)
+                              for d in ((g_dim,) if spec.a_owner
+                                        else (a_dim, g_dim)))))
         if k > len(items):
             raise ValueError(
                 f'inv_pipeline_chunks={k} exceeds the {len(items)} '
@@ -654,7 +675,37 @@ class DistributedKFAC:
                     {k: {n: state[k][n] for n in experts}
                      for k in ('factors', 'grouped_inv')})
                 ['by_group'].values()))
+        inverted, saved = self._inverse_counts(state)
+        tracing.gauge('kfac/inverses/per_firing', inverted)
+        tracing.gauge('kfac/state_bytes/shared_saved', saved)
         return state
+
+    def _inverse_counts(self, state: dict) -> tuple[int, int]:
+        """``(matrices a firing inverts, bytes of inverse state that
+        layers sharing an A do not hold)``, from the placement and the
+        state's shapes: every dense factor with a slot of its own and
+        every block of a stack (an embedding's diagonal A is no
+        matrix); and for each layer that follows another's A
+        (``KFAC.a_followers``) what one more slot of its dim's bucket,
+        or one more ``A_inv`` stack, would hold."""
+        follows = self.kfac.a_followers()
+        inverted = sum(
+            len({(self.assignment.layer_row[name], at)
+                 for (name, _which), at in plan.slot.items()})
+            for plan in self.assignment.buckets.values())
+        saved = 0
+        for name in self.assignment.grouped_layers:
+            blocks = self.kfac.specs[name].num_blocks
+            inverted += blocks * (1 if name in follows else 2)
+        nbytes = lambda x: x.size * x.dtype.itemsize  # noqa: E731
+        for name, owner in follows.items():
+            if name in self.assignment.grouped_layers:
+                saved += nbytes(state['grouped_inv'][owner]['A_inv'])
+            else:
+                stack = state['inv_stacks'][str(self._factor_dims[name][0])]
+                saved += sum(nbytes(x) // x.shape[0]
+                             for x in stack.values())
+        return inverted, saved
 
     def _init_state_values(self, params) -> dict:
         """The values of :meth:`init_state` (``params``: shapes only)."""
@@ -766,19 +817,26 @@ class DistributedKFAC:
         The device-local half of the factor update (reference
         compute_factors, preconditioner.py:566-575), split out so gradient
         accumulation can average contributions over micro-batches before
-        the mesh ``pmean``.
+        the mesh ``pmean``. A layer that follows another's A
+        (``KFAC.a_followers``) is handed its owner's A statistic, as in
+        ``KFAC.factor_contribs``.
         """
         cdt = self.kfac.factor_compute_dtype
         captures = subsample_captures(captures,
                                       self.kfac.factor_batch_fraction)
         out = {}
         for name, spec in self.kfac.specs.items():
+            owner = out.get(spec.a_owner)
+            if owner is not None:
+                tracing.count('kfac/factors/shared_a')
             if spec.kind == EXPERTS:
-                out[name] = L.experts_contrib(spec, captures[name], cdt)
+                out[name] = L.experts_contrib(spec, captures[name], cdt,
+                                              a_of=owner)
                 continue
             contrib = {
-                'A': L.compute_a_factor(spec, captures[name]['a'],
-                                        compute_dtype=cdt),
+                'A': (owner['A'] if owner is not None
+                      else L.compute_a_factor(spec, captures[name]['a'],
+                                              compute_dtype=cdt)),
                 'G': L.compute_g_factor(spec, captures[name]['g'],
                                         compute_dtype=cdt)}
             extras = L.compute_tied_factor_extras(spec, captures[name],
@@ -831,8 +889,13 @@ class DistributedKFAC:
                 return jax.lax.pmean(m, self.data_axes)
 
         new_factors = {}
-        for name in kfac.specs:
-            a_new = factor_pmean(contribs[name]['A'])
+        a_means: dict[str, Any] = {}
+        for name, spec in kfac.specs.items():
+            # One reduction of an A that several layers share: a
+            # follower's contribution is its owner's value.
+            a_new = a_means[name] = (
+                a_means[spec.a_owner] if spec.a_owner is not None
+                else factor_pmean(contribs[name]['A']))
             g_new = g_scale * factor_pmean(contribs[name]['G'])
             if 'A_g2' in contribs[name]:
                 # Tied-embedding attend parts: the vocab-side diagonal
@@ -984,6 +1047,14 @@ class DistributedKFAC:
             new_factors[name] = entry
         return new_factors
 
+    def _owned_slots(self, plan: BucketPlan):
+        """The bucket's ``((layer, side), slot)`` pairs whose factor is
+        the one stacked and inverted there: all but the A of a layer
+        that follows another's (it reads its owner's slot)."""
+        follows = self.kfac.a_followers()
+        return [(key, at) for key, at in plan.slot.items()
+                if not (key[1] == 'A' and key[0] in follows)]
+
     def _build_bucket_stack(self, factors, plan: BucketPlan) -> jax.Array:
         """Replicated ``(n_rows * slots_per_row, dim, dim)`` factor stack.
 
@@ -995,7 +1066,7 @@ class DistributedKFAC:
         """
         S = plan.slots_per_row
         mats: list[Any] = [None] * (self.total_rows * S)
-        for (name, which), slot_idx in plan.slot.items():
+        for (name, which), slot_idx in self._owned_slots(plan):
             g = self.assignment.layer_row[name] * S + slot_idx
             mats[g] = factors[name][which]
         eye = jnp.eye(plan.dim,
@@ -1021,7 +1092,7 @@ class DistributedKFAC:
         S = plan.slots_per_row
         s = plan.slots_per_col
         by_global = {}
-        for (name, which), slot_idx in plan.slot.items():
+        for (name, which), slot_idx in self._owned_slots(plan):
             g = self.assignment.layer_row[name] * S + slot_idx
             by_global[g] = factors[name][which]
         eye = jnp.eye(plan.dim,
@@ -1248,8 +1319,9 @@ class DistributedKFAC:
             name: (prev_grouped[name]
                    if chunk is not None
                    and chunk_plan['grouped'][name] != chunk
-                   else grouped_block_inverses(factors[name], damping,
-                                               kfac.inv_dtype))
+                   else grouped_block_inverses(
+                       factors[name], damping, kfac.inv_dtype,
+                       sides='G' if kfac.specs[name].a_owner else 'AG'))
             for name in self.assignment.grouped_layers}
         return stacks, diag_inv, grouped_inv
 
@@ -1426,7 +1498,12 @@ class DistributedKFAC:
                 # G_inv @ grad @ A_inv broadcasts over the group dim.
                 # Masked to the owning row like every per-layer path so
                 # the delivery psum stays a sum of one contribution.
+                # (A stack that follows another's A reads its owner's,
+                # which its own row holds.)
                 inv = grouped_inv[name]
+                if spec.a_owner is not None:
+                    inv = {**inv,
+                           'A_inv': grouped_inv[spec.a_owner]['A_inv']}
             else:
                 inv = self._layer_inverses(inv_stacks, name)
             # Same four-way per-side dispatch as the single-chip path
@@ -1781,10 +1858,14 @@ class DistributedKFAC:
                     for n in state['inv_stacks'][k])
             for k in state['inv_stacks'])
         if compatible and not self._degenerate_stacks(sd['inv_stacks']):
+            # (A checkpoint from before layers shared an A carries an
+            # ``A_inv`` for every stack: a follower's is dropped.)
+            grouped = sd.get('grouped_inv', state['grouped_inv'])
             state = {**state, 'inv_stacks': sd['inv_stacks'],
                      'diag_inv': sd['diag_inv'],
-                     'grouped_inv': sd.get('grouped_inv',
-                                           state['grouped_inv'])}
+                     'grouped_inv': {
+                         name: {k: grouped[name][k] for k in entry}
+                         for name, entry in state['grouped_inv'].items()}}
         else:
             state = self.recompute_inverses(state, damping=damping)
         return self._commit(state, self.state_pspecs(state))

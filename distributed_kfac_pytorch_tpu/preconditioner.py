@@ -46,9 +46,11 @@ from distributed_kfac_pytorch_tpu.observability import (
     metrics as obs_metrics,
 )
 from distributed_kfac_pytorch_tpu.observability import profiling
+from distributed_kfac_pytorch_tpu.observability import tracing
 from distributed_kfac_pytorch_tpu.capture import (BLOCK_STACK_KINDS,
                                                   EMBEDDING, EXPERTS,
                                                   KFACCapture,
+                                                  share_a_owners,
                                                   subsample_captures)
 from distributed_kfac_pytorch_tpu.ops import factors as F
 from distributed_kfac_pytorch_tpu.ops import linalg
@@ -633,6 +635,8 @@ class KFAC:
         n_layers = (len(self._specs) if self._specs is not None
                     else '<uninitialized>')
         lines.append(f'  registered_layers: {n_layers}')
+        if self._specs is not None:
+            lines.append(f'  layers_sharing_an_a: {len(self.a_followers())}')
         return 'KFAC(\n' + '\n'.join(lines) + '\n)'
 
     # ------------------------------------------------------------------
@@ -703,7 +707,9 @@ class KFAC:
                             ) -> list[tuple[tuple, float]]:
         """Cost-weighted inverse work items for pipelined firing.
 
-        One item per dense factor matrix (``('mat', layer, 'A'|'G')``
+        One item per dense factor matrix that is inverted (``('mat',
+        layer, 'A'|'G')``; a layer that follows another's A has no A
+        item, :meth:`a_followers`)
         — the finest unit the bucketed eigh/inverse paths can regroup:
         within a chunk, same-dim fired matrices still stack into one
         vmapped kernel via ``_size_buckets``, so chunking never changes
@@ -734,7 +740,7 @@ class KFAC:
             if spec.kind in BLOCK_STACK_KINDS:
                 continue
             f = factors[name]
-            if spec.kind != EMBEDDING:
+            if spec.kind != EMBEDDING and spec.a_owner is None:
                 a = int(f['A'].shape[-1])
                 dense_count[a] = dense_count.get(a, 0) + 1
             g = int(f['G'].shape[-1])
@@ -763,15 +769,17 @@ class KFAC:
             if spec.kind in BLOCK_STACK_KINDS:
                 ng = int(f['A'].shape[0])
                 items.append((('grouped', name),
-                              proxy_scale
-                              * (ng * decomposition_cost(a_dim)
-                                 + ng * decomposition_cost(g_dim))))
+                              proxy_scale * sum(
+                                  ng * decomposition_cost(d)
+                                  for d in ((g_dim,) if spec.a_owner
+                                            else (a_dim, g_dim)))))
                 continue
             if spec.kind == EMBEDDING:
                 # Elementwise reciprocal: O(dim), negligible next to any
                 # dense decomposition but still a schedulable item.
                 items.append((('diag', name), proxy_scale * a_dim))
-            else:
+            elif spec.a_owner is None:
+                # (A follower's A is its owner's item.)
                 items.append((('mat', name, 'A'), unit_cost(a_dim)))
             items.append((('mat', name, 'G'), unit_cost(g_dim)))
         return items
@@ -821,9 +829,13 @@ class KFAC:
         # every factor-math consumer reads spec.kfac_approx. The
         # capture object keeps its own unannotated copy (it only needs
         # call/tied counts for pairing).
-        self._specs = self._approx_mod.annotate_specs(specs,
-                                                      self.kfac_approx)
-        specs = self._specs
+        specs = self._approx_mod.annotate_specs(specs, self.kfac_approx)
+        # Which layers share an A was registered under one approximation
+        # for all; a follower whose resolved one differs from its
+        # owner's makes another statistic of the input and leaves.
+        root = {n: s.a_owner or n for n, s in specs.items()}
+        self._specs = specs = share_a_owners(
+            specs, lambda owner, name: root[owner] == root[name])
         if (self.deferred_factor_reduction or self.hierarchical_reduce) \
                 and any(s.kind == EXPERTS for s in specs.values()):
             # An expert's A is a ratio (row sums over row counts) and an
@@ -839,7 +851,9 @@ class KFAC:
                       f'(bias={spec.has_bias}, calls={spec.num_calls}, '
                       f'approx={spec.kfac_approx}'
                       + (f', tied_calls={spec.tied_calls}'
-                         if spec.tied_calls else '') + ')')
+                         if spec.tied_calls else '')
+                      + (f', A of {spec.a_owner}'
+                         if spec.a_owner else '') + ')')
             for name, reason in self.capture.skipped_modules.items():
                 print(f'Skipped {name}: {reason}')
         state = self.init_state(variables['params'])
@@ -851,7 +865,8 @@ class KFAC:
             raise ValueError('call init() first')
         return self._specs
 
-    def approx_summary(self, left_to_sgd: bool = False) -> dict[str, str]:
+    def approx_summary(self, left_to_sgd: bool = False,
+                       shared_a: bool = False) -> dict[str, str]:
         """{layer name: resolved approx} for run provenance.
 
         The per-layer map the observability meta records (the JSONL
@@ -861,12 +876,44 @@ class KFAC:
         parameterized module K-FAC does not precondition (norm scales,
         ``skip_layers`` matches such as an untied head) as ``'sgd:
         <reason>'``: their gradients reach the optimizer as they are.
+        ``shared_a=True`` labels a layer that reads the input of an
+        earlier one ``<approx>+A of <owner>``: one A statistic and one
+        A inverse serve both (:meth:`a_followers`).
         """
         out = self._approx_mod.approx_summary(self.specs)
+        if shared_a:
+            for name, owner in self.a_followers().items():
+                out[name] += f'+A of {owner}'
         if left_to_sgd:
             out.update({name: f'sgd: {reason}' for name, reason
                         in self.capture.skipped_modules.items()})
         return out
+
+    def a_followers(self) -> dict[str, str]:
+        """``{layer: the layer that owns its A}`` for the layers that
+        read the very input an earlier layer reads
+        (``LayerSpec.a_owner``, found at registration): their A
+        statistic is contracted once and inverted once, by the owner,
+        and their inverse state holds no A side. Empty where no two
+        layers read one input."""
+        return {n: s.a_owner for n, s in self.specs.items()
+                if s.a_owner is not None}
+
+    def _a_side_baked(self, sides: dict) -> dict[str, bool]:
+        """Per A owner: does its eigen-family A side also carry the
+        dense inverse baked at firing time? It does where the owner or
+        a layer that follows it is *mixed* (its G side is a baked
+        inverse), as a layer on its own does for itself. ``sides``:
+        ``{layer: (A method, G method)}``."""
+        baked: dict[str, bool] = {}
+        for name, spec in self.specs.items():
+            ma, mg = sides[name]
+            if spec.kind == EMBEDDING or ma is None:
+                continue
+            owner = spec.a_owner or name
+            baked[owner] = baked.get(owner, False) or (
+                eigen_family(ma) != eigen_family(mg))
+        return baked
 
     def init_state(self, params) -> dict:
         """Fresh K-FAC state pytree for the registered layers.
@@ -882,11 +929,18 @@ class KFAC:
         is computed at step 0 before first use (0 % freq == 0).
         """
         factors, inverses = {}, {}
+        dims = {name: L.factor_shapes(spec, _get(params, spec.path))
+                for name, spec in self.specs.items()}
+        sides = {name: self._side_methods(spec, *dims[name])
+                 for name, spec in self.specs.items()}
+        a_baked = self._a_side_baked(sides)
         for name, spec in self.specs.items():
-            a_dim, g_dim = L.factor_shapes(spec, _get(params, spec.path))
+            a_dim, g_dim = dims[name]
             fdt = self.factor_dtype or jnp.float32
             idt = self.inv_dtype
-            ma, mg = self._side_methods(spec, a_dim, g_dim)
+            ma, mg = sides[name]
+            # A follower's A is inverted by its owner and read there.
+            follows = spec.a_owner is not None
             for which, m, dim in (('A', ma, a_dim), ('G', mg, g_dim)):
                 if m == 'lowrank' and self.inv_lowrank_rank >= dim:
                     # Fail closed: a rank at or above the engaged dim
@@ -925,8 +979,10 @@ class KFAC:
                     'G': jnp.broadcast_to(jnp.eye(g_dim, dtype=fdt),
                                           (ng, g_dim, g_dim))}
                 inverses[name] = {
-                    'A_inv': jnp.zeros((ng, a_dim, a_dim), idt),
                     'G_inv': jnp.zeros((ng, g_dim, g_dim), idt)}
+                if not follows:
+                    inverses[name]['A_inv'] = jnp.zeros(
+                        (ng, a_dim, a_dim), idt)
                 continue
             if spec.kind == EMBEDDING:
                 factors[name] = {'A': jnp.ones((a_dim,), fdt),
@@ -935,11 +991,10 @@ class KFAC:
             else:
                 factors[name] = {'A': jnp.eye(a_dim, dtype=fdt),
                                  'G': jnp.eye(g_dim, dtype=fdt)}
-                if eigen_family(ma):
+                if not follows and eigen_family(ma):
                     entry['QA'], entry['dA'] = eigen_seed(a_dim, ma)
-                    if mixed:
-                        entry['A_inv'] = jnp.zeros((a_dim, a_dim), idt)
-                else:
+                if not follows and (a_baked[name]
+                                    or not eigen_family(ma)):
                     entry['A_inv'] = jnp.zeros((a_dim, a_dim), idt)
             if eigen_family(mg):
                 entry['QG'], entry['dG'] = eigen_seed(g_dim, mg)
@@ -1030,17 +1085,24 @@ class KFAC:
         (single-chip captures are global, so no world rescale — cf.
         the SPMD path's g_scale). Shared by the eager EWMA path and the
         deferred-reduction accumulator so the contribution math cannot
-        drift between them.
+        drift between them. A layer that follows another's A
+        (:meth:`a_followers`) is handed its owner's A statistic: one
+        contraction of the input both read.
         """
         cdt = self.factor_compute_dtype
         captures = subsample_captures(captures, self.factor_batch_fraction)
         out = {}
         for name, spec in self.specs.items():
+            owner = out.get(spec.a_owner)
+            if owner is not None:
+                tracing.count('kfac/factors/shared_a')
             if spec.kind == EXPERTS:
-                out[name] = L.experts_contrib(spec, captures[name], cdt)
+                out[name] = L.experts_contrib(spec, captures[name], cdt,
+                                              a_of=owner)
                 continue
-            a_new = L.compute_a_factor(spec, captures[name]['a'],
-                                       compute_dtype=cdt)
+            a_new = (owner['A'] if owner is not None
+                     else L.compute_a_factor(spec, captures[name]['a'],
+                                             compute_dtype=cdt))
             g_new = L.compute_g_factor(spec, captures[name]['g'],
                                        compute_dtype=cdt)
             extras = L.compute_tied_factor_extras(spec, captures[name],
@@ -1239,7 +1301,8 @@ class KFAC:
             if spec.kind in BLOCK_STACK_KINDS:
                 continue
             for which, m in (('A', ma), ('G', mg)):
-                if m is None:
+                if m is None or (which == 'A' and spec.a_owner):
+                    # (A follower's A is inverted once, as its owner's.)
                     continue
                 if not fires(('mat', name, which)):
                     continue
@@ -1289,12 +1352,15 @@ class KFAC:
             for _j, mats in sorted(by_chunk(inv_mats).items()):
                 invs.update(self._bucketed_inverse(mats, damping))
 
+        a_baked = self._a_side_baked(sides)
         new_inv = {}
         for name, spec in self.specs.items():
             old = state['inverses'][name]
+            follows = spec.a_owner is not None
             if spec.kind in BLOCK_STACK_KINDS:
                 new_inv[name] = (grouped_block_inverses(
-                    state['factors'][name], damping, self.inv_dtype)
+                    state['factors'][name], damping, self.inv_dtype,
+                    sides='G' if follows else 'AG')
                     if fires(('grouped', name)) else old)
                 continue
             ma, mg = sides[name]
@@ -1320,12 +1386,15 @@ class KFAC:
                     entry['A_inv'] = linalg.get_elementwise_inverse(
                         state['factors'][name]['A'].astype(jnp.float32),
                         damping=damping).astype(self.inv_dtype)
+            elif follows:
+                pass  # the A side lives in the owner's entry
             elif eigen_family(ma):
                 if fires(('mat', name, 'A')):
                     qa, da = eigs[f'{name}/A']
                     entry['QA'] = qa.astype(self.inv_dtype)
                     entry['dA'] = da.astype(self.inv_dtype)
-                    if mixed:
+                    if a_baked[name]:
+                        # (also where only a follower is mixed)
                         entry['A_inv'] = linalg.eigen_side_inverse(
                             qa, da, damping).astype(self.inv_dtype)
             elif fires(('mat', name, 'A')):
@@ -1399,9 +1468,13 @@ class KFAC:
                 continue  # dense layer: computed by a shape bucket
             spec = self.specs[name]
             inv = state['inverses'][name]
+            if spec.a_owner is not None:
+                inv = {**inv, 'A_inv':
+                       state['inverses'][spec.a_owner]['A_inv']}
             # Per-layer path for the non-dense kinds: embedding A is the
             # diagonal elementwise inverse; grouped convs broadcast the
-            # batched G_inv @ grad @ A_inv over their block stacks.
+            # batched G_inv @ grad @ A_inv over their block stacks (a
+            # stack that follows another's A reads the owner's).
             # Same dispatch as the SPMD preconditioner:
             # linalg.precondition_dispatch.
             precond_mats[name] = linalg.precondition_dispatch(
@@ -1466,6 +1539,8 @@ class KFAC:
         layers carry baked inverses for both sides). Embedding
         (diagonal A) and grouped-conv (block-stack) layers are not
         dense (g, a) matmuls and stay on the caller's per-layer path.
+        A layer that follows another's A reads the A-side operands in
+        its owner's entry (its own holds none).
         """
         cdt = self.precond_compute_dtype
         groups: dict[tuple[int, ...], list[str]] = {}
@@ -1475,13 +1550,15 @@ class KFAC:
             groups.setdefault(tuple(grad_mats[name].shape),
                               []).append(name)
         mats: dict = {}
-        for members in groups.values():
+        for (g_dim, a_dim), members in groups.items():
             gstack = jnp.stack([grad_mats[n] for n in members])
-            e0 = inverses[members[0]]
-            keys = (('A_inv', 'G_inv') if 'A_inv' in e0 or 'G_inv' in e0
-                    else ('QA', 'dA', 'QG', 'dG'))
-            entry = {k: jnp.stack([inverses[n][k] for n in members])
-                     for k in keys}
+            both_eigen = (eigen_family(self.method_for_dim(a_dim))
+                          and eigen_family(self.method_for_dim(g_dim)))
+            keys = (('QA', 'dA', 'QG', 'dG') if both_eigen
+                    else ('A_inv', 'G_inv'))
+            entry = {k: jnp.stack([
+                inverses[(self.specs[n].a_owner or n) if k in A_SIDE_KEYS
+                         else n][k] for n in members]) for k in keys}
             vs = jax.vmap(
                 lambda gm, e: linalg.precondition_dispatch(
                     gm, e, damping, compute_dtype=cdt))(gstack, entry)
@@ -1750,15 +1827,22 @@ class KFAC:
         # (d, d) basis shares the QA/dA key names with a truncated
         # (d, r) one — splicing it into a low-rank config (or vice
         # versa) would hand the wrong-shape operand to every firing.
+        # A checkpoint from before layers shared an A still carries an
+        # A side for every layer: a follower's is dropped (its owner's
+        # inverts the same matrix), and the rest must match as ever.
         import numpy as np
+        follows = self.a_followers()
+        saved = {n: {k: v for k, v in entry.items()
+                     if not (n in follows and k in A_SIDE_KEYS)}
+                 for n, entry in sd.get('inverses', {}).items()}
         compatible = 'inverses' in sd and all(
-            set(sd['inverses'].get(n, ())) == set(state['inverses'][n])
-            and all(tuple(np.shape(sd['inverses'][n][k]))
+            set(saved.get(n, ())) == set(state['inverses'][n])
+            and all(tuple(np.shape(saved[n][k]))
                     == tuple(np.shape(state['inverses'][n][k]))
                     for k in state['inverses'][n])
             for n in state['inverses'])
-        if compatible and not _degenerate_bases(sd['inverses']):
-            state = {**state, 'inverses': sd['inverses']}
+        if compatible and not _degenerate_bases(saved):
+            state = {**state, 'inverses': saved}
         elif compute_inverses:
             # warm=False: the fresh state's identity bases are not a
             # valid warm start for arbitrary checkpointed factors — use
@@ -1767,6 +1851,11 @@ class KFAC:
                      'inverses': self.update_inverses(state, self.damping,
                                                       warm=False)}
         return state
+
+
+#: The entries of a layer's inverse state that belong to its A factor:
+#: a layer that follows another's A holds none and reads its owner's.
+A_SIDE_KEYS = ('A_inv', 'QA', 'dA')
 
 
 def _overlay_overlap_state(state: dict, sd: dict) -> dict:
@@ -1835,7 +1924,8 @@ def guard_nonfinite_factors(new_factors: dict, old_factors: dict,
     return new_factors, finite.astype(jnp.int32)
 
 
-def grouped_block_inverses(factors: dict, damping, inv_dtype) -> dict:
+def grouped_block_inverses(factors: dict, damping, inv_dtype,
+                           sides: str = 'AG') -> dict:
     """Per-block damped inverses for a grouped-conv or stacked-expert
     layer (``capture.BLOCK_STACK_KINDS``).
 
@@ -1845,14 +1935,14 @@ def grouped_block_inverses(factors: dict, damping, inv_dtype) -> dict:
     2048-dim experts are, runs sub-stack by sub-stack under
     ``lax.map``). A depthwise group's blocks are tiny — ``kh*kw+1`` —
     so eigen warm-start bookkeeping would cost more than it saves.
-    Single point of truth for the single-chip and SPMD inverse updates.
+    ``sides``: ``'G'`` for a layer that follows another's A (the owner
+    inverts it). Single point of truth for the single-chip and SPMD
+    inverse updates.
     """
-    return {'A_inv': linalg.damped_inverse_stack(
-                factors['A'].astype(jnp.float32), damping,
-                'cholesky').astype(inv_dtype),
-            'G_inv': linalg.damped_inverse_stack(
-                factors['G'].astype(jnp.float32), damping,
-                'cholesky').astype(inv_dtype)}
+    return {f'{side}_inv': linalg.damped_inverse_stack(
+                factors[side].astype(jnp.float32), damping,
+                'cholesky').astype(inv_dtype)
+            for side in sides}
 
 
 def measured_unit_scale(measured: dict, dim_counts: dict[int, int],

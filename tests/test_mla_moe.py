@@ -107,6 +107,9 @@ def test_approx_summary_names_what_is_left_to_sgd(toy):
     assert summary['head'] == 'sgd: skip_layers match'
     assert summary['layer0/input_layernorm'].startswith('sgd: ')
     assert summary['layer1/mlp/experts/up_proj'] == 'expand'
+    assert kfac.approx_summary(shared_a=True)[
+        'layer1/mlp/experts/up_proj'] == (
+            'expand+A of layer1/mlp/experts/gate_proj')
     assert set(kfac.approx_summary().values()) == {'expand'}
     kinds = {spec.kind for spec in kfac.specs.values()}
     assert kinds == {'embedding', 'linear', EXPERTS}
@@ -290,6 +293,10 @@ def test_an_expert_without_a_token_keeps_its_factors_and_inverts():
     for name in ('gate_proj', 'up_proj', 'down_proj'):
         layer = f'layer1/mlp/experts/{name}'
         f, inv = state['factors'][layer], state['inverses'][layer]
+        # up_proj reads gate_proj's rows: its A inverse is gate_proj's.
+        inv = {**inv, 'A_inv': state['inverses'][
+            kfac.specs[layer].a_owner or layer]['A_inv']}
+        assert ('A_inv' in state['inverses'][layer]) == (name != 'up_proj')
         for side in ('A', 'G'):
             d = f[side].shape[-1]
             np.testing.assert_array_equal(f[side][0], np.eye(d))
