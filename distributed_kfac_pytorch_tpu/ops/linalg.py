@@ -18,7 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from distributed_kfac_pytorch_tpu.observability import profiling
+from distributed_kfac_pytorch_tpu.observability import profiling, tracing
 
 
 def decomposition_cost(dim: int, count: int = 1,
@@ -496,22 +496,122 @@ def batched_lowrank_eigh(stack: jax.Array, rank: int,
         return qs, ds
 
 
+# The halved route of the damped Cholesky inverse (get_inverse), a
+# function of the static dim alone. A dim of INVERSE_HALVE_MIN_DIM or
+# more is split in two at a multiple of INVERSE_HALVE_ALIGN (the lane
+# width: 3073 -> 1536 + 1537, 6144 -> 3072 + 3072), and each half
+# again, down to blocks of INVERSE_HALVE_LEAF or less, which XLA's own
+# cholesky and triangular solve take (2048 -> 2 x 1024; 3072 and 6144
+# -> leaves of 768; 1536 -> 2 x 768). Set by the function alone on one
+# v5e (benchmarks/inverse_forms.py; PERF.md section 6, PR 32), ms a
+# matrix, whole -> halved at leaf 512 / 1024 / 2048: 1536 dims 0.85 ->
+# 0.66 / 0.72; 2048: 1.63 -> 1.19 / 1.27; 3072: 3.99 -> 2.77 / 2.81 /
+# 3.08; 3073: 4.49 -> 2.85 / 2.85 / 3.22; 6144: 25.2 -> 15.9 / 15.4 /
+# 15.5; 768 and 769 gain nothing (0.27 -> 0.25, 0.29 -> 0.27 at best)
+# and stay whole. Leaf 1024 and not 512, which is 6 % faster at 2048:
+# every product of the recursion is a kernel of its own in the loaded
+# program, which is HBM (46 against 53 MB a (8, 2048, 2048) call site,
+# 34 whole), and the cells' peak_hbm_gib has 1 % of room.
+INVERSE_HALVE_MIN_DIM = 1536
+INVERSE_HALVE_LEAF = 1024
+INVERSE_HALVE_ALIGN = 128
+
+
+def inverse_is_halved(n: int) -> bool:
+    """Whether a damped Cholesky inverse of dim ``n`` takes the halved
+    route (see the ``INVERSE_HALVE_*`` constants)."""
+    return n >= INVERSE_HALVE_MIN_DIM
+
+
+def _halving_point(n: int) -> int:
+    """Where a dim ``n`` over the leaf is split: the multiple of
+    ``INVERSE_HALVE_ALIGN`` at or under its middle."""
+    return n // 2 // INVERSE_HALVE_ALIGN * INVERSE_HALVE_ALIGN
+
+
+def _whole_inverse(x: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``(L^-1, x^-1)`` for ``x = L L^T``: XLA's Cholesky, a triangular
+    solve against the identity, and ``X^T X``."""
+    chol = jnp.linalg.cholesky(x)
+    eye = jnp.eye(x.shape[-1], dtype=x.dtype)
+    inv_l = jax.scipy.linalg.solve_triangular(chol, eye, lower=True)
+    return inv_l, inv_l.T @ inv_l
+
+
+def _halved_inverse(x: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``(L^-1, x^-1)`` for ``x = L L^T`` by recursive halving.
+
+    With ``x = [[A11, A21^T], [A21, A22]]`` and ``X11 = L11^-1`` from
+    the first half: ``L21 = A21 X11^T``, the Schur complement
+    ``S = A22 - L21 L21^T`` is ``L22 L22^T``, ``X22 = L22^-1`` from
+    the second half, and ``X21 = -X22 (L21 X11)``: the factorization a
+    blocked Cholesky and a blocked forward substitution compute, at the
+    same ``4/3 d^3`` flops, every product a square one of half the dim
+    at ``Precision.HIGHEST`` (what XLA's own expanders use for theirs).
+    The inverse ``X^T X`` is put together from the halves' own
+    (``X11^T X11`` and ``X22^T X22`` come back from the recursion), so
+    the zero block of a triangular ``X`` is never multiplied: a third
+    of the whole product's flops, at the precision it always had (the
+    default: ROADMAP D10). A half that is not positive definite comes
+    back NaN from its leaf's ``cholesky`` and every later product
+    carries it into all four blocks. (Nothing reads the outermost
+    call's ``L^-1``; under ``jit`` its assembly is dead code.)
+    """
+    n = x.shape[-1]
+    if n <= INVERSE_HALVE_LEAF:
+        return _whole_inverse(x)
+    mm = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+    h = _halving_point(n)
+    x11, inv11 = _halved_inverse(x[:h, :h])
+    l21 = mm(x[h:, :h], x11.T)
+    x22, inv22 = _halved_inverse(x[h:, h:] - mm(l21, l21.T))
+    x21 = -mm(x22, mm(l21, x11))
+    low = x22.T @ x21
+    inv_l = jnp.block([[x11, jnp.zeros((h, n - h), x.dtype)], [x21, x22]])
+    return inv_l, jnp.block([[inv11 + x21.T @ x21, low.T], [low, inv22]])
+
+
 @profiling.scope('kfac/inverse/cholesky')
 def get_inverse(x: jax.Array, damping: float | jax.Array | None = None
                 ) -> jax.Array:
     """Damped SPD inverse via Cholesky: ``(x + damping*I)^-1`` in fp32.
 
-    Implemented as a Cholesky factorization followed by two triangular
-    solves against the identity — the XLA analogue of torch's
-    ``cholesky_inverse(cholesky(x))`` (kfac/layers/utils.py:76-96).
+    ``L^-T L^-1`` with ``L`` the Cholesky factor — the XLA analogue of
+    torch's ``cholesky_inverse(cholesky(x))``
+    (kfac/layers/utils.py:76-96) — by one of two routes, chosen from
+    the static dim (``inverse_is_halved``):
+
+      - under ``INVERSE_HALVE_MIN_DIM``: XLA's ``cholesky``, a
+        triangular solve against the identity, and ``X^T X``. On a TPU
+        the first two walk the matrix in 128-wide strips (a custom call
+        on each diagonal block and one panel product a strip, the solve
+        against a *dense* identity), re-reading it strip by strip: for
+        six 3073-dim matrices the compiler counts 12.7 GB accessed and
+        3.0 GB of temporaries, and the chip takes 4.5 ms a matrix
+        (1.6 at 2048 dims, 25 at 6144) where the flops need 1.2.
+      - from it up: the same factorization by recursive halving
+        (:func:`_halved_inverse`): square ``Precision.HIGHEST``
+        products of half the dim in place of the strips, XLA's pair
+        only on leaves of at most ``INVERSE_HALVE_LEAF``, and ``X^T X``
+        assembled from the halves' own. 2.9 ms a 3073-dim matrix, 1.3
+        at 2048, 15.4 at 6144 (one v5e, the function alone:
+        ``benchmarks/inverse_forms.py``; PERF.md section 6, PR 32), to
+        the same 2e-6 relative distance from the whole route's result
+        as float32 summation order gives on the CPU.
+
+    Same operator and precisions on both: float32 throughout, the
+    final product at the default matmul precision (ROADMAP D10), a
+    matrix that is not positive definite comes back non-finite. One
+    matrix a call: ``damped_inverse_stack`` vmaps it and counts the
+    matrices by route.
     """
     x = x.astype(jnp.float32)
     if damping is not None:
         x = x + damping * jnp.eye(x.shape[-1], dtype=x.dtype)
-    chol = jnp.linalg.cholesky(x)
-    eye = jnp.eye(x.shape[-1], dtype=x.dtype)
-    inv_l = jax.scipy.linalg.solve_triangular(chol, eye, lower=True)
-    return inv_l.T @ inv_l
+    route = (_halved_inverse if inverse_is_halved(x.shape[-1])
+             else _whole_inverse)
+    return route(x)[1]
 
 
 @profiling.scope('kfac/inverse/newton')
@@ -597,6 +697,11 @@ def damped_inverse_stack(stack: jax.Array, damping, method: str,
     firing program's cold build -54 s; kanana's 25 x 2048 as 2 x 13
     against 16 and 9 inline: 44.6 against 38.1 ms a firing, build
     -27 s.
+
+    A Cholesky stack's matrices are counted, once a traced call, on
+    the recorder (``observability.tracing``) by the route their dim
+    takes in :func:`get_inverse`: ``kfac/inverse/halved`` or
+    ``kfac/inverse/whole`` (the padding is not counted).
     """
     def solve(sub):
         if method == 'newton':
@@ -608,6 +713,9 @@ def damped_inverse_stack(stack: jax.Array, damping, method: str,
         return inv if out_dtype is None else inv.astype(out_dtype)
 
     b, n, _ = stack.shape
+    if method != 'newton':
+        tracing.count('kfac/inverse/halved' if inverse_is_halved(n)
+                      else 'kfac/inverse/whole', b)
     per_chunk = max(1, INVERSE_SUBSTACK_BYTES // (n * n * 4))
     if b <= per_chunk:
         return solve(stack)

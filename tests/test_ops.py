@@ -461,6 +461,195 @@ class TestDampedInverseStack:
             assert sizes == in_loop
 
 
+def _whole_route(x, damping):
+    """``get_inverse`` as it was before the halved route (PR 32): the
+    oracle of the leaf's arithmetic and of the jaxpr a dim under the
+    gate must still trace."""
+    x = x.astype(jnp.float32)
+    if damping is not None:
+        x = x + damping * jnp.eye(x.shape[-1], dtype=x.dtype)
+    chol = jnp.linalg.cholesky(x)
+    eye = jnp.eye(x.shape[-1], dtype=x.dtype)
+    inv_l = jax.scipy.linalg.solve_triangular(chol, eye, lower=True)
+    return inv_l.T @ inv_l
+
+
+def _graded(n, seed, low=-4.0):
+    """A float64 SPD matrix with eigenvalues graded from 1 down to
+    ``10**low`` in a random basis: damping 0.003 then sets the
+    condition, as it does for a K-FAC factor."""
+    rng = np.random.default_rng(seed)
+    q, _ = np.linalg.qr(rng.standard_normal((n, n)))
+    return (q * np.logspace(0.0, low, n)) @ q.T
+
+
+def _fro_gap(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.linalg.norm(got - want) / np.linalg.norm(want))
+
+
+class TestHalvedInverse:
+    """``linalg.get_inverse`` above ``INVERSE_HALVE_MIN_DIM``: the
+    Cholesky factor's inverse by recursive halving. The toy cases lower
+    the gate, the leaf and the split's alignment together (gate 32,
+    leaf 16, splits at multiples of 8), so 49 = 6 * 8 + 1 splits as
+    3073 = 24 * 128 + 1 does: 24 + 25, and the 25 again as 8 + 17."""
+
+    DAMPING = 0.003
+
+    @pytest.fixture
+    def lowered(self, monkeypatch):
+        monkeypatch.setattr(linalg, 'INVERSE_HALVE_MIN_DIM', 32)
+        monkeypatch.setattr(linalg, 'INVERSE_HALVE_LEAF', 16)
+        monkeypatch.setattr(linalg, 'INVERSE_HALVE_ALIGN', 8)
+
+    def test_the_constants_are_consistent(self):
+        assert linalg.INVERSE_HALVE_ALIGN == 128
+        assert linalg.INVERSE_HALVE_LEAF % linalg.INVERSE_HALVE_ALIGN == 0
+        # Every dim the gate lets through is split at least once, at a
+        # lane-aligned point that leaves two non-empty halves.
+        assert (linalg.INVERSE_HALVE_MIN_DIM > linalg.INVERSE_HALVE_LEAF
+                >= 2 * linalg.INVERSE_HALVE_ALIGN)
+        assert not linalg.inverse_is_halved(linalg.INVERSE_HALVE_MIN_DIM - 1)
+        assert linalg.inverse_is_halved(linalg.INVERSE_HALVE_MIN_DIM)
+
+    def _check_against_whole_and_float64(self, n):
+        a = _graded(n, seed=n)
+        want = np.linalg.inv(a + self.DAMPING * np.eye(n))
+        x = jnp.asarray(a, jnp.float32)
+        assert linalg.inverse_is_halved(n)
+        halved = jax.jit(
+            lambda m: linalg.get_inverse(m, self.DAMPING))(x)
+        whole = jax.jit(lambda m: _whole_route(m, self.DAMPING))(x)
+        assert halved.dtype == jnp.float32 and halved.shape == (n, n)
+        err_whole = _fro_gap(whole, want)
+        # The tolerance is what the whole route itself reads against
+        # the float64 inverse: the same factorization in another order
+        # of summation may not be worse than twice that, nor farther
+        # from the whole route than both errors together.
+        assert 0 < err_whole < 1e-4
+        assert _fro_gap(halved, want) <= 2 * err_whole
+        assert _fro_gap(halved, whole) <= 3 * err_whole
+        np.testing.assert_allclose(halved, halved.T, rtol=0,
+                                   atol=1e-5 * float(jnp.abs(whole).max()))
+
+    @pytest.mark.parametrize('n', [32, 64, 128, 49, 97, 33],
+                             ids=lambda n: f'dim{n}')
+    def test_agrees_with_the_whole_route_and_float64(self, lowered, n):
+        self._check_against_whole_and_float64(n)
+
+    def test_at_the_real_gate_an_odd_dim(self):
+        """No constant lowered: the first odd dim over the gate (the
+        3073 kind: an aligned half and a half one wider)."""
+        self._check_against_whole_and_float64(
+            linalg.INVERSE_HALVE_MIN_DIM + 1)
+
+    @pytest.mark.parametrize('count,budget', [(5, 8), (7, 3)],
+                             ids=['one-batch', 'sub-stacked'])
+    def test_a_batched_stack_and_its_counter(self, lowered, monkeypatch,
+                                             count, budget):
+        n = 49
+        monkeypatch.setattr(linalg, 'INVERSE_SUBSTACK_BYTES',
+                            budget * n * n * 4)
+        mats = [_graded(n, seed=70 + i) for i in range(count)]
+        stack = jnp.asarray(np.stack(mats), jnp.float32)
+        tracing.clear_trace()
+        got = linalg.damped_inverse_stack(stack, self.DAMPING, 'cholesky')
+        # Matrices, not calls and not the identities a sub-stacked
+        # bucket is padded with.
+        assert tracing.counters() == {'kfac/inverse/halved': count}
+        whole = jax.vmap(lambda m: _whole_route(m, self.DAMPING))(stack)
+        for i, a in enumerate(mats):
+            want = np.linalg.inv(a + self.DAMPING * np.eye(n))
+            err_whole = _fro_gap(whole[i], want)
+            assert _fro_gap(got[i], want) <= 2 * err_whole
+
+    @pytest.mark.parametrize('method,dims,want', [
+        ('cholesky', (8, 31), {'kfac/inverse/whole': 6}),
+        ('cholesky', (31, 32, 49),
+         {'kfac/inverse/whole': 3, 'kfac/inverse/halved': 6}),
+        ('newton', (8, 49), {})],
+        ids=['under-the-gate', 'both-routes', 'newton-counts-nothing'])
+    def test_the_counters_count_matrices_by_route(self, lowered, method,
+                                                  dims, want):
+        tracing.clear_trace()
+        jax.make_jaxpr(lambda *stacks: [
+            linalg.damped_inverse_stack(s, 0.1, method, iters=2)
+            for s in stacks])(*[
+                jax.ShapeDtypeStruct((3, d, d), jnp.float32) for d in dims])
+        assert tracing.counters() == want
+
+    @pytest.mark.parametrize('route', ['halved', 'whole'])
+    @pytest.mark.parametrize('where', ['first-half', 'schur-complement'])
+    def test_not_positive_definite_is_non_finite_on_both_routes(
+            self, lowered, where, route):
+        n = 64
+        a = _graded(n, seed=5) + self.DAMPING * np.eye(n)
+        # One strongly negative direction: inside the leading block, or
+        # on the last coordinate, where only the last Schur complement
+        # (the last leaf's input) is indefinite.
+        at = 3 if where == 'first-half' else n - 1
+        a[at, at] -= 10.0
+        assert np.linalg.eigvalsh(a).min() < -1.0
+        fn = linalg.get_inverse if route == 'halved' else _whole_route
+        got = np.asarray(jax.jit(lambda m: fn(m, None))(
+            jnp.asarray(a, jnp.float32)))
+        assert not np.isfinite(got).any()
+
+    @pytest.mark.parametrize('batched', [False, True],
+                             ids=['matrix', 'vmapped'])
+    def test_under_the_gate_the_jaxpr_is_the_one_it_was(self, batched):
+        n = linalg.INVERSE_HALVE_MIN_DIM - 1
+        assert n > 640      # over every eigen-path dim 'auto' keeps
+        new, old = (lambda m: linalg.get_inverse(m, damping=0.003),
+                    lambda m: _whole_route(m, 0.003))
+        shape = (n, n)
+        if batched:
+            new, old, shape = jax.vmap(new), jax.vmap(old), (3, n, n)
+        spec = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        assert str(jax.make_jaxpr(new)(spec)) == str(
+            jax.make_jaxpr(old)(spec))
+
+    def test_the_halved_jaxpr_holds_leaves_and_square_products(
+            self, lowered):
+        n, leaf = 97, linalg.INVERSE_HALVE_LEAF
+        jaxpr = jax.make_jaxpr(
+            lambda m: linalg.get_inverse(m, 0.003))(
+                jax.ShapeDtypeStruct((n, n), jnp.float32)).jaxpr
+        by_name = {}
+
+        def walk(j):
+            for eqn in j.eqns:
+                by_name.setdefault(eqn.primitive.name, []).append(eqn)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+        walk(jaxpr)
+        # 97 -> 48 + 49 -> (24, 24) + (24, 25) -> (8, 16) ... (8, 17)
+        # -> (8, 9): 9 leaves of 8..16, and XLA's own factorization and
+        # solve only ever see those.
+        leaves = [e.outvars[0].aval.shape[-1] for e in by_name['cholesky']]
+        merges = len(leaves) - 1
+        assert sorted(leaves) == [8] * 5 + [9] + [16] * 3
+        assert sum(leaves) == n and max(leaves) <= leaf
+        assert len(by_name['triangular_solve']) == len(leaves)
+        # Four products a merge at HIGHEST in float32 (where XLA's
+        # expanders ran theirs). At the default precision, as the whole
+        # route's X^T X is (ROADMAP D10): a leaf's own X^T X and two
+        # block products a merge, none of them as large as the matrix:
+        # the zero block of X is never multiplied.
+        dots = by_name['dot_general']
+        pinned = [e for e in dots if e.params['precision'] is not None
+                  and set(e.params['precision']) == {
+                      jax.lax.Precision.HIGHEST}]
+        assert len(pinned) == 4 * merges
+        assert all(e.params['preferred_element_type'] == jnp.float32
+                   for e in pinned)
+        default = [e for e in dots if e not in pinned]
+        assert len(default) == len(leaves) + 2 * merges
+        assert all(e.params['precision'] is None for e in default)
+        assert max(max(e.outvars[0].aval.shape) for e in dots) <= 49
+
+
 class TestFusedPatchCov:
     """Fused im2col+covariance Pallas kernel (interpret mode on CPU):
     must equal ops.factors.conv2d_a_factor exactly in structure — same
