@@ -73,6 +73,7 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 from distributed_kfac_pytorch_tpu import launch, multislice  # noqa: E402
 from distributed_kfac_pytorch_tpu import native  # noqa: E402
 from distributed_kfac_pytorch_tpu.models import (  # noqa: E402
+    looped_lm,
     mla_moe_lm,
     transformer_lm,
 )
@@ -385,10 +386,11 @@ def leg_lm(out_dir: str, *, name: str = 'B', size: str = 'xl',
            grad_worker_fraction: float = 0.25, dtype=jnp.bfloat16,
            bf16_state: bool = True, seed: int = 0,
            arch: str = 'transformer', **model_overrides) -> dict:
-    """``transformer_lm.get_model(size)`` — or, with ``arch='mla_moe'``,
-    ``mla_moe_lm.get_model(size)`` with its untied head left to SGD —
-    through the calls ``examples/train_language_model.py:main`` makes,
-    in its order. (The CLI itself cannot select bf16 compute.)"""
+    """``transformer_lm.get_model(size)`` — or, with ``arch='mla_moe'``
+    or ``'looped'``, ``mla_moe_lm`` / ``looped_lm.get_model(size)`` with
+    the untied head left to SGD (the looped decoder is handed its
+    targets and returns its own objective) — through the calls
+    ``examples/train_language_model.py:main`` makes, in its order. (The CLI itself cannot select bf16 compute.)"""
     report = _new_report(name)
     stream = os.path.join(out_dir, f'{name}.jsonl')
     n_dev = jax.device_count()
@@ -399,6 +401,9 @@ def leg_lm(out_dir: str, *, name: str = 'B', size: str = 'xl',
         if arch == 'mla_moe':
             return mla_moe_lm.get_model(vocab, size, dtype=dtype,
                                         **model_overrides)
+        if arch == 'looped':
+            return looped_lm.get_model(vocab, size, dtype=dtype,
+                                       **model_overrides)
         return transformer_lm.get_model(
             vocab, size, max_len=seq, tie_weights=True, dtype=dtype,
             **model_overrides)
@@ -410,7 +415,7 @@ def leg_lm(out_dir: str, *, name: str = 'B', size: str = 'xl',
         kfac_inv_update_freq=inv_freq, kfac_cov_update_freq=factor_freq,
         damping=0.003, factor_decay=0.95, kl_clip=0.001,
         inverse_method='auto',
-        skip_layers=['head'] if arch == 'mla_moe' else [],
+        skip_layers=['head'] if arch in ('mla_moe', 'looped') else [],
         comm_method=comm_method,
         grad_worker_fraction=grad_worker_fraction,
         bf16_factors=bf16_state, bf16_inverses=bf16_state,
@@ -440,10 +445,13 @@ def leg_lm(out_dir: str, *, name: str = 'B', size: str = 'xl',
     kstate = dkfac.init_state(params)
     opt_state = tx.init(params)
 
-    def loss_fn(out, batch):
+    def eval_loss(out, batch):
         return optax.softmax_cross_entropy_with_integer_labels(
-            out.astype(jnp.float32) if arch == 'mla_moe' else out,
+            out if arch == 'transformer' else out.astype(jnp.float32),
             batch[1]).mean()
+
+    def loss_fn(out, batch):
+        return out.mean() if arch == 'looped' else eval_loss(out, batch)
 
     data_axes = dkfac.data_axes
 
@@ -452,8 +460,11 @@ def leg_lm(out_dir: str, *, name: str = 'B', size: str = 'xl',
         idx = jax.lax.axis_index(data_axes[0])
         for ax in data_axes[1:]:
             idx = idx * jax.lax.psum(1, ax) + jax.lax.axis_index(ax)
-        return {'train': True,
-                'rngs': {'dropout': jax.random.fold_in(batch[2], idx)}}
+        kwargs = {'train': True,
+                  'rngs': {'dropout': jax.random.fold_in(batch[2], idx)}}
+        if arch == 'looped':
+            kwargs['targets'] = batch[1]
+        return kwargs
 
     data_spec = P(multislice.batch_axes(mesh))
     clock = StepClock()
@@ -461,7 +472,7 @@ def leg_lm(out_dir: str, *, name: str = 'B', size: str = 'xl',
         loss_fn, tx, model_kwargs_fn=model_kwargs_fn,
         batch_spec=(data_spec, data_spec, P()), loss_scale=None))
     eval_step = engine.make_eval_step(
-        build_model(), loss_fn, None, model_args_fn=lambda b: (b[0],),
+        build_model(), eval_loss, None, model_args_fn=lambda b: (b[0],),
         model_kwargs={'train': False}, metrics_fn=lambda o, b: {})
 
     state = engine.TrainState(params=params, opt_state=opt_state,

@@ -10,6 +10,10 @@
 - ``mla_moe_lm``: DeepSeek-V3-shaped decoder LM — latent attention
   (MLA), sigmoid-routed stacked experts beside shared ones, SwiGLU,
   RMSNorm, RoPE, an untied head; exercises the ``experts`` layer kind.
+- ``looped_lm``: looped (depth-recurrent) decoder LM — one stack of
+  full-head SwiGLU layers run several times a forward with the same
+  weights, an exit and a gate after every pass; every projection is one
+  K-FAC layer with several calls a step (the multi-call capture path).
 - ``mobilenet``: MobileNetV1 — the depthwise workload the reference
   cannot precondition (no grouped-conv layer kind there); exercises
   this framework's ``conv2d_grouped`` path end to end.
@@ -20,6 +24,7 @@
 
 from distributed_kfac_pytorch_tpu.models import cifar_resnet
 from distributed_kfac_pytorch_tpu.models import imagenet_resnet
+from distributed_kfac_pytorch_tpu.models import looped_lm
 from distributed_kfac_pytorch_tpu.models import lstm_lm
 from distributed_kfac_pytorch_tpu.models import mla_moe_lm
 from distributed_kfac_pytorch_tpu.models import mobilenet

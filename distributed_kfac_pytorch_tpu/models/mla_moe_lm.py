@@ -63,7 +63,9 @@ from distributed_kfac_pytorch_tpu.parallel.sequence import (
 INIT = nn.initializers.normal(0.02)
 
 
-def _dense(features: int, dtype, name: str, **kw) -> nn.Dense:
+def dense(features: int, dtype, name: str, **kw) -> nn.Dense:
+    """A bias-free projection, N(0, 0.02): every matrix of this decoder
+    and of ``models/looped_lm.py``."""
     return nn.Dense(features, use_bias=False, dtype=dtype,
                     kernel_init=INIT, name=name, **kw)
 
@@ -116,13 +118,13 @@ class MLA(nn.Module):
         b, t, d_model = x.shape
         h, nope, rot = (self.heads_held, self.qk_nope_head_dim,
                         self.qk_rope_head_dim)
-        q = _dense(h * (nope + rot), self.dtype, 'q_proj')(x)
+        q = dense(h * (nope + rot), self.dtype, 'q_proj')(x)
         q = q.reshape(b, t, h, nope + rot)
-        kv_a = _dense(self.kv_lora_rank + rot, self.dtype,
-                      'kv_a_proj_with_mqa')(x)
+        kv_a = dense(self.kv_lora_rank + rot, self.dtype,
+                     'kv_a_proj_with_mqa')(x)
         c_kv, k_rope = kv_a[..., :self.kv_lora_rank], kv_a[
             ..., self.kv_lora_rank:]
-        kv = _dense(h * (nope + self.v_head_dim), self.dtype, 'kv_b_proj')(
+        kv = dense(h * (nope + self.v_head_dim), self.dtype, 'kv_b_proj')(
             RMSNorm(dtype=self.dtype, name='kv_a_layernorm')(c_kv))
         kv = kv.reshape(b, t, h, nope + self.v_head_dim)
         k_nope, v = kv[..., :nope], kv[..., nope:]
@@ -134,7 +136,7 @@ class MLA(nn.Module):
         with jax.named_scope('kfac_model/attention'):
             o = local_causal_attention(q, k, v, causal=True)
         o = o.reshape(b, t, h * self.v_head_dim).astype(x.dtype)
-        return _dense(d_model, self.dtype, 'o_proj')(o)
+        return dense(d_model, self.dtype, 'o_proj')(o)
 
 
 class GatedMLP(nn.Module):
@@ -144,9 +146,9 @@ class GatedMLP(nn.Module):
 
     @nn.compact
     def __call__(self, h):
-        gate = _dense(self.width, self.dtype, 'gate_proj')(h)
-        up = _dense(self.width, self.dtype, 'up_proj')(h)
-        return _dense(h.shape[-1], self.dtype, 'down_proj')(
+        gate = dense(self.width, self.dtype, 'gate_proj')(h)
+        up = dense(self.width, self.dtype, 'up_proj')(h)
+        return dense(h.shape[-1], self.dtype, 'down_proj')(
             nn.silu(gate) * up)
 
 
@@ -208,8 +210,8 @@ class MoE(nn.Module):
         tracing.gauge('kfac/moe/experts_total', self.n_routed_experts)
         h = x.reshape(-1, x.shape[-1])
         n, d = h.shape
-        logits = _dense(self.n_routed_experts, jnp.float32, 'router',
-                        precision=jax.lax.Precision.HIGHEST)(
+        logits = dense(self.n_routed_experts, jnp.float32, 'router',
+                       precision=jax.lax.Precision.HIGHEST)(
             h.astype(jnp.float32))
         bias = self.param('e_score_correction_bias',
                           nn.initializers.zeros,
@@ -304,7 +306,7 @@ class MlaMoeLM(nn.Module):
                       self.intermediate_size, moe, mla, dtype=self.dtype,
                       name=f'layer{i}')(x, pos)
         x = RMSNorm(dtype=self.dtype, name='norm')(x)
-        return _dense(self.vocab_size, self.dtype, 'head')(x)
+        return dense(self.vocab_size, self.dtype, 'head')(x)
 
 
 def get_model(vocab_size: int, size: str = 'tiny',

@@ -678,6 +678,12 @@ class DistributedKFAC:
         inverted, saved = self._inverse_counts(state)
         tracing.gauge('kfac/inverses/per_firing', inverted)
         tracing.gauge('kfac/state_bytes/shared_saved', saved)
+        # Capture pairs a step hands the factor stage: one a call of
+        # every registered layer (a looped decoder's matrices are called
+        # once a pass), and the most calls any one layer has.
+        calls = [spec.num_calls for spec in self.kfac.specs.values()]
+        tracing.gauge('kfac/capture/calls', sum(calls))
+        tracing.gauge('kfac/capture/calls_max', max(calls))
         return state
 
     def _inverse_counts(self, state: dict) -> tuple[int, int]:
@@ -1749,6 +1755,20 @@ class DistributedKFAC:
             chunk_phase = (jnp.zeros((), jnp.int32) if inv_update
                            else state['inv_chunk_phase'])
 
+        if factor_update is True and any(
+                spec.num_calls > 1 for spec in kfac.specs.values()):
+            # A layer applied several times a step (a looped decoder's
+            # every matrix) hands the factor stage one capture pair a
+            # CALL. Where no inverse fires, nothing downstream reads the
+            # new factors, and XLA schedules their contractions last:
+            # every call's captures then outlive the precondition and
+            # the optimizer (15.35 GiB against 14.76 at 4 layers x 4
+            # passes, compiled for a v5e's 15.75; PERF.md section 6,
+            # PR 34). Tie the gradients to
+            # the new factors so that the captures are dead before the
+            # precondition starts. A model in which no module is called
+            # twice traces the program it traced before.
+            factors, grads = jax.lax.optimization_barrier((factors, grads))
         if not kfac.collect_metrics:
             precond = self._spmd_precondition(
                 inv_stacks, diag_inv, grouped_inv, grads, damping, lr,

@@ -2,7 +2,7 @@
 
 Working TPU-native counterpart of the reference's WIP LM entry point
 (examples/torch_language_model.py — broken as shipped: SURVEY.md §8 notes
-the lr and factory-unpacking bugs at :253,:277). Two architectures:
+the lr and factory-unpacking bugs at :253,:277). Four architectures:
 
 - ``--arch lstm``: the K-FAC-friendly LSTM LM (reference rnn_utils/lstm.py
   + kfac/modules/lstm.py), BPTT windows (``--bptt 35``,
@@ -20,6 +20,13 @@ the lr and factory-unpacking bugs at :253,:277). Two architectures:
   ``--mla-moe-size``; K-FAC on every projection, the router and every
   expert matrix (layer kind ``experts``), the head left to SGD by
   default.
+- ``--arch looped``: the looped (depth-recurrent) decoder of
+  ``models/looped_lm.py`` (Ouro's: one stack of layers run
+  ``total_ut_steps`` times with the same weights, an exit after every
+  pass, a gate that spreads the loss over the exits) at
+  ``--looped-size``; every projection is one K-FAC layer called once a
+  pass (``num_calls``), the head left to SGD by default. The step hands
+  the model its targets and takes the mean of the objective it returns.
 
 Data: whitespace-tokenized train.txt/valid.txt under --data-dir
 (PTB/WikiText layout), else a synthetic Markov corpus (offline default).
@@ -50,6 +57,7 @@ from distributed_kfac_pytorch_tpu import observability as obs
 from distributed_kfac_pytorch_tpu import resilience as resil
 from distributed_kfac_pytorch_tpu import multislice
 from distributed_kfac_pytorch_tpu.models import (
+    looped_lm,
     lstm_lm,
     mla_moe_lm,
     transformer_lm,
@@ -78,12 +86,17 @@ def parse_args(argv=None):
     p.add_argument('--checkpoint-dir', default='./checkpoints/lm')
     p.add_argument('--checkpoint-freq', type=int, default=5)
     p.add_argument('--arch', default='lstm',
-                   choices=['lstm', 'transformer', 'mla_moe'])
+                   choices=['lstm', 'transformer', 'mla_moe', 'looped'])
     p.add_argument('--mla-moe-size', default='tiny',
                    choices=['tiny', 'kanana2'],
                    help="--arch mla_moe: mla_moe_lm.get_model's named "
                         "shape ('kanana2': kanana-2-30b-a3b's widths at "
                         "one chip's share; needs a 16 GB chip)")
+    p.add_argument('--looped-size', default='tiny',
+                   choices=['tiny', 'ouro_2p6b'],
+                   help="--arch looped: looped_lm.get_model's named shape "
+                        "('ouro_2p6b': Ouro-2.6B as published, 48 layers "
+                        "run 4 times; more than one 16 GB chip holds)")
     # Model size (reference torch_language_model.py:41-50).
     p.add_argument('--emsize', type=int, default=650)
     p.add_argument('--nhid', type=int, default=650)
@@ -249,6 +262,9 @@ def build_model(args, vocab_size, seq_axis=None, dtype=None):
     if args.arch == 'mla_moe':
         return mla_moe_lm.get_model(vocab_size, args.mla_moe_size,
                                     dtype=dtype)
+    if args.arch == 'looped':
+        return looped_lm.get_model(vocab_size, args.looped_size,
+                                   dtype=dtype)
     return transformer_lm.TransformerLM(
         vocab_size=vocab_size, d_model=args.emsize,
         num_layers=args.nlayers, num_heads=args.nheads,
@@ -298,7 +314,8 @@ def main(argv=None):
 
     if args.skip_layers is None:
         args.skip_layers = {'lstm': ['embed', 'decoder'],
-                            'mla_moe': ['head']}.get(args.arch, [])
+                            'mla_moe': ['head'],
+                            'looped': ['head']}.get(args.arch, [])
 
     seq_axis = seq.SEQ_AXIS if sp > 1 else None
     model = build_model(args, vocab_size, seq_axis=seq_axis)
@@ -421,6 +438,10 @@ def main(argv=None):
         return out[0] if args.arch == 'lstm' else out
 
     def loss_fn(out, batch):
+        if args.arch == 'looped':
+            # Handed the targets, the looped decoder returns its own
+            # objective a token (it needs the head at every exit).
+            return out.mean()
         return optax.softmax_cross_entropy_with_integer_labels(
             logits_of(out), batch[1]).mean()
 
@@ -440,6 +461,8 @@ def main(argv=None):
         if seq_axis:
             kwargs['pos_offset'] = (
                 jax.lax.axis_index(seq.SEQ_AXIS) * t_local)
+        if args.arch == 'looped':
+            kwargs['targets'] = batch[1]
         return kwargs
 
     batch_axes = multislice.batch_axes(mesh)

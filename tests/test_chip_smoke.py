@@ -63,6 +63,22 @@ def test_leg_lm_second_decoder_toy(tmp_path):
     assert report['classes_run'] == ['factor', 'firing']
 
 
+def test_leg_lm_looped_decoder_toy(tmp_path):
+    # The looped decoder's two variants (every matrix called once a
+    # pass), its head left to SGD, on the same HYBRID 2x4 mesh. Not a
+    # leg of the chip run: two more programs to compile there.
+    report = _passed(chip_smoke.leg_lm(
+        str(tmp_path), name='L', arch='looped', size='tiny', seq=16,
+        per_chip_batch=1, vocab=64, steps=5, factor_freq=1, inv_freq=2,
+        bf16_state=False, comm_method='hybrid-opt',
+        grad_worker_fraction=0.5))
+    assert report['mesh'] == {'kfac_ig': 2, 'kfac_gw': 4}
+    assert report['trace_counts'] == {'(True, True, None)': 1,
+                                      '(True, False, None)': 1}
+    assert report['programs_built'] == 2
+    assert report['classes_run'] == ['factor', 'firing']
+
+
 def test_leg_kernels_interpret():
     report = _passed(chip_smoke.leg_kernels(
         interpret=True, stack=2, inverse_dims=(32, 17)))
