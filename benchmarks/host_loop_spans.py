@@ -12,7 +12,10 @@ does, and then reads the program's own recorder
 (``observability.tracing``), which the harness prints nothing of: the
 children of the window's ``kfac/host/step`` spans (mean, median and
 largest, in ms a step, the window's first step left out as
-``readers/span_ms`` does) and every counter. Writes them to
+``readers/span_ms`` does), every counter, the ``kfac/build/*`` spans'
+attributes and JAX's backend compiles by the span they came in (PR
+36: one a variant, on a checkout's first run and on a warm one alike).
+Writes them to
 ``chiprun_out/host_loop/NAME.json`` and to standard error.
 
 With ``--trace 1`` it also writes the traced window's whole operation
@@ -35,6 +38,8 @@ import sys
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 STEP = 'kfac/host/step'
+BUILD = 'kfac/build/'
+BACKEND_COMPILE = '/jax/core/compile/backend_compile_duration'
 
 
 def children_ms(spans, steps: int) -> dict:
@@ -92,6 +97,18 @@ def main() -> int:
     if not tracing.__file__.startswith(checkout):
         sys.exit(f'the program came from {tracing.__file__}, not from '
                  f'{checkout}')
+    # Every backend compile of the run (a cache hit is one too), and
+    # the span it came in: a build that compiles a variant twice shows.
+    import jax
+    compiles: dict[str, int] = {}
+
+    def on_duration(event, _seconds, **_kw):
+        if event == BACKEND_COMPILE:
+            span = tracing.current()
+            name = span.name if span else '(none)'
+            compiles[name] = compiles.get(name, 0) + 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
     run_args = argparse.ArgumentParser()
     run_args.add_argument('--workload', required=True)
     run_args.add_argument('--seed', type=int, required=True)
@@ -128,11 +145,15 @@ def main() -> int:
                                             result['attempted']),
             'counters': {k: v for k, v in tracing.counters().items()
                          if not k.startswith('kfac/state_bytes/')
-                         or k.endswith('/shared_saved')}}
+                         or k.endswith(('/shared_saved', '/total'))},
+            'builds': {s.name: s.attrs for s in tracing.spans()
+                       if s.name.startswith(BUILD)},
+            'backend_compiles': compiles}
     with open(os.path.join(out_dir, f'{args.label}.json'), 'w') as f:
         json.dump(read, f, indent=1)
     print(json.dumps({k: read[k] for k in (
-        'label', 'steps', 'step_children_ms', 'counters')}),
+        'label', 'steps', 'step_children_ms', 'counters', 'builds',
+        'backend_compiles')}),
         file=sys.stderr)
     return code
 

@@ -1,6 +1,7 @@
-"""Memory telemetry: device HBM watermarks + K-FAC state footprint.
+"""Memory telemetry: device HBM watermarks, K-FAC state footprint, and
+each step program's own bytes.
 
-Two complementary sources, both host-side and sync-free (r10):
+Three complementary sources, all host-side and sync-free (r10):
 
   - :func:`device_memory_stats` — the live allocator watermarks
     (``bytes_in_use`` / ``peak_bytes_in_use``) from
@@ -17,6 +18,11 @@ Two complementary sources, both host-side and sync-free (r10):
     grad-worker-fraction memory claims auditable from a run's JSONL
     alone — the ``kind='memory'`` records carry both sources
     (``observability.sink.JsonlMetricsSink.memory_record``).
+  - :func:`program_footprint` — a compiled program's arguments,
+    temporaries and code by the compiler's count. The allocator's peak
+    leaves out a running program's temporaries and counts every loaded
+    program's code (PERF.md, PR 34/35); ``build_train_step`` records
+    this for each variant it loads (``kfac/program_bytes/*``).
 
 The engine samples every ``memory_interval`` steps
 (``train_epoch(memory_interval=)``, ``--memory-interval`` in the CLIs);
@@ -144,6 +150,40 @@ def state_footprint(state: Any) -> dict:
             gk = f'{group}/{dt}'
             out['by_group_dtype'][gk] = (
                 out['by_group_dtype'].get(gk, 0) + n)
+    return out
+
+
+# program_footprint keys -> the compiler's CompiledMemoryStats fields.
+PROGRAM_FIELDS = {
+    'argument': 'argument_size_in_bytes',
+    'output': 'output_size_in_bytes',
+    'alias': 'alias_size_in_bytes',
+    'temp': 'temp_size_in_bytes',
+    'code': 'generated_code_size_in_bytes',
+}
+
+
+def program_footprint(compiled) -> dict:
+    """One device's bytes of a compiled program, by the compiler's
+    count (``compiled.memory_analysis()``; ``{}`` where the backend
+    gives none, as :func:`device_memory_stats` degrades).
+
+    Keys: :data:`PROGRAM_FIELDS`'s, ``total`` = argument + output −
+    alias + temp + code (what the device holds while the program runs:
+    the runtime's ``peak_bytes_in_use`` counts its arguments and code
+    but not its temporaries), and ``peak``, the compiler's own
+    ``peak_memory_in_bytes``, where it is given and non-zero.
+    """
+    stats = compiled.memory_analysis()
+    if stats is None:
+        return {}
+    out = {key: int(getattr(stats, field))
+           for key, field in PROGRAM_FIELDS.items()}
+    out['total'] = (out['argument'] + out['output'] - out['alias']
+                    + out['temp'] + out['code'])
+    peak = getattr(stats, 'peak_memory_in_bytes', 0)
+    if peak:
+        out['peak'] = int(peak)
     return out
 
 

@@ -19,7 +19,8 @@ Prints, from the recorded stream alone (no live process needed):
   - memory telemetry (r10): device HBM watermarks (last/peak) and the
     resident K-FAC state footprint by group/dtype;
   - compile/retrace telemetry (r10): per-variant first-call wall time
-    from the step builder's (factor, inv, chunk) variant cache, and
+    from the step builder's (factor, inv, chunk) variant cache, with
+    (PR 36) each program's total, temporaries and code, and
     any retrace events (the offline echo of the ``trace_counts``
     guard);
   - straggler attribution (r10): when per-rank shards
@@ -500,12 +501,22 @@ def print_report(s: dict, out=None, torn: int = 0,
                 w(f'  {gk:<24} '
                   f"{format_bytes(st['by_group_dtype'][gk])}")
     if s.get('compiles') or s.get('retraces'):
+        from distributed_kfac_pytorch_tpu.observability.memory import (
+            format_bytes,
+        )
         w()
         w(f"-- compile/retrace ({len(s['compiles'])} variant "
           'compile(s)) --')
         for ev in s['compiles']:
+            # The program's bytes by the compiler's count, where the
+            # builder recorded them (kfac/program_bytes/*).
+            held = ''.join(
+                f'  {name} {format_bytes(ev[f"{name}_bytes"])}'
+                for name in ('total', 'temp', 'code')
+                if f'{name}_bytes' in ev)
             w(f"  compile {ev.get('variant', '?'):<28} "
-              f"first call {_fmt(_num(ev.get('first_call_ms')), ' ms')}")
+              f"first call {_fmt(_num(ev.get('first_call_ms')), ' ms')}"
+              + held)
         if s['retraces']:
             w(f"  ! {len(s['retraces'])} RETRACE event(s) — a "
               'static-cadence variant recompiled mid-run '

@@ -33,8 +33,9 @@ measure async dispatch only). Reference bugs fixed (SURVEY.md §8):
 Stages *inside* the jitted step are attributed by the profiler scopes
 in :mod:`observability.profiling` instead. The span and counter names
 the program itself records (``kfac/host/*``, ``kfac/build/*``,
-``kfac/state_bytes/*``, ``kfac/factors/*``, ``kfac/inverses/*``) are
-listed in README.md's observability section with what each is for.
+``kfac/state_bytes/*``, ``kfac/program_bytes/*``, ``kfac/factors/*``,
+``kfac/inverses/*``) are listed in README.md's observability section
+with what each is for.
 """
 
 from __future__ import annotations

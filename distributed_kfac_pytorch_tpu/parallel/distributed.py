@@ -148,6 +148,43 @@ def _attention_paths_since(before: dict) -> dict:
         for path in ('fused', 'plain')}
 
 
+PROGRAM_BYTES_PREFIX = 'kfac/program_bytes/'
+_PROGRAM_GAUGES = (*obs_memory.PROGRAM_FIELDS, 'total')
+
+
+def _record_program_bytes(label: str, variant, args: tuple) -> dict:
+    """Gauge one variant's bytes by the compiler's count
+    (``kfac/program_bytes/<variant>/<field>``), then, over every variant
+    the recorder holds, the fullest program (``total_max``,
+    ``temp_max``) and all loaded code (``code_sum``: what sits in the
+    runtime's ``peak_bytes_in_use`` beside the arguments). Returns the
+    build span's attributes (none where the backend gives no analysis).
+
+    The executable comes from the variant's own ``jax.jit``, lowered
+    from the arguments of its first call before the call donates them:
+    the call then finds it compiled (no second trace, no second backend
+    compile; a persistent-cache hit loads it once). Under an outer trace
+    there is no program of its own to count."""
+    if any(isinstance(x, jax.core.Tracer) for x in jax.tree.leaves(args)):
+        return {}
+    footprint = obs_memory.program_footprint(variant.lower(*args).compile())
+    if not footprint:
+        return {}
+    for field in _PROGRAM_GAUGES:
+        tracing.gauge(f'{PROGRAM_BYTES_PREFIX}{label}/{field}',
+                      footprint[field])
+    built: dict[str, list] = {}
+    for name, value in tracing.counters().items():
+        other, _, field = name[len(PROGRAM_BYTES_PREFIX):].rpartition('/')
+        if name.startswith(PROGRAM_BYTES_PREFIX) and other:
+            built.setdefault(field, []).append(value)
+    tracing.gauge(PROGRAM_BYTES_PREFIX + 'total_max', max(built['total']))
+    tracing.gauge(PROGRAM_BYTES_PREFIX + 'temp_max', max(built['temp']))
+    tracing.gauge(PROGRAM_BYTES_PREFIX + 'code_sum', sum(built['code']))
+    return {f'{field}_bytes': footprint[field]
+            for field in ('temp', 'code', 'total')}
+
+
 def resolve_grad_workers(size: int, comm_method: CommMethod,
                          grad_worker_fraction: float) -> int:
     """Number of devices per inverse group for a strategy.
@@ -2496,9 +2533,12 @@ class DistributedKFAC:
                 tracing.count('kfac/builds')
                 label = _variant_label(key)
                 counted = tracing.counters()
+                args = (params, opt_state, kstate, extra_vars, batch,
+                        hyper)
                 with _open_build_span(label) as build:
-                    out = variants[key](params, opt_state, kstate,
-                                        extra_vars, batch, hyper)
+                    build.set(**_record_program_bytes(
+                        label, variants[key], args))
+                    out = variants[key](*args)
                     build.set(
                         cache_hit=build.attrs['cache_retrieval_s'] > 0,
                         **_attention_paths_since(counted))
