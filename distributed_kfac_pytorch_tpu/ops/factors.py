@@ -85,42 +85,66 @@ def _cov_blocked(calls: Sequence[jax.Array], k: int, scale,
 
     The k(k+1)/2 blocks ``sum_c a_c[:, Bi]^T a_c[:, Bj]``, i <= j, are
     contracted (same operands, accumulator and precision as
-    :func:`_cov_full`, the calls summed in float32 block by block)
-    and written to ``(Bi, Bj)`` and, transposed, to ``(Bj, Bi)``. A
-    diagonal block is ``v^T v``, symmetric only up to round-off, and is
-    symmetrized on its own: the square is then symmetric entry for
-    entry and needs no full-size ``cov + cov.T`` pass.
-
-    One loop over the block pairs, not k(k+1)/2 contractions side by
+    :func:`_cov_full`, the calls summed in float32 block by block) in
+    one loop over the block pairs, not k(k+1)/2 contractions side by
     side: unrolled, every block is a kernel of its own in the step's
     program (about 1 MB of code each on a v5e, +0.4 GB of HBM in
     gpt2s_f1i10 at 24 blocked factors in two programs: PERF.md section
-    6, PR 30), and the blocks are arrays XLA's scheduler may hold back
-    and its layout assignment may turn; the loop is one instruction
-    with one contraction's code a call, which slices its operands at a
-    traced offset and writes each block in place.
+    6); the loop is one instruction with one contraction's code a
+    call, which slices its operands at a traced offset.
+
+    The loop carries nothing of the square's size: each trip's block
+    and its transpose are one slot each of its two stacked outputs,
+    ``(k(k+1)/2, d/k, d/k)`` float32, written whole. The square is put
+    together once, after the loop, from static slices of the stacks
+    (:func:`_square_of_blocks`), as one concatenation that what reads
+    it (the bias pad, the running average, the non-finite guard) can
+    take into its own fusion. Placed block by block into a zeroed square inside the loop
+    instead, each block and its transpose were strided writes into a
+    square XLA lays out column-major, and the result took a layout copy
+    besides; transposed after the loop, the lower blocks lead XLA to
+    the column-major square again, and to copies of the running average
+    it meets (PERF.md section 6).
     """
     rows, d = calls[0].shape
     width = d // k
     pairs = [(i, j) for i in range(k) for j in range(i, k)]
-    row_of = jnp.asarray([i * width for i, _ in pairs], jnp.int32)
-    col_of = jnp.asarray([j * width for _, j in pairs], jnp.int32)
+    offsets = jnp.asarray([(i * width, j * width) for i, j in pairs],
+                          jnp.int32)
 
-    def one_block(t, cov):
-        lo_i, lo_j = row_of[t], col_of[t]
+    def one_block(carry, lo):
         blk = _sum([jax.lax.dot_general(
-            jax.lax.dynamic_slice(a, (0, lo_i), (rows, width)),
-            jax.lax.dynamic_slice(a, (0, lo_j), (rows, width)),
+            jax.lax.dynamic_slice(a, (0, lo[0]), (rows, width)),
+            jax.lax.dynamic_slice(a, (0, lo[1]), (rows, width)),
             dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32, precision=precision)
             for a in calls])
-        blk = jnp.where(lo_i == lo_j, (blk + blk.T) * (0.5 / scale),
-                        blk * (1.0 / scale))
-        cov = jax.lax.dynamic_update_slice(cov, blk, (lo_i, lo_j))
-        return jax.lax.dynamic_update_slice(cov, blk.T, (lo_j, lo_i))
+        return carry, (blk, blk.T)
 
-    return jax.lax.fori_loop(0, len(pairs), one_block,
-                             jnp.zeros((d, d), jnp.float32))
+    _, (blocks, transposed) = jax.lax.scan(one_block, None, offsets)
+    return _square_of_blocks(blocks, transposed, pairs, scale)
+
+
+def _square_of_blocks(blocks: jax.Array, transposed: jax.Array, pairs,
+                      scale) -> jax.Array:
+    """The ``(d, d)`` square of :func:`_cov_blocked`'s stacked upper
+    blocks, ``blocks[t]`` the block at ``pairs[t] = (i, j)``, i <= j,
+    and ``transposed[t]`` its transpose: ``(i, j)`` is the block over
+    ``scale``, ``(j, i)`` its transpose over ``scale``, and a diagonal
+    block, ``v^T v`` and so symmetric only up to round-off, is
+    symmetrized on its own. The square is then symmetric entry for
+    entry and needs no full-size ``cov + cov.T`` pass."""
+    k = pairs[-1][0] + 1
+    square = {}
+    for t, (i, j) in enumerate(pairs):
+        if i == j:
+            square[i, i] = (blocks[t] + transposed[t]) * (0.5 / scale)
+        else:
+            square[i, j] = blocks[t] * (1.0 / scale)
+            square[j, i] = transposed[t] * (1.0 / scale)
+    return jnp.concatenate([
+        jnp.concatenate([square[i, j] for j in range(k)], axis=1)
+        for i in range(k)])
 
 
 def get_cov(a: jax.Array, b: jax.Array | None = None,
@@ -203,8 +227,11 @@ def get_cov_of_calls(calls: Sequence[jax.Array],
       - where the calls' rows TOGETHER are at least ``d >= 3072`` and d
         divides into three or four wide blocks a side: only the upper
         block triangle is contracted, the calls summed block by block,
-        and the lower blocks are its transposes (:func:`_cov_blocked`),
-        6 of 9 blocks or 10 of 16. Entries differ from the first
+        6 of 9 blocks or 10 of 16, stacked with their transposes as
+        one loop's outputs; the square is assembled once after the
+        loop, the lower blocks the upper ones' transposes, where what
+        reads the statistic can fuse it (:func:`_cov_blocked`).
+        Entries differ from the first
         route's only in float32 summation order.
 
     The calls are read where they are, no copy, whatever their number

@@ -67,6 +67,34 @@ def _single_contraction(a, scale=None, compute_dtype=None):
     return (cov + cov.T) * (0.5 / (a.shape[0] if scale is None else scale))
 
 
+def _placed_cov_blocked(calls, k, scale, precision):
+    """``factors._cov_blocked`` as it was before its blocks became the
+    loop's stacked output, kept verbatim as the oracle of the same
+    arithmetic: each block and its transpose written into a zeroed
+    square the loop carries."""
+    rows, d = calls[0].shape
+    width = d // k
+    pairs = [(i, j) for i in range(k) for j in range(i, k)]
+    row_of = jnp.asarray([i * width for i, _ in pairs], jnp.int32)
+    col_of = jnp.asarray([j * width for _, j in pairs], jnp.int32)
+
+    def one_block(t, cov):
+        lo_i, lo_j = row_of[t], col_of[t]
+        blk = factors._sum([jax.lax.dot_general(
+            jax.lax.dynamic_slice(a, (0, lo_i), (rows, width)),
+            jax.lax.dynamic_slice(a, (0, lo_j), (rows, width)),
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32, precision=precision)
+            for a in calls])
+        blk = jnp.where(lo_i == lo_j, (blk + blk.T) * (0.5 / scale),
+                        blk * (1.0 / scale))
+        cov = jax.lax.dynamic_update_slice(cov, blk, (lo_i, lo_j))
+        return jax.lax.dynamic_update_slice(cov, blk.T, (lo_j, lo_i))
+
+    return jax.lax.fori_loop(0, len(pairs), one_block,
+                             jnp.zeros((d, d), jnp.float32))
+
+
 class TestBlockedCov:
     """``get_cov``'s self-covariance contracts only the upper block
     triangle where the static ``(rows, d)`` makes the contraction
@@ -156,6 +184,69 @@ class TestBlockedCov:
                 aval = eqn.outvars[0].aval
                 assert aval.shape in ((), (d // k, d // k)), eqn
         assert counters == {'kfac/factors/cov_blocked': 1}
+
+    # (calls, rows a call, d, column blocks a side): one call at and
+    # over the dim, and calls whose rows open the gate only together.
+    PLACED = [(1, 3072, 3072, 3), (1, 3100, 3072, 3), (4, 768, 3072, 3),
+              (2, 1536, 3072, 3)]
+
+    @pytest.mark.parametrize('compute_dtype', [
+        None, jnp.float32, jnp.bfloat16],
+        ids=['default', 'float32', 'bfloat16'])
+    @pytest.mark.parametrize('n_calls,rows,d,k', PLACED, ids=[
+        f'{c}x{r}x{d}' for c, r, d, _ in PLACED])
+    def test_equals_the_placed_square_bit_for_bit(self, n_calls, rows, d,
+                                                  k, compute_dtype):
+        calls = [rand(rows, d, seed=d + c) for c in range(n_calls)]
+        tracing.clear_trace()
+        got = jax.jit(lambda xs: factors.get_cov_of_calls(
+            xs, compute_dtype=compute_dtype))(calls)
+        assert tracing.counters()['kfac/factors/cov_blocked'] == 1
+        ops = (calls if compute_dtype is None
+               else [a.astype(compute_dtype) for a in calls])
+        want = jax.jit(lambda xs: _placed_cov_blocked(
+            xs, k, rows, factors._cov_precision(compute_dtype)))(ops)
+        # the same contractions, the same scaling and symmetrization:
+        # only where each block is written differs
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(got, got.T)
+
+    @pytest.mark.parametrize('n_calls,rows,d,k', PLACED[::2] + [
+        (1, 4096, 4096, 4), (4, 4096, 5632, 4)], ids=[
+        f'{c}x{r}x{d}' for c, r, d, _ in PLACED[::2]] + [
+        '1x4096x4096', '4x4096x5632'])
+    def test_the_loop_stacks_its_blocks_and_carries_no_square(
+            self, n_calls, rows, d, k):
+        a = jax.ShapeDtypeStruct((rows, d), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(lambda *xs: factors.get_cov_of_calls(xs))(
+            *[a] * n_calls).jaxpr
+        (loop,) = [e for e in jaxpr.eqns if e.primitive.name == 'scan']
+        width, n_pairs = d // k, k * (k + 1) // 2
+        # what goes in and comes out: the operands, the block offsets,
+        # and the stacked upper blocks and their transposes; no carry
+        assert loop.params['num_carry'] == 0
+        assert [(v.aval.shape, v.aval.dtype) for v in loop.outvars] == [
+            ((n_pairs, width, width), jnp.float32)] * 2
+
+        def square(v):  # a float32 (d, d), not a (d, d) bf16 operand
+            return v.aval.shape == (d, d) and v.aval.dtype == jnp.float32
+
+        assert not [v for v in loop.invars if square(v)]
+        # the body writes no square: a block and its transpose
+        body = loop.params['jaxpr'].jaxpr
+        assert 'dynamic_update_slice' not in _count_primitives(body)
+        assert not [e for e in body.eqns if e.primitive.name == 'select_n'
+                    and e.outvars[0].aval.shape]
+        assert [e.invars[0].aval.shape for e in body.eqns
+                if e.primitive.name == 'transpose'] == [(width, width)]
+        assert not [v for e in body.eqns for v in e.outvars if square(v)]
+        # nothing zeroed, placed or transposed outside it: the square is
+        # one concatenation of (d/k, d/k) pieces
+        found = _count_primitives(jaxpr)
+        assert 'dynamic_update_slice' not in found
+        assert not [e for e in jaxpr.eqns if e.primitive.name == 'transpose']
+        squares = [e for e in jaxpr.eqns if any(map(square, e.outvars))]
+        assert [e.primitive.name for e in squares] == ['concatenate']
 
     def test_strict_float32_keeps_highest_in_every_block(self):
         a = jax.ShapeDtypeStruct((3072, 3072), jnp.bfloat16)
@@ -307,8 +398,12 @@ class TestMergedCov:
             lambda *xs: factors.get_cov_of_calls(xs))(*[a] * 4).jaxpr
         found = _count_primitives(jaxpr)
         # no copy of the operands, long calls or short: a contraction
-        # a call
-        assert 'concatenate' not in found and found['dot_general'] == 4
+        # a call (the blocked route concatenates its float32 blocks
+        # into the square, never the calls)
+        assert found['dot_general'] == 4
+        assert not [e for e in jaxpr.eqns if e.primitive.name ==
+                    'concatenate' and any(v.aval.shape == (rows, d)
+                                          for v in e.invars)]
         squares = [e for e in jaxpr.eqns if e.primitive.name == 'transpose'
                    and e.outvars[0].aval.shape == (d, d)]
         if route.endswith('full'):
