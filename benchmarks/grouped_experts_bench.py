@@ -75,7 +75,8 @@ def main() -> int:
     print(json.dumps({'tail_rows_nonzero': int(np.count_nonzero(tail)),
                       'tail_rows_finite': bool(np.isfinite(tail).all())}))
 
-    layer = mla_moe_lm.MoE(experts_held=(0, HELD), dtype=jnp.bfloat16)
+    layer = mla_moe_lm.MoE(experts_held=(0, HELD),
+                           routed_scaling_factor=2.448, dtype=jnp.bfloat16)
     h = jax.random.normal(key, (8, 1024, D), jnp.bfloat16)
     params = jax.jit(layer.init)(key, h)
 

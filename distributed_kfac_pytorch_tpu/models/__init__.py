@@ -14,6 +14,9 @@
   full-head SwiGLU layers run several times a forward with the same
   weights, an exit and a gate after every pass; every projection is one
   K-FAC layer with several calls a step (the multi-call capture path).
+- ``window_moe_lm``: Mellum 2-shaped decoder LM — sliding-window and
+  full attention layers mixed, grouped key/value heads, YaRN RoPE on the
+  full layers, softmax-routed stacked experts with no shared one.
 - ``mobilenet``: MobileNetV1 — the depthwise workload the reference
   cannot precondition (no grouped-conv layer kind there); exercises
   this framework's ``conv2d_grouped`` path end to end.
@@ -30,3 +33,4 @@ from distributed_kfac_pytorch_tpu.models import mla_moe_lm
 from distributed_kfac_pytorch_tpu.models import mobilenet
 from distributed_kfac_pytorch_tpu.models import transformer_lm
 from distributed_kfac_pytorch_tpu.models import vit
+from distributed_kfac_pytorch_tpu.models import window_moe_lm

@@ -64,15 +64,23 @@ from distributed_kfac_pytorch_tpu.parallel.sequence import (
 )
 
 
-def rope_half(x: jax.Array, pos: jax.Array, theta: float) -> jax.Array:
+def rope_half(x: jax.Array, pos: jax.Array, theta: float, *,
+              inv_freq: jax.Array | None = None,
+              scale: float = 1.0) -> jax.Array:
     """RoPE in the half-split layout: with ``x = [x1 | x2]`` the halves
     of the last dim, ``[x1 cos - x2 sin | x2 cos + x1 sin]`` at angle
     ``pos * theta**(-2i/d)`` for entry ``i`` of either half, in float32.
-    ``x``: (B, T, H, d)."""
+    ``x``: (B, T, H, d). ``inv_freq`` ((d/2,)) replaces
+    ``theta**(-2i/d)`` and ``scale`` multiplies cos and sin (YaRN's
+    frequencies and attention factor: ``models/window_moe_lm.py``)."""
     d = x.shape[-1]
-    inv_freq = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if inv_freq is None:
+        inv_freq = 1.0 / theta ** (jnp.arange(0, d, 2, dtype=jnp.float32)
+                                   / d)
     angle = pos.astype(jnp.float32)[:, None, None] * inv_freq  # (T,1,d/2)
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                            axis=-1).astype(x.dtype)

@@ -24,7 +24,9 @@ bias-free, every norm an RMSNorm computed in float32):
   gradient reaches it), ``w = s[chosen] / (sum s[chosen] + 1e-20) *
   routed_scaling_factor``; no token is dropped and there is no
   capacity. Every expert is a SwiGLU; the shared experts are one SwiGLU
-  of their summed width.
+  of their summed width. ``MoE`` also takes the Qwen-MoE router
+  (``scoring_func='softmax'``: softmax over all experts, no bias) and
+  no shared expert, as ``models/window_moe_lm.py`` runs it.
 - token embedding with nothing added, a final RMSNorm, an untied head.
 
 **Shares.** A deployment spreads a layer's experts and heads over
@@ -173,26 +175,40 @@ class StackedExperts(nn.Module):
             nn.silu(gate) * up, group_sizes)
 
 
-def route(logits: jax.Array, bias: jax.Array, top_k: int,
-          scaling: float) -> tuple[jax.Array, jax.Array]:
+def route(logits: jax.Array, bias: jax.Array | None, top_k: int,
+          scaling: float, scoring: str = 'sigmoid'
+          ) -> tuple[jax.Array, jax.Array]:
     """``(chosen experts, their weights)``, each ``(tokens, top_k)``:
-    sigmoid scores, the bias in the choice only, weights normalised over
-    the choice and scaled."""
-    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
-    _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    ``sigmoid`` scores with the bias in the choice only (DeepSeek-V3),
+    or ``softmax`` scores over all experts and no bias (Qwen-MoE);
+    weights normalised over the choice and scaled."""
+    logits = logits.astype(jnp.float32)
+    if scoring == 'softmax':
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, chosen = jax.lax.top_k(scores, top_k)
+    elif scoring == 'sigmoid':
+        scores = jax.nn.sigmoid(logits)
+        _, chosen = jax.lax.top_k(scores + jax.lax.stop_gradient(bias),
+                                  top_k)
+    else:
+        raise ValueError(f'unknown scoring {scoring!r}')
     picked = jnp.take_along_axis(scores, chosen, axis=-1)
     weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
     return chosen, weights * scaling
 
 
 class MoE(nn.Module):
-    """``shared(h) + sum_k w_k expert_k(h)`` over the experts held."""
+    """``shared(h) + sum_k w_k expert_k(h)`` over the experts held.
+    ``scoring_func`` as :func:`route` takes it; a ``sigmoid`` router
+    holds the correction bias, a ``softmax`` one none. No shared MLP
+    where ``n_shared_experts`` is 0."""
     n_routed_experts: int = 128
     experts_held: tuple[int, int] = (0, 128)
     num_experts_per_tok: int = 6
     moe_intermediate_size: int = 768
     n_shared_experts: int = 2
-    routed_scaling_factor: float = 2.448
+    routed_scaling_factor: float = 1.0
+    scoring_func: str = 'sigmoid'
     dtype: Any = None
 
     @nn.compact
@@ -213,11 +229,14 @@ class MoE(nn.Module):
         logits = dense(self.n_routed_experts, jnp.float32, 'router',
                        precision=jax.lax.Precision.HIGHEST)(
             h.astype(jnp.float32))
-        bias = self.param('e_score_correction_bias',
-                          nn.initializers.zeros,
-                          (self.n_routed_experts,), jnp.float32)
+        bias = None
+        if self.scoring_func == 'sigmoid':
+            bias = self.param('e_score_correction_bias',
+                              nn.initializers.zeros,
+                              (self.n_routed_experts,), jnp.float32)
         chosen, weights = route(logits, bias, k,
-                                self.routed_scaling_factor)
+                                self.routed_scaling_factor,
+                                self.scoring_func)
 
         # Dispatch: the (token, choice) pairs sorted by the held expert
         # they go to, pairs for experts held elsewhere last. The buffer
@@ -236,9 +255,11 @@ class MoE(nn.Module):
             dispatch_rows(h, token_of_row, rows_here), group_sizes)
         routed = combine_rows(y, weights.reshape(-1)[order], token_of_row,
                               rows_here, n).astype(x.dtype)
-        shared = GatedMLP(self.n_shared_experts * self.moe_intermediate_size,
-                          self.dtype, name='shared_experts')(h)
-        return (shared + routed).reshape(x.shape)
+        if self.n_shared_experts:
+            routed = GatedMLP(
+                self.n_shared_experts * self.moe_intermediate_size,
+                self.dtype, name='shared_experts')(h) + routed
+        return routed.reshape(x.shape)
 
 
 class Block(nn.Module):

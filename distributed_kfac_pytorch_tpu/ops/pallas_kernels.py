@@ -654,11 +654,16 @@ def _tpu_interpret_forced() -> bool:
     return state is not None and state.value is not None
 
 
-@functools.lru_cache(maxsize=1)
-def _fused_attention_probed() -> bool:
-    """Once per process: the kill switch (recorded), else the probe."""
+def _attention_probe(kernel: str, fused, q_shape, kv_shape, **kw) -> bool:
+    """The kill switch (recorded), else one route's parity probe against
+    the plain path, output and dq/dk/dv, on bf16 normals of q's and
+    k/v's shapes; ``kw`` goes to both paths. The scalar the gradients
+    are taken of is not compared: a sum of terms of either sign, its
+    relative error is the paths' rounding divided by how far the sum
+    cancels (0.12 at the splash probe's shapes on the CPU's
+    interpreter, where output and gradients read 0.005)."""
     if _forced_fallback():
-        record_fallback('attention', 'forced by KFAC_PALLAS_FALLBACK')
+        record_fallback(kernel, 'forced by KFAC_PALLAS_FALLBACK')
         return False
     if jax.default_backend() != 'tpu':
         return True
@@ -668,19 +673,20 @@ def _fused_attention_probed() -> bool:
 
         from distributed_kfac_pytorch_tpu.parallel import sequence
         rng = np.random.default_rng(0)
-        # Two 512-tiles a side: a skipped tile, a full one, two masked.
-        q, k, v, w = (jnp.asarray(rng.normal(size=(1, 1024, 1, 64)),
-                                  jnp.bfloat16) for _ in range(4))
+        q, k, v, w = (jnp.asarray(rng.normal(size=s), jnp.bfloat16)
+                      for s in (q_shape, kv_shape, kv_shape, q_shape))
 
         def out_and_grads(attend):
-            return jax.value_and_grad(lambda *qkv: jnp.sum(
-                attend(*qkv, causal=True) * w.astype(jnp.float32)),
-                (0, 1, 2))
+            def loss(*qkv):
+                out = attend(*qkv, causal=True, **kw)
+                return jnp.sum(out * w.astype(jnp.float32)), out
+            return lambda *qkv: jax.grad(loss, (0, 1, 2), has_aux=True)(
+                *qkv)[::-1]
 
         # One program for both paths: run eagerly, each of their
         # operations would compile by itself (7 s on a v5e).
         got, ref = jax.jit(lambda *qkv: (
-            out_and_grads(fused_attention)(*qkv),
+            out_and_grads(fused)(*qkv),
             out_and_grads(sequence.plain_attention)(*qkv)))(q, k, v)
         return max(max_rel_error(a, b) for a, b in zip(
             jax.tree.leaves(got), jax.tree.leaves(ref)))
@@ -690,7 +696,15 @@ def _fused_attention_probed() -> bool:
     # does under no trace, mesh or scope of the caller's; result()
     # raises here what the probe raised there.
     with concurrent.futures.ThreadPoolExecutor(1) as pool:
-        return pool.submit(_probe_on_tpu, 'attention', rel_error).result()
+        return pool.submit(_probe_on_tpu, kernel, rel_error).result()
+
+
+@functools.lru_cache(maxsize=1)
+def _fused_attention_probed() -> bool:
+    """Once per process: the kill switch (recorded), else the probe."""
+    # Two 512-tiles a side: a skipped tile, a full one, two masked.
+    shape = (1, 1024, 1, 64)
+    return _attention_probe('attention', fused_attention, shape, shape)
 
 
 def fused_attention_applies(q, k, v, kvalid=None) -> bool:
@@ -717,3 +731,146 @@ def fused_attention_applies(q, k, v, kvalid=None) -> bool:
     if jax.default_backend() != 'tpu' and not _tpu_interpret_forced():
         return False
     return _fused_attention_probed()
+
+
+# ---------------------------------------------------------------------------
+# Windowed and grouped-head attention
+# ---------------------------------------------------------------------------
+#
+# The flash kernel above takes one causal mask over q, k and v of one
+# shape. A sliding window (query i reads keys i - W < j <= i) or fewer
+# key/value heads than query heads (q head j reads kv head j // (H_q /
+# H_kv)) take the splash kernels JAX ships instead
+# (jax.experimental.pallas.ops.tpu.splash_attention): the mask is
+# described tile by tile, the tiles outside it are skipped, forward and
+# backward, and each q head reads its kv head where it lies, with no
+# repeated copy. Same arithmetic as the flash route: bf16 or fp32
+# operands with fp32 accumulation, fp32 row statistics, P cast to V's
+# dtype for P.V; q is scaled by 1/sqrt(d) before the kernel, which
+# applies no scale of its own.
+
+
+SPLASH_KERNEL_MODULE = ('jax.experimental.pallas.ops.tpu.splash_attention.'
+                        'splash_attention_kernel')
+
+
+@functools.lru_cache(maxsize=1)
+def _splash_kernels():
+    """A private copy of JAX's splash kernel module whose kernels keep
+    their ``jax.named_scope``; JAX's own module is left as it is.
+
+    The shipped kernels hand ``pallas_call`` profiler ``metadata``,
+    which the TPU lowering attaches to the custom call as frontend
+    attributes, and XLA's TPU compiler then drops the call's
+    ``op_name``: a device trace would tie the kernels to no scope, and
+    ``kfac_model/attention`` would read only the glue round them (0.68
+    of 14 ms a step, the windowed cell's first traced run on a v5e).
+    The copy is executed from the module's own source under no entry of
+    ``sys.modules``, and its name for Pallas is then pointed at a
+    namespace whose ``pallas_call`` leaves that metadata out. Raises if
+    the module no longer reaches Pallas by that name, rather than hand
+    back kernels that lose their scope without a word."""
+    import importlib.util
+    import types
+
+    from jax.experimental import pallas as pl
+
+    spec = importlib.util.find_spec(SPLASH_KERNEL_MODULE)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    if getattr(module, 'pl', None) is not pl:
+        raise RuntimeError(
+            f'{SPLASH_KERNEL_MODULE} no longer reaches Pallas as `pl`: '
+            'its kernels would carry no op_name in a device trace')
+
+    def pallas_call(*args, metadata=None, **kwargs):
+        del metadata  # profiler annotations only
+        return pl.pallas_call(*args, **kwargs)
+
+    module.pl = types.SimpleNamespace(**{**vars(pl),
+                                         'pallas_call': pallas_call})
+    return module
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(heads: int, t: int, window: int | None):
+    """The kernel for ``heads`` q heads over ``t`` positions, causal or
+    in a causal window of ``window`` keys, in tiles of 512 where they
+    divide ``t`` (else 256) in all three of its passes. Its tables of
+    which tiles to visit are made once, as concrete arrays (under no
+    trace, so that the cached kernel serves every later one)."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as masks,
+    )
+
+    kernels = _splash_kernels()
+    if window is None:
+        mask = masks.CausalMask((t, t))
+    else:
+        mask = masks.LocalMask((t, t), window_size=(window - 1, 0),
+                               offset=0)
+    s = 512 if t % 512 == 0 else ATTENTION_MIN_BLOCK
+    sizes = kernels.BlockSizes(block_q=s, block_kv=s, block_kv_compute=s,
+                               block_q_dkv=s, block_kv_dkv=s,
+                               block_kv_dkv_compute=s, block_q_dq=s,
+                               block_kv_dq=s)
+    with jax.ensure_compile_time_eval():
+        return kernels.make_splash_mha(masks.MultiHeadMask([mask] * heads),
+                                       block_sizes=sizes, head_shards=1,
+                                       q_seq_shards=1)
+
+
+def splash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                     causal: bool = True,
+                     window: int | None = None) -> jax.Array:
+    """Exact causal softmax attention in the shipped splash kernels,
+    over the whole past (``window`` None) or the last ``window`` keys.
+
+    ``q``: (B, T, H, D); ``k``/``v``: (B, T, H_kv, D) with H a multiple
+    of H_kv, all of one dtype; returns (B, T, H, D) float32 like the
+    plain path, rounded once to the input dtype by the kernel.
+    Differentiable; no (T, T) tensor reaches HBM in either direction."""
+    if not causal:
+        raise ValueError('the splash route is causal only')
+    b, t, heads, d = q.shape
+    kernel = _splash_kernel(heads, t, window)
+    q = (q.astype(jnp.float32) * (1.0 / d ** 0.5)).astype(k.dtype)
+    heads_first = lambda x: jnp.swapaxes(x, 1, 2)  # noqa: E731
+    o = jax.vmap(kernel)(heads_first(q), heads_first(k), heads_first(v))
+    return heads_first(o).astype(jnp.float32)
+
+
+@functools.lru_cache(maxsize=1)
+def _splash_attention_probed() -> bool:
+    """Once per process: the kill switch (recorded), else the probe."""
+    # Two 512-tiles a side, a window of one tile (the band crosses
+    # both, one tile is skipped), 4 q heads over 2 kv heads.
+    return _attention_probe('splash_attention', splash_attention,
+                            (1, 1024, 4, 128), (1, 1024, 2, 128),
+                            window=512)
+
+
+def splash_attention_applies(q, k, v, kvalid=None) -> bool:
+    """The gate of :func:`splash_attention`, from what the call shows.
+
+    Open for self-attention without a key mask, k and v of one shape,
+    q of their batch, length and head dim with a whole multiple of
+    their heads, one dtype (bfloat16 or float32), a head dim in
+    :data:`ATTENTION_HEAD_DIMS` and ``T`` a whole number of
+    :data:`ATTENTION_MIN_BLOCK` tiles; on a TPU, where the route's own
+    once-per-process probe must pass or raise, or under Pallas's TPU
+    interpret mode. ``KFAC_PALLAS_FALLBACK=1`` closes it as it closes
+    :func:`fused_attention_applies`."""
+    b, t, heads, d = q.shape
+    if kvalid is not None or k.shape != v.shape or heads % k.shape[2]:
+        return False
+    if k.shape != (b, t, k.shape[2], d):
+        return False
+    if not (q.dtype == k.dtype == v.dtype
+            and q.dtype in (jnp.bfloat16, jnp.float32)):
+        return False
+    if d not in ATTENTION_HEAD_DIMS or t % ATTENTION_MIN_BLOCK:
+        return False
+    if jax.default_backend() != 'tpu' and not _tpu_interpret_forced():
+        return False
+    return _splash_attention_probed()

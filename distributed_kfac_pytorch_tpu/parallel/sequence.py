@@ -43,13 +43,15 @@ SEQ_AXIS = 'kfac_sp'
 _NEG_INF = -1e30
 
 
-def _block_attend(q, k, v, scale, qpos, kpos, causal, kvalid=None):
+def _block_attend(q, k, v, scale, qpos, kpos, causal, kvalid=None,
+                  window=None):
     """One blockwise attention contribution with positions for masking.
 
     q: (B, Tq, H, D), k/v: (B, Tk, H, D); qpos/kpos: (Tq,)/(Tk,) global
     token positions. ``kvalid`` (optional, (Tk,) bool) masks out padding
     keys — the chunked path pads ragged sequences up to a block
-    multiple. Returns (scores_max, exp_scores @ v, exp_scores sum)
+    multiple. ``window`` (causal only) keeps the keys ``qpos - window <
+    kpos``. Returns (scores_max, exp_scores @ v, exp_scores sum)
     per (B, H, Tq).
 
     Operands enter the QK^T einsum at their INPUT dtype with fp32
@@ -68,6 +70,8 @@ def _block_attend(q, k, v, scale, qpos, kpos, causal, kvalid=None):
     mask = None
     if causal:
         mask = kpos[None, :] <= qpos[:, None]          # (Tq, Tk)
+        if window is not None:
+            mask &= kpos[None, :] > qpos[:, None] - window
     if kvalid is not None:
         kv = jnp.broadcast_to(kvalid[None, :],
                               (qpos.shape[0], kpos.shape[0]))
@@ -155,54 +159,80 @@ def ring_self_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 def plain_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True,
-                    kvalid: jax.Array | None = None) -> jax.Array:
+                    kvalid: jax.Array | None = None,
+                    window: int | None = None) -> jax.Array:
     """Monolithic attention: one ``_block_attend`` over the whole
     sequence, (B, H, T, T) float32 scores and probabilities in HBM.
-    The path every backend can take, and the fused kernel's reference."""
+    The path every backend can take, and the fused kernels' reference.
+    k/v with fewer heads than q are repeated to q's heads first (q head
+    ``j`` reads kv head ``j // (H / H_kv)``)."""
     b, t, h, d = q.shape
+    if k.shape[2] != h:
+        k, v = (jnp.repeat(x, h // k.shape[2], axis=2) for x in (k, v))
     pos = jnp.arange(t)
     m, o, l = _block_attend(q, k, v, 1.0 / (d ** 0.5), pos, pos, causal,
-                            kvalid=kvalid)
+                            kvalid=kvalid, window=window)
     l = jnp.maximum(jnp.moveaxis(l, 1, 2)[..., None], 1e-30)
     return o / l
 
 
 def local_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                            *, causal: bool = True,
-                           kvalid: jax.Array | None = None) -> jax.Array:
+                           kvalid: jax.Array | None = None,
+                           window: int | None = None) -> jax.Array:
     """Single-device attention with the same contract as the ring path:
     (B, T, H, D) in, exact softmax attention out, (B, T, H, D) fp32.
     ``kvalid`` ((T,) bool) masks padding keys as in ``_block_attend``.
+    ``window`` (causal only): query ``i`` reads keys ``i - window < j
+    <= i``. k/v may have fewer heads than q, ``H_kv`` dividing ``H``:
+    q head ``j`` reads kv head ``j // (H / H_kv)``.
 
-    The call itself decides between two forms of the same fold
-    (``ops.pallas_kernels.fused_attention_applies``): the fused TPU
-    kernel when the backend is a TPU, there is no ``kvalid``, q/k/v
-    share one shape and one dtype (bf16 or fp32), the head dim is one
-    the kernel takes (64, 128) and T is a whole number of 256-row
-    tiles; the plain path (``plain_attention``) otherwise — a ViT's
-    T = 197, any CPU run — unchanged. ``KFAC_PALLAS_FALLBACK=1`` forces
-    the plain path and records a ``pallas_fallback`` event.
+    The call itself decides between forms of the same fold. A call with
+    neither a window nor grouped heads takes the fused TPU kernel
+    (``ops.pallas_kernels.fused_attention_applies``) when the backend is
+    a TPU, there is no ``kvalid``, q/k/v share one shape and one dtype
+    (bf16 or fp32), the head dim is one the kernel takes (64, 128) and
+    T is a whole number of 256-row tiles. A call with a window or
+    grouped heads takes the splash kernels under the same conditions
+    (``splash_attention_applies``, its own probe). Anything else takes
+    the plain path (``plain_attention``) — a ViT's T = 197, any CPU run
+    — unchanged. ``KFAC_PALLAS_FALLBACK=1`` forces the plain path and
+    records a ``pallas_fallback`` event.
 
-    The fused path's contract: exact attention at the same precisions
+    The fused paths' contract: exact attention at the same precisions
     (operands enter QK^T and P.V at the input dtype with fp32
     accumulation, max/exp/sum and the output accumulator in fp32; P is
     cast to V's dtype for P.V, which is what the TPU's default matmul
     precision does to the plain path's fp32 P). It equals the plain
     path up to summation order and one rounding of the result to the
-    input dtype, and writes no (B, H, T, T) tensor — scores,
-    probabilities, mask or their gradients — to HBM, forward or
-    backward (the backward recomputes a tile's probabilities from the
-    saved row max and sum).
+    input dtype (the splash route also rounds q/sqrt(d) once), and
+    writes no (B, H, T, T) tensor — scores, probabilities, mask or
+    their gradients — to HBM, forward or backward (the backward
+    recomputes a tile's probabilities from the saved row statistics).
 
     Each traced call counts itself in the recorder
-    (``observability.tracing``): ``kfac/attention/fused`` or
-    ``kfac/attention/plain``.
+    (``observability.tracing``): ``kfac/attention/fused`` (either
+    kernel) or ``kfac/attention/plain``, and besides
+    ``kfac/attention/windowed`` where it has a window and
+    ``kfac/attention/grouped`` where k/v have fewer heads than q.
     """
-    if pallas_kernels.fused_attention_applies(q, k, v, kvalid):
+    if window is not None and not causal:
+        raise ValueError('a window is causal: keys i - window < j <= i')
+    grouped = k.shape[2] != q.shape[2]
+    if window is not None:
+        tracing.count('kfac/attention/windowed')
+    if grouped:
+        tracing.count('kfac/attention/grouped')
+    if window is not None or grouped:
+        if pallas_kernels.splash_attention_applies(q, k, v, kvalid):
+            tracing.count('kfac/attention/fused')
+            return pallas_kernels.splash_attention(q, k, v, window=window)
+    elif pallas_kernels.fused_attention_applies(q, k, v, kvalid):
         tracing.count('kfac/attention/fused')
         return pallas_kernels.fused_attention(q, k, v, causal=causal)
     tracing.count('kfac/attention/plain')
-    return plain_attention(q, k, v, causal=causal, kvalid=kvalid)
+    return plain_attention(q, k, v, causal=causal, kvalid=kvalid,
+                           window=window)
 
 
 def chunked_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,

@@ -2,7 +2,7 @@
 
 Working TPU-native counterpart of the reference's WIP LM entry point
 (examples/torch_language_model.py — broken as shipped: SURVEY.md §8 notes
-the lr and factory-unpacking bugs at :253,:277). Four architectures:
+the lr and factory-unpacking bugs at :253,:277). Five architectures:
 
 - ``--arch lstm``: the K-FAC-friendly LSTM LM (reference rnn_utils/lstm.py
   + kfac/modules/lstm.py), BPTT windows (``--bptt 35``,
@@ -27,6 +27,12 @@ the lr and factory-unpacking bugs at :253,:277). Four architectures:
   ``--looped-size``; every projection is one K-FAC layer called once a
   pass (``num_calls``), the head left to SGD by default. The step hands
   the model its targets and takes the mean of the objective it returns.
+- ``--arch window_moe``: the Mellum 2-shaped decoder of
+  ``models/window_moe_lm.py`` (sliding-window and full attention mixed,
+  grouped key/value heads, YaRN RoPE on the full layers, softmax-routed
+  stacked experts, untied head) at ``--window-moe-size``; K-FAC on every
+  projection, the router and every expert matrix, the head left to SGD
+  by default.
 
 Data: whitespace-tokenized train.txt/valid.txt under --data-dir
 (PTB/WikiText layout), else a synthetic Markov corpus (offline default).
@@ -61,6 +67,7 @@ from distributed_kfac_pytorch_tpu.models import (
     lstm_lm,
     mla_moe_lm,
     transformer_lm,
+    window_moe_lm,
 )
 from distributed_kfac_pytorch_tpu.parallel import distributed as D
 from distributed_kfac_pytorch_tpu.parallel import sequence as seq
@@ -86,7 +93,8 @@ def parse_args(argv=None):
     p.add_argument('--checkpoint-dir', default='./checkpoints/lm')
     p.add_argument('--checkpoint-freq', type=int, default=5)
     p.add_argument('--arch', default='lstm',
-                   choices=['lstm', 'transformer', 'mla_moe', 'looped'])
+                   choices=['lstm', 'transformer', 'mla_moe', 'looped',
+                            'window_moe'])
     p.add_argument('--mla-moe-size', default='tiny',
                    choices=['tiny', 'kanana2'],
                    help="--arch mla_moe: mla_moe_lm.get_model's named "
@@ -97,6 +105,12 @@ def parse_args(argv=None):
                    help="--arch looped: looped_lm.get_model's named shape "
                         "('ouro_2p6b': Ouro-2.6B as published, 48 layers "
                         "run 4 times; more than one 16 GB chip holds)")
+    p.add_argument('--window-moe-size', default='tiny',
+                   choices=['tiny', 'mellum2'],
+                   help="--arch window_moe: window_moe_lm.get_model's "
+                        "named shape ('mellum2': Mellum2-12B-A2.5B as "
+                        "published, 28 layers and 64 experts; more than "
+                        "one 16 GB chip holds)")
     # Model size (reference torch_language_model.py:41-50).
     p.add_argument('--emsize', type=int, default=650)
     p.add_argument('--nhid', type=int, default=650)
@@ -265,6 +279,9 @@ def build_model(args, vocab_size, seq_axis=None, dtype=None):
     if args.arch == 'looped':
         return looped_lm.get_model(vocab_size, args.looped_size,
                                    dtype=dtype)
+    if args.arch == 'window_moe':
+        return window_moe_lm.get_model(vocab_size, args.window_moe_size,
+                                       dtype=dtype)
     return transformer_lm.TransformerLM(
         vocab_size=vocab_size, d_model=args.emsize,
         num_layers=args.nlayers, num_heads=args.nheads,
@@ -315,7 +332,8 @@ def main(argv=None):
     if args.skip_layers is None:
         args.skip_layers = {'lstm': ['embed', 'decoder'],
                             'mla_moe': ['head'],
-                            'looped': ['head']}.get(args.arch, [])
+                            'looped': ['head'],
+                            'window_moe': ['head']}.get(args.arch, [])
 
     seq_axis = seq.SEQ_AXIS if sp > 1 else None
     model = build_model(args, vocab_size, seq_axis=seq_axis)

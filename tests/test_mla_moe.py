@@ -142,7 +142,8 @@ def test_expert_shares_add_up_to_the_uncut_reference_layer():
                       reference.Rounding(), {})
     bias = {'e_score_correction_bias': jnp.zeros((8,))}
     common = dict(n_routed_experts=8, num_experts_per_tok=2,
-                  moe_intermediate_size=16, n_shared_experts=2)
+                  moe_intermediate_size=16, n_shared_experts=2,
+                  routed_scaling_factor=2.448)
     shared = mla_moe_lm.GatedMLP(32).apply(
         {'params': params['shared_experts']}, h)
     total = shared                       # the replicated part, once

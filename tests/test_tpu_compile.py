@@ -7,6 +7,7 @@ imports every test file), and all such tests live in this one file.
 """
 
 import os
+import re
 import types
 
 import jax
@@ -14,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 from distributed_kfac_pytorch_tpu.observability import memory as obs_memory
+from distributed_kfac_pytorch_tpu.ops import pallas_kernels
 from distributed_kfac_pytorch_tpu.parallel import distributed as D
 
 
@@ -90,3 +92,32 @@ def test_program_footprint_reads_a_tpu_programs_code(one_chip):
         assert footprint['total'] == (
             footprint['argument'] + footprint['output']
             - footprint['alias'] + footprint['temp'] + footprint['code'])
+
+
+@pytest.mark.parametrize('window', [1024, None], ids=['sliding', 'full'])
+def test_the_splash_route_compiles_at_mellum2s_widths(one_chip, window):
+    """The windowed/grouped attention route at the cell's shapes (4096
+    positions, 32 q heads over 4 kv heads of 128, bf16), forward and
+    backward: Mosaic takes its three kernels, each carries the caller's
+    ``jax.named_scope`` in its ``op_name`` (what a device trace ties it
+    to a scope by), and nothing of (T, T) size is left to XLA's
+    temporaries."""
+    q = jax.ShapeDtypeStruct((1, 4096, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 4096, 4, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(*qkv):
+        with jax.named_scope('kfac_model/attention/sliding'):
+            out = pallas_kernels.splash_attention(*qkv, window=window)
+        return jnp.sum(out ** 2)
+
+    compiled = _compiled(jax.value_and_grad(loss, (0, 1, 2)), None, q, kv,
+                         kv)
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    for line in calls:
+        assert re.search(r'op_name="[^"]*kfac_model/attention/sliding',
+                         line), line[:200]
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
