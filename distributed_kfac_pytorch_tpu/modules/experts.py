@@ -3,18 +3,26 @@
 ``ExpertsDense`` holds ``(num_experts, in, out)`` and multiplies each
 row of its input by the weight of the expert the row was routed to. The
 rows arrive sorted by expert, ``group_sizes[e]`` of them for expert
-``e``. The product is ``jax.lax.ragged_dot``: on a TPU XLA lowers it to
-its grouped-matmul kernel, whose time follows the rows that are there
-and not ``num_experts x rows`` nor the buffer (PERF.md, PR 27);
-elsewhere JAX expands it densely, which the CPU tests can afford.
+``e``. On a TPU the product, its input gradient and its weight gradient
+run on the grouped-matmul kernels JAX ships
+(``ops.pallas_kernels.grouped_matmul``), whose grid visits only the row
+tiles that hold routed rows, so that their time follows the rows that
+are there and not ``num_experts x rows`` nor the buffer. A call the
+kernels cannot tile (``in`` or ``out`` not a multiple of 128, rows not
+a whole number of row tiles) and every call off the TPU take
+``jax.lax.ragged_dot``: XLA's own grouped kernel on a TPU, a dense
+expansion elsewhere, which the CPU tests can afford. Each traced call
+counts its route: ``kfac/moe/grouped_pallas`` or ``kfac/moe/grouped_xla``
+(``observability.tracing``).
 
 **Rows past ``sum(group_sizes)`` are undefined**, in the output and, in
 the backward pass, in the input's gradient: the caller's buffer is sized
 for the worst routing, so most steps leave a long tail, and the kernel
 neither reads nor writes it (on the chip the tail of a fresh output
-holds whatever the memory held, NaN included). Whoever moves rows
-between tokens and this layout (``dispatch_rows`` / ``combine_rows``)
-looks at the valid rows only; everything in between is row-wise.
+holds whatever the memory held, NaN included), nor lets it reach the
+weight gradient. Whoever moves rows between tokens and this layout
+(``dispatch_rows`` / ``combine_rows``) looks at the valid rows only;
+everything in between is row-wise.
 
 The K-FAC registry (``capture.py``) sees this module as layer kind
 ``EXPERTS``: per expert an ``A`` and a ``G`` factor, contracted over the
@@ -30,6 +38,9 @@ from typing import Any, Callable
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from distributed_kfac_pytorch_tpu.observability import tracing
+from distributed_kfac_pytorch_tpu.ops import pallas_kernels
 
 #: Rows a ``lax.cond`` of :func:`dispatch_rows` / :func:`combine_rows`
 #: covers: the part of the buffer a step pays for is the routed rows
@@ -58,8 +69,13 @@ class ExpertsDense(nn.Module):
                             self.param_dtype)
         dtype = self.dtype or x.dtype
         with jax.named_scope('kfac_model/moe/experts'):
-            return jax.lax.ragged_dot(x.astype(dtype), kernel.astype(dtype),
-                                      group_sizes.astype(jnp.int32))
+            x, kernel = x.astype(dtype), kernel.astype(dtype)
+            group_sizes = group_sizes.astype(jnp.int32)
+            if pallas_kernels.grouped_matmul_applies(x, kernel):
+                tracing.count('kfac/moe/grouped_pallas')
+                return pallas_kernels.grouped_matmul(x, kernel, group_sizes)
+            tracing.count('kfac/moe/grouped_xla')
+            return jax.lax.ragged_dot(x, kernel, group_sizes)
 
 
 def _row_chunks(rows: int):

@@ -874,3 +874,144 @@ def splash_attention_applies(q, k, v, kvalid=None) -> bool:
     if jax.default_backend() != 'tpu' and not _tpu_interpret_forced():
         return False
     return _splash_attention_probed()
+
+
+# ---------------------------------------------------------------------------
+# Grouped matmul of stacked experts
+# ---------------------------------------------------------------------------
+#
+# ``modules.experts.ExpertsDense`` multiplies each of its rows, sorted by
+# expert, by its expert's weight. On a TPU the product, its input
+# gradient and its weight gradient run on the grouped-matmul kernels JAX
+# ships (jax.experimental.pallas.ops.tpu.megablox: ``gmm``, ``tgmm``),
+# whose grid is built from ``group_sizes``: only the row tiles that hold
+# routed rows are visited, and the rows past the last group are neither
+# read nor written. bf16 or fp32 operands, fp32 accumulation, the output
+# in the operands' dtype: the arithmetic of ``jax.lax.ragged_dot``.
+
+#: Rows a grid step of the grouped kernels. A group that starts inside a
+#: tile costs that tile twice, so short tiles waste less where experts
+#: get a few hundred rows each, and long ones run faster where the
+#: buffer is full. On a v5e (``benchmarks/grouped_experts_bench.py``),
+#: kernel alone at 8 experts of 390-520 rows every row tile reads within
+#: 20 % of the best tiling found for each product; with the buffer full
+#: 256 is within 12 % of 512 and ahead of 128.
+GROUPED_ROW_TILE = 256
+#: Fast memory a grouped kernel's tiles may take by
+#: :func:`_grouped_tile_bytes` (the compiler's scoped limit on a v5e is
+#: 16 MiB; the count leaves out the kernel's own scratch).
+GROUPED_TILE_BUDGET = 13 * 2 ** 20
+
+
+def _grouped_tile_bytes(tm: int, tk: int, tn: int, itemsize: int,
+                        weight_grad: bool) -> int:
+    """Fast memory of one grid step: the two operand tiles and the
+    output tile double-buffered, the fp32 accumulator. ``gmm`` holds a
+    ``(tm, tk)`` row tile, a ``(tk, tn)`` weight tile and a ``(tm, tn)``
+    output; ``tgmm`` (``weight_grad``) holds ``(tm, tk)`` and
+    ``(tm, tn)`` row tiles and a ``(tk, tn)`` output."""
+    out = tk * tn if weight_grad else tm * tn
+    operands = tm * (tk + tn) if weight_grad else tk * (tm + tn)
+    return 2 * itemsize * (operands + out) + 4 * out
+
+
+def lane_divisor_below(d: int) -> int | None:
+    """The largest multiple of the lane width under ``d`` that divides
+    it, or None."""
+    for t in range(d - _LANE, 0, -_LANE):
+        if d % t == 0:
+            return t
+    return None
+
+
+def grouped_tiling(k: int, n: int, itemsize: int = 2, *,
+                   weight_grad: bool = False) -> tuple[int, int, int]:
+    """``(tm, tk, tn)`` for a grouped product that contracts ``k`` into
+    ``n`` (``weight_grad``: the ``tgmm`` that makes an ``(E, k, n)``
+    weight gradient from rows of ``k`` and ``n``). Whole ``k`` and ``n``
+    where the tiles fit :data:`GROUPED_TILE_BUDGET`, so that a group's
+    weight tile is fetched once; else the wider of the two is cut to
+    its next lane-multiple divisor until they fit."""
+    tm, tk, tn = GROUPED_ROW_TILE, k, n
+    while _grouped_tile_bytes(tm, tk, tn, itemsize,
+                              weight_grad) > GROUPED_TILE_BUDGET:
+        cut = lane_divisor_below(max(tk, tn))
+        if cut is None:
+            break
+        tk, tn = (cut, tn) if tk >= tn else (tk, cut)
+    return tm, tk, tn
+
+
+@functools.lru_cache(maxsize=1)
+def _grouped_matmul_allowed() -> bool:
+    """Once per process: the kill switch (recorded)."""
+    if _forced_fallback():
+        record_fallback('grouped_matmul', 'forced by KFAC_PALLAS_FALLBACK')
+        return False
+    return True
+
+
+def grouped_matmul_applies(x, kernel) -> bool:
+    """The gate of :func:`grouped_matmul`, from what the call shows.
+
+    Open for rows ``x`` ``(rows, k)`` and weights ``kernel``
+    ``(E, k, n)`` of one dtype (bfloat16 or float32), ``k`` and ``n``
+    multiples of the lane width and ``rows`` a whole number of
+    :data:`GROUPED_ROW_TILE` tiles, on a TPU or under Pallas's TPU
+    interpret mode. ``KFAC_PALLAS_FALLBACK=1`` closes it (recorded).
+    Anything else keeps ``jax.lax.ragged_dot``."""
+    rows, k = x.shape
+    if not (x.dtype == kernel.dtype
+            and x.dtype in (jnp.bfloat16, jnp.float32)):
+        return False
+    if k % _LANE or kernel.shape[-1] % _LANE or rows % GROUPED_ROW_TILE:
+        return False
+    if jax.default_backend() != 'tpu' and not _tpu_interpret_forced():
+        return False
+    return _grouped_matmul_allowed()
+
+
+@jax.custom_vjp
+def grouped_matmul(x: jax.Array, kernel: jax.Array,
+                   group_sizes: jax.Array) -> jax.Array:
+    """``y[r] = x[r] @ kernel[e]`` for the ``group_sizes[e]`` rows of
+    expert ``e``, the rows sorted by expert: ``jax.lax.ragged_dot`` on
+    the shipped grouped kernels. ``x`` ``(rows, k)``, ``kernel``
+    ``(E, k, n)`` of one dtype, ``group_sizes`` int32 ``(E,)``.
+
+    Rows past ``sum(group_sizes)`` are undefined in ``y`` and in the
+    input gradient; they never reach the weight gradient, whose kernel
+    selects a tile's rows of the group (a NaN there is not multiplied by
+    zero). The input gradient and the weight gradient are one kernel
+    call each, each tiled for its own shape."""
+    return _grouped_forward(x, kernel, group_sizes)
+
+
+def _grouped_forward(x, kernel, group_sizes):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    k, n = kernel.shape[1:]
+    return gmm(x, kernel, group_sizes, x.dtype,
+               grouped_tiling(k, n, x.dtype.itemsize))
+
+
+def _grouped_fwd(x, kernel, group_sizes):
+    return (_grouped_forward(x, kernel, group_sizes),
+            (x, kernel, group_sizes))
+
+
+def _grouped_bwd(residuals, dy):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    x, kernel, group_sizes = residuals
+    k, n = kernel.shape[1:]
+    dx = gmm(dy, kernel, group_sizes, x.dtype,
+             grouped_tiling(n, k, x.dtype.itemsize), transpose_rhs=True)
+    # tgmm takes the rows as (k, rows) and swaps them back before its
+    # kernel: the two transposes fold, the buffer is not copied.
+    dw = tgmm(x.swapaxes(0, 1), dy, group_sizes, kernel.dtype,
+              grouped_tiling(k, n, x.dtype.itemsize, weight_grad=True))
+    return dx, dw, None
+
+
+grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)

@@ -121,3 +121,34 @@ def test_the_splash_route_compiles_at_mellum2s_widths(one_chip, window):
         assert re.search(r'op_name="[^"]*kfac_model/attention/sliding',
                          line), line[:200]
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
+@pytest.mark.parametrize('rows,d,f', [(32768, 2304, 896), (49152, 2048, 768)],
+                         ids=['mellum2', 'kanana2'])
+@pytest.mark.parametrize('matrix', ['gate', 'down'])
+def test_the_grouped_expert_kernels_compile_at_the_moe_cells_widths(
+        one_chip, rows, d, f, matrix):
+    """A stacked-expert product at a MoE cell's buffer and widths (8
+    experts held, bf16), forward, input gradient and weight gradient:
+    Mosaic takes the three grouped kernels at the tiles
+    ``grouped_tiling`` picks, and each carries the caller's
+    ``jax.named_scope`` in its ``op_name`` (``moe_experts_ms`` reads them
+    by it)."""
+    k, n = (d, f) if matrix == 'gate' else (f, d)
+    x = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((8, k, n), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+
+    def loss(x, w, sizes):
+        with jax.named_scope('kfac_model/moe/experts'):
+            y = pallas_kernels.grouped_matmul(x, w, sizes)
+        return jnp.sum(y.astype(jnp.float32) ** 2)
+
+    compiled = _compiled(jax.value_and_grad(loss, (0, 1)), None, x, w,
+                         sizes)
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    for line in calls:
+        assert re.search(r'op_name="[^"]*kfac_model/moe/experts',
+                         line), line[:200]
