@@ -17,6 +17,10 @@
 - ``window_moe_lm``: Mellum 2-shaped decoder LM — sliding-window and
   full attention layers mixed, grouped key/value heads, YaRN RoPE on the
   full layers, softmax-routed stacked experts with no shared one.
+- ``block_diffusion_lm``: SDAR-shaped decoder LM trained by diffusion
+  over blocks — a noised and a clean copy of each sequence side by
+  side under a block-diffusion mask, grouped key/value heads with q/k
+  RMSNorm, softmax-routed stacked experts, the loss on masked tokens.
 - ``mobilenet``: MobileNetV1 — the depthwise workload the reference
   cannot precondition (no grouped-conv layer kind there); exercises
   this framework's ``conv2d_grouped`` path end to end.
@@ -25,6 +29,7 @@
   reference has no working analogue of.
 """
 
+from distributed_kfac_pytorch_tpu.models import block_diffusion_lm
 from distributed_kfac_pytorch_tpu.models import cifar_resnet
 from distributed_kfac_pytorch_tpu.models import imagenet_resnet
 from distributed_kfac_pytorch_tpu.models import looped_lm

@@ -93,13 +93,19 @@ def yarn_inv_freq(head_dim: int, theta: float, factor: float,
 
 class Attention(nn.Module):
     """Causal attention over the whole past or a window of it, grouped
-    key/value heads, half-split RoPE (YaRN's on full layers)."""
+    key/value heads, half-split RoPE (YaRN's on full layers). ``qk_norm``
+    adds a per-head RMSNorm over ``head_dim`` to q and to k before RoPE
+    (``q_norm``, ``k_norm``: Qwen3's); ``diffusion_block`` puts the
+    block-diffusion mask of blocks of that many positions in the causal
+    rule's place (``models/block_diffusion_lm.py``)."""
     num_heads: int
     num_kv_heads: int
     head_dim: int
     window: int | None
     rope_theta: float
     yarn: dict | None = None
+    qk_norm: bool = False
+    diffusion_block: int | None = None
     dtype: Any = None
 
     @nn.compact
@@ -112,6 +118,9 @@ class Attention(nn.Module):
         q = q.reshape(b, t, self.num_heads, hd)
         k = k.reshape(b, t, self.num_kv_heads, hd)
         v = v.reshape(b, t, self.num_kv_heads, hd)
+        if self.qk_norm:
+            q = RMSNorm(dtype=self.dtype, name='q_norm')(q)
+            k = RMSNorm(dtype=self.dtype, name='k_norm')(k)
         rope = {}
         if self.yarn is not None:
             rope = dict(inv_freq=yarn_inv_freq(
@@ -121,10 +130,15 @@ class Attention(nn.Module):
                 scale=self.yarn['attention_factor'])
         q = rope_half(q, pos, self.rope_theta, **rope)
         k = rope_half(k, pos, self.rope_theta, **rope)
-        kind = 'full' if self.window is None else 'sliding'
-        with jax.named_scope(f'kfac_model/attention/{kind}'):
-            o = local_causal_attention(q, k, v, causal=True,
-                                       window=self.window)
+        if self.diffusion_block is not None:
+            with jax.named_scope('kfac_model/attention/block_diffusion'):
+                o = local_causal_attention(
+                    q, k, v, diffusion_block=self.diffusion_block)
+        else:
+            kind = 'full' if self.window is None else 'sliding'
+            with jax.named_scope(f'kfac_model/attention/{kind}'):
+                o = local_causal_attention(q, k, v, causal=True,
+                                           window=self.window)
         return dense(d_model, self.dtype, 'o_proj')(
             o.reshape(b, t, self.num_heads * hd).astype(x.dtype))
 

@@ -792,10 +792,87 @@ def _splash_kernels():
     return module
 
 
+# A block-diffusion step lays a sequence's noised copy (positions 0 to
+# L - 1) and its clean copy (L to 2L - 1) side by side and attends over
+# both under one mask of blocks of B positions (BD3-LM's vectorised
+# training): a noised query reads its own noised block both ways and
+# every clean block strictly before its own; a clean query reads the
+# clean blocks up to and including its own, and nothing noised.
+
+
+def block_diffusion_keep(q_ids, kv_ids, block: int, length: int):
+    """Whether query ``q_ids`` reads key ``kv_ids`` under the block-
+    diffusion mask of blocks of ``block`` over two streams of
+    ``length`` (numpy or jax integer arrays, broadcast together). Only
+    comparisons, ``//`` by a constant, ``+`` and the logical operators:
+    the splash kernels evaluate it on their tiles."""
+    qb, kb = q_ids // block, kv_ids // block
+    q_noised, k_noised = q_ids < length, kv_ids < length
+    return ((q_noised & ((k_noised & (qb == kb))
+                         | (~k_noised & (kb < qb + length // block))))
+            | (~q_noised & ~k_noised & (kb <= qb)))
+
+
+def block_diffusion_length(t: int, block: int) -> int:
+    """The length L of each stream where a block-diffusion step lays two
+    streams of whole blocks of ``block`` side by side in ``t`` = 2L
+    positions; any other ``t`` raises."""
+    if t % (2 * block):
+        raise ValueError(f'a block-diffusion mask lays two streams of '
+                         f'whole blocks of {block} side by side: {t=}')
+    return t // 2
+
+
 @functools.lru_cache(maxsize=None)
-def _splash_kernel(heads: int, t: int, window: int | None):
-    """The kernel for ``heads`` q heads over ``t`` positions, causal or
-    in a causal window of ``window`` keys, in tiles of 512 where they
+def _block_diffusion_mask(t: int, block: int):
+    """Splash's computable mask of :func:`block_diffusion_keep` over
+    ``t`` positions, two streams of ``t / 2``: the kernel computes it
+    on a tile and never loads an array of it."""
+    length = block_diffusion_length(t, block)
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_mask as masks,
+    )
+
+    class BlockDiffusionMask(masks._ComputableMask):
+        def __init__(self):
+            super().__init__(shape=(t, t), mask_function=functools.partial(
+                block_diffusion_keep, block=block, length=length))
+
+        def __eq__(self, other):
+            return type(other) is type(self)
+
+        def __hash__(self):
+            return hash((t, block))
+
+    return BlockDiffusionMask()
+
+
+def _splash_tile(t: int) -> int:
+    return 512 if t % 512 == 0 else ATTENTION_MIN_BLOCK
+
+
+@functools.lru_cache(maxsize=None)
+def splash_tile_fill(t: int, block: int) -> tuple[int, int]:
+    """``(kept, visited)`` (query, key) pairs of one head under the
+    block-diffusion mask: the pairs it keeps, and the pairs of the tiles
+    the splash kernel visits at its tile size (every tile that keeps a
+    pair; the empty ones are skipped)."""
+    mask, s = _block_diffusion_mask(t, block), _splash_tile(t)
+    kept = visited = 0
+    for lo in range(0, t, s):
+        for col in range(0, t, s):
+            n = int(mask[lo:lo + s, col:col + s].sum())
+            kept += n
+            visited += s * s if n else 0
+    return kept, visited
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(heads: int, t: int, window: int | None,
+                   diffusion_block: int | None = None):
+    """The kernel for ``heads`` q heads over ``t`` positions, causal, in
+    a causal window of ``window`` keys, or under the block-diffusion
+    mask of blocks of ``diffusion_block``, in tiles of 512 where they
     divide ``t`` (else 256) in all three of its passes. Its tables of
     which tiles to visit are made once, as concrete arrays (under no
     trace, so that the cached kernel serves every later one)."""
@@ -804,12 +881,14 @@ def _splash_kernel(heads: int, t: int, window: int | None):
     )
 
     kernels = _splash_kernels()
-    if window is None:
+    if diffusion_block is not None:
+        mask = _block_diffusion_mask(t, diffusion_block)
+    elif window is None:
         mask = masks.CausalMask((t, t))
     else:
         mask = masks.LocalMask((t, t), window_size=(window - 1, 0),
                                offset=0)
-    s = 512 if t % 512 == 0 else ATTENTION_MIN_BLOCK
+    s = _splash_tile(t)
     sizes = kernels.BlockSizes(block_q=s, block_kv=s, block_kv_compute=s,
                                block_q_dkv=s, block_kv_dkv=s,
                                block_kv_dkv_compute=s, block_q_dq=s,
@@ -821,19 +900,21 @@ def _splash_kernel(heads: int, t: int, window: int | None):
 
 
 def splash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                     causal: bool = True,
-                     window: int | None = None) -> jax.Array:
-    """Exact causal softmax attention in the shipped splash kernels,
-    over the whole past (``window`` None) or the last ``window`` keys.
+                     causal: bool = True, window: int | None = None,
+                     diffusion_block: int | None = None) -> jax.Array:
+    """Exact softmax attention in the shipped splash kernels: causal
+    over the whole past (``window`` None) or the last ``window`` keys,
+    or, in place of the causal rule, under the block-diffusion mask of
+    blocks of ``diffusion_block`` (:func:`block_diffusion_keep`).
 
     ``q``: (B, T, H, D); ``k``/``v``: (B, T, H_kv, D) with H a multiple
     of H_kv, all of one dtype; returns (B, T, H, D) float32 like the
     plain path, rounded once to the input dtype by the kernel.
     Differentiable; no (T, T) tensor reaches HBM in either direction."""
-    if not causal:
-        raise ValueError('the splash route is causal only')
+    if not causal and diffusion_block is None:
+        raise ValueError('the splash route is causal or block-diffusion')
     b, t, heads, d = q.shape
-    kernel = _splash_kernel(heads, t, window)
+    kernel = _splash_kernel(heads, t, window, diffusion_block)
     q = (q.astype(jnp.float32) * (1.0 / d ** 0.5)).astype(k.dtype)
     heads_first = lambda x: jnp.swapaxes(x, 1, 2)  # noqa: E731
     o = jax.vmap(kernel)(heads_first(q), heads_first(k), heads_first(v))
@@ -850,7 +931,19 @@ def _splash_attention_probed() -> bool:
                             window=512)
 
 
-def splash_attention_applies(q, k, v, kvalid=None) -> bool:
+@functools.lru_cache(maxsize=1)
+def _splash_block_diffusion_probed() -> bool:
+    """Once per process: the kill switch (recorded), else the probe of
+    the block-diffusion mask."""
+    # Two 512-tiles a side: the noised-noised and the clean-noised
+    # tiles, one skipped, the two others kept in part.
+    return _attention_probe('splash_block_diffusion', splash_attention,
+                            (1, 1024, 4, 128), (1, 1024, 2, 128),
+                            diffusion_block=4)
+
+
+def splash_attention_applies(q, k, v, kvalid=None,
+                             diffusion_block=None) -> bool:
     """The gate of :func:`splash_attention`, from what the call shows.
 
     Open for self-attention without a key mask, k and v of one shape,
@@ -860,7 +953,8 @@ def splash_attention_applies(q, k, v, kvalid=None) -> bool:
     :data:`ATTENTION_MIN_BLOCK` tiles; on a TPU, where the route's own
     once-per-process probe must pass or raise, or under Pallas's TPU
     interpret mode. ``KFAC_PALLAS_FALLBACK=1`` closes it as it closes
-    :func:`fused_attention_applies`."""
+    :func:`fused_attention_applies`. A call with a ``diffusion_block``
+    asks the block-diffusion mask's own probe."""
     b, t, heads, d = q.shape
     if kvalid is not None or k.shape != v.shape or heads % k.shape[2]:
         return False
@@ -873,6 +967,8 @@ def splash_attention_applies(q, k, v, kvalid=None) -> bool:
         return False
     if jax.default_backend() != 'tpu' and not _tpu_interpret_forced():
         return False
+    if diffusion_block is not None:
+        return _splash_block_diffusion_probed()
     return _splash_attention_probed()
 
 

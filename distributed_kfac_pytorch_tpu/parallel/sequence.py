@@ -44,15 +44,17 @@ _NEG_INF = -1e30
 
 
 def _block_attend(q, k, v, scale, qpos, kpos, causal, kvalid=None,
-                  window=None):
+                  window=None, diffusion=None):
     """One blockwise attention contribution with positions for masking.
 
     q: (B, Tq, H, D), k/v: (B, Tk, H, D); qpos/kpos: (Tq,)/(Tk,) global
     token positions. ``kvalid`` (optional, (Tk,) bool) masks out padding
     keys — the chunked path pads ragged sequences up to a block
     multiple. ``window`` (causal only) keeps the keys ``qpos - window <
-    kpos``. Returns (scores_max, exp_scores @ v, exp_scores sum)
-    per (B, H, Tq).
+    kpos``. ``diffusion`` = (block, length) takes the causal rule's
+    place with the block-diffusion mask
+    (``ops.pallas_kernels.block_diffusion_keep``). Returns (scores_max,
+    exp_scores @ v, exp_scores sum) per (B, H, Tq).
 
     Operands enter the QK^T einsum at their INPUT dtype with fp32
     accumulation (``preferred_element_type``) — the native MXU contract
@@ -68,7 +70,10 @@ def _block_attend(q, k, v, scale, qpos, kpos, causal, kvalid=None,
     logits = jnp.einsum('bqhd,bkhd->bhqk', q, k,
                         preferred_element_type=jnp.float32) * scale
     mask = None
-    if causal:
+    if diffusion is not None:
+        mask = pallas_kernels.block_diffusion_keep(
+            qpos[:, None], kpos[None, :], *diffusion)
+    elif causal:
         mask = kpos[None, :] <= qpos[:, None]          # (Tq, Tk)
         if window is not None:
             mask &= kpos[None, :] > qpos[:, None] - window
@@ -160,18 +165,24 @@ def ring_self_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def plain_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True,
                     kvalid: jax.Array | None = None,
-                    window: int | None = None) -> jax.Array:
+                    window: int | None = None,
+                    diffusion_block: int | None = None) -> jax.Array:
     """Monolithic attention: one ``_block_attend`` over the whole
     sequence, (B, H, T, T) float32 scores and probabilities in HBM.
     The path every backend can take, and the fused kernels' reference.
     k/v with fewer heads than q are repeated to q's heads first (q head
-    ``j`` reads kv head ``j // (H / H_kv)``)."""
+    ``j`` reads kv head ``j // (H / H_kv)``). ``diffusion_block`` as
+    :func:`local_causal_attention` takes it."""
     b, t, h, d = q.shape
     if k.shape[2] != h:
         k, v = (jnp.repeat(x, h // k.shape[2], axis=2) for x in (k, v))
     pos = jnp.arange(t)
+    diffusion = (None if diffusion_block is None else
+                 (diffusion_block, pallas_kernels.block_diffusion_length(
+                     t, diffusion_block)))
     m, o, l = _block_attend(q, k, v, 1.0 / (d ** 0.5), pos, pos, causal,
-                            kvalid=kvalid, window=window)
+                            kvalid=kvalid, window=window,
+                            diffusion=diffusion)
     l = jnp.maximum(jnp.moveaxis(l, 1, 2)[..., None], 1e-30)
     return o / l
 
@@ -179,13 +190,19 @@ def plain_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 def local_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                            *, causal: bool = True,
                            kvalid: jax.Array | None = None,
-                           window: int | None = None) -> jax.Array:
+                           window: int | None = None,
+                           diffusion_block: int | None = None
+                           ) -> jax.Array:
     """Single-device attention with the same contract as the ring path:
     (B, T, H, D) in, exact softmax attention out, (B, T, H, D) fp32.
     ``kvalid`` ((T,) bool) masks padding keys as in ``_block_attend``.
     ``window`` (causal only): query ``i`` reads keys ``i - window < j
     <= i``. k/v may have fewer heads than q, ``H_kv`` dividing ``H``:
-    q head ``j`` reads kv head ``j // (H / H_kv)``.
+    q head ``j`` reads kv head ``j // (H / H_kv)``. ``diffusion_block``
+    = B puts the block-diffusion mask in the causal rule's place: the
+    T = 2L positions are a noised stream (0..L-1) and a clean one
+    (L..2L-1) of blocks of B, and the mask is
+    ``ops.pallas_kernels.block_diffusion_keep``'s.
 
     The call itself decides between forms of the same fold. A call with
     neither a window nor grouped heads takes the fused TPU kernel
@@ -194,9 +211,10 @@ def local_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (bf16 or fp32), the head dim is one the kernel takes (64, 128) and
     T is a whole number of 256-row tiles. A call with a window or
     grouped heads takes the splash kernels under the same conditions
-    (``splash_attention_applies``, its own probe). Anything else takes
-    the plain path (``plain_attention``) — a ViT's T = 197, any CPU run
-    — unchanged. ``KFAC_PALLAS_FALLBACK=1`` forces the plain path and
+    (``splash_attention_applies``, its own probe), and so does a call
+    with a ``diffusion_block`` (that mask's own probe). Anything
+    else takes the plain path (``plain_attention``) — a ViT's T = 197,
+    any CPU run — unchanged. ``KFAC_PALLAS_FALLBACK=1`` forces the plain path and
     records a ``pallas_fallback`` event.
 
     The fused paths' contract: exact attention at the same precisions
@@ -214,7 +232,12 @@ def local_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (``observability.tracing``): ``kfac/attention/fused`` (either
     kernel) or ``kfac/attention/plain``, and besides
     ``kfac/attention/windowed`` where it has a window and
-    ``kfac/attention/grouped`` where k/v have fewer heads than q.
+    ``kfac/attention/grouped`` where k/v have fewer heads than q, and
+    ``kfac/attention/block_diffusion`` where it has a
+    ``diffusion_block``. A block-diffusion call on the splash route adds
+    to ``kfac/attention/tile_fill/kept_pairs`` the (query, key, q head)
+    pairs its mask keeps, and to ``.../visited_pairs`` the pairs of the
+    tiles the kernel visits (``pallas_kernels.splash_tile_fill``).
     """
     if window is not None and not causal:
         raise ValueError('a window is causal: keys i - window < j <= i')
@@ -223,16 +246,30 @@ def local_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         tracing.count('kfac/attention/windowed')
     if grouped:
         tracing.count('kfac/attention/grouped')
-    if window is not None or grouped:
-        if pallas_kernels.splash_attention_applies(q, k, v, kvalid):
+    if diffusion_block is not None:
+        if window is not None:
+            raise ValueError('a block-diffusion mask has no window')
+        tracing.count('kfac/attention/block_diffusion')
+    if window is not None or grouped or diffusion_block is not None:
+        if pallas_kernels.splash_attention_applies(
+                q, k, v, kvalid, diffusion_block=diffusion_block):
             tracing.count('kfac/attention/fused')
-            return pallas_kernels.splash_attention(q, k, v, window=window)
+            if diffusion_block is not None:
+                kept, visited = pallas_kernels.splash_tile_fill(
+                    q.shape[1], diffusion_block)
+                rows = q.shape[0] * q.shape[2]
+                tracing.count('kfac/attention/tile_fill/kept_pairs',
+                              kept * rows)
+                tracing.count('kfac/attention/tile_fill/visited_pairs',
+                              visited * rows)
+            return pallas_kernels.splash_attention(
+                q, k, v, window=window, diffusion_block=diffusion_block)
     elif pallas_kernels.fused_attention_applies(q, k, v, kvalid):
         tracing.count('kfac/attention/fused')
         return pallas_kernels.fused_attention(q, k, v, causal=causal)
     tracing.count('kfac/attention/plain')
     return plain_attention(q, k, v, causal=causal, kvalid=kvalid,
-                           window=window)
+                           window=window, diffusion_block=diffusion_block)
 
 
 def chunked_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,

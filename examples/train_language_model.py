@@ -2,7 +2,7 @@
 
 Working TPU-native counterpart of the reference's WIP LM entry point
 (examples/torch_language_model.py — broken as shipped: SURVEY.md §8 notes
-the lr and factory-unpacking bugs at :253,:277). Five architectures:
+the lr and factory-unpacking bugs at :253,:277). Six architectures:
 
 - ``--arch lstm``: the K-FAC-friendly LSTM LM (reference rnn_utils/lstm.py
   + kfac/modules/lstm.py), BPTT windows (``--bptt 35``,
@@ -33,6 +33,23 @@ the lr and factory-unpacking bugs at :253,:277). Five architectures:
   stacked experts, untied head) at ``--window-moe-size``; K-FAC on every
   projection, the router and every expert matrix, the head left to SGD
   by default.
+- ``--arch block_diffusion_moe``: the SDAR-shaped decoder of
+  ``models/block_diffusion_lm.py`` at ``--bd-size``, trained by
+  diffusion over blocks of ``--bd-block`` tokens (BD3-LM's vectorised
+  objective): each window ``x_0`` gets a noise level ``t ~ U[0.3, 0.8]``
+  a (row, block), each position of the block masked with probability
+  ``t`` (the mask token is one id past the corpus's vocabulary); the
+  noised copy ``x_t`` and ``x_0`` run side by side as ``2 x --bptt``
+  positions under the block-diffusion mask (a noised query reads its
+  own noised block and the clean blocks before it, a clean query the
+  clean blocks up to its own), grouped key/value heads with a per-head
+  q/k RMSNorm and half-split RoPE at the same positions in both streams,
+  softmax-routed stacked experts over both streams, and the loss
+  ``sum over masked positions of CE(x_0) / t`` over the window's tokens
+  from the head over the noised stream; K-FAC on every projection, the
+  router and every expert matrix over both streams, the head left to
+  SGD by default. ``--seq-parallel`` is refused: the ring and chunked
+  paths have no such mask.
 
 Data: whitespace-tokenized train.txt/valid.txt under --data-dir
 (PTB/WikiText layout), else a synthetic Markov corpus (offline default).
@@ -63,6 +80,7 @@ from distributed_kfac_pytorch_tpu import observability as obs
 from distributed_kfac_pytorch_tpu import resilience as resil
 from distributed_kfac_pytorch_tpu import multislice
 from distributed_kfac_pytorch_tpu.models import (
+    block_diffusion_lm,
     looped_lm,
     lstm_lm,
     mla_moe_lm,
@@ -94,7 +112,7 @@ def parse_args(argv=None):
     p.add_argument('--checkpoint-freq', type=int, default=5)
     p.add_argument('--arch', default='lstm',
                    choices=['lstm', 'transformer', 'mla_moe', 'looped',
-                            'window_moe'])
+                            'window_moe', 'block_diffusion_moe'])
     p.add_argument('--mla-moe-size', default='tiny',
                    choices=['tiny', 'kanana2'],
                    help="--arch mla_moe: mla_moe_lm.get_model's named "
@@ -111,6 +129,14 @@ def parse_args(argv=None):
                         "named shape ('mellum2': Mellum2-12B-A2.5B as "
                         "published, 28 layers and 64 experts; more than "
                         "one 16 GB chip holds)")
+    p.add_argument('--bd-size', default='tiny', choices=['tiny', 'sdar30'],
+                   help="--arch block_diffusion_moe: block_diffusion_lm."
+                        "get_model's named shape ('sdar30': "
+                        "SDAR-30B-A3B-Chat as published, 48 layers and "
+                        "128 experts; more than one 16 GB chip holds)")
+    p.add_argument('--bd-block', type=int, default=4,
+                   help='--arch block_diffusion_moe: tokens a diffusion '
+                        'block (divides --bptt)')
     # Model size (reference torch_language_model.py:41-50).
     p.add_argument('--emsize', type=int, default=650)
     p.add_argument('--nhid', type=int, default=650)
@@ -282,6 +308,11 @@ def build_model(args, vocab_size, seq_axis=None, dtype=None):
     if args.arch == 'window_moe':
         return window_moe_lm.get_model(vocab_size, args.window_moe_size,
                                        dtype=dtype)
+    if args.arch == 'block_diffusion_moe':
+        # One row past the corpus's vocabulary: the mask token.
+        return block_diffusion_lm.get_model(
+            vocab_size + 1, args.bd_size, block_size=args.bd_block,
+            dtype=dtype)
     return transformer_lm.TransformerLM(
         vocab_size=vocab_size, d_model=args.emsize,
         num_layers=args.nlayers, num_heads=args.nheads,
@@ -303,6 +334,14 @@ def main(argv=None):
     is_main = info['process_index'] == 0
     n_dev = jax.device_count()
     sp = args.seq_parallel
+    diffusion = args.arch == 'block_diffusion_moe'
+    if sp > 1 and diffusion:
+        raise SystemExit('--seq-parallel cannot run --arch '
+                         'block_diffusion_moe: the ring and chunked '
+                         'attention paths have no block-diffusion mask')
+    if diffusion and args.bptt % args.bd_block:
+        raise SystemExit(f'--bptt {args.bptt} must be a whole number of '
+                         f'--bd-block {args.bd_block} blocks')
     if sp > 1 and args.arch != 'transformer':
         raise SystemExit('--seq-parallel requires --arch transformer')
     if args.attn_block_size:
@@ -333,7 +372,9 @@ def main(argv=None):
         args.skip_layers = {'lstm': ['embed', 'decoder'],
                             'mla_moe': ['head'],
                             'looped': ['head'],
-                            'window_moe': ['head']}.get(args.arch, [])
+                            'window_moe': ['head'],
+                            'block_diffusion_moe': ['head']}.get(
+                                args.arch, [])
 
     seq_axis = seq.SEQ_AXIS if sp > 1 else None
     model = build_model(args, vocab_size, seq_axis=seq_axis)
@@ -456,9 +497,9 @@ def main(argv=None):
         return out[0] if args.arch == 'lstm' else out
 
     def loss_fn(out, batch):
-        if args.arch == 'looped':
-            # Handed the targets, the looped decoder returns its own
-            # objective a token (it needs the head at every exit).
+        if args.arch == 'looped' or diffusion:
+            # Handed the targets (the weights), the looped (the
+            # block-diffusion) decoder returns its own objective a token.
             return out.mean()
         return optax.softmax_cross_entropy_with_integer_labels(
             logits_of(out), batch[1]).mean()
@@ -475,7 +516,7 @@ def main(argv=None):
         for ax in data_axes[1:]:
             idx = idx * jax.lax.psum(1, ax) + jax.lax.axis_index(ax)
         kwargs = {'train': True,
-                  'rngs': {'dropout': jax.random.fold_in(batch[2], idx)}}
+                  'rngs': {'dropout': jax.random.fold_in(batch[-1], idx)}}
         if seq_axis:
             kwargs['pos_offset'] = (
                 jax.lax.axis_index(seq.SEQ_AXIS) * t_local)
@@ -486,26 +527,43 @@ def main(argv=None):
     batch_axes = multislice.batch_axes(mesh)
     data_spec = (P(batch_axes, seq.SEQ_AXIS) if seq_axis
                  else P(batch_axes))
+    # A block-diffusion batch is (x_t, x_0, weights, key); any other
+    # (ids, targets, key).
+    train_spec = ((data_spec,) * (3 if diffusion else 2)) + (P(),)
+    model_args_fn = (lambda b: b[:3]) if diffusion else None
     if dkfac is not None:
         step_fn = dkfac.build_train_step(
-            loss_fn, tx, model_kwargs_fn=model_kwargs_fn,
-            batch_spec=(data_spec, data_spec, P()),
+            loss_fn, tx, model_args_fn=model_args_fn,
+            model_kwargs_fn=model_kwargs_fn, batch_spec=train_spec,
             loss_scale='dynamic' if args.fp16 else None)
     else:  # --kfac-update-freq 0: plain SGD (reference optimizers.py:28)
         step_fn = engine.build_sgd_train_step(
-            model, loss_fn, tx, mesh,
-            model_kwargs_fn=model_kwargs_fn,
-            batch_spec=(data_spec, data_spec, P()),
+            model, loss_fn, tx, mesh, model_args_fn=model_args_fn,
+            model_kwargs_fn=model_kwargs_fn, batch_spec=train_spec,
             metrics_fn=lambda out, b: {})
 
     def eval_loss(out, batch):
+        if diffusion:
+            return out.mean()
         return optax.softmax_cross_entropy_with_integer_labels(
             logits_of(out), batch[1]).mean()
 
     eval_step = engine.make_eval_step(
         build_model(args, vocab_size, seq_axis=None), eval_loss, None,
-        model_args_fn=lambda b: (b[0],), model_kwargs={'train': False},
-        metrics_fn=lambda o, b: {})
+        model_args_fn=model_args_fn or (lambda b: (b[0],)),
+        model_kwargs={'train': False}, metrics_fn=lambda o, b: {})
+
+    def windows(pairs, rng_of):
+        """``pairs`` of ``(ids, targets)`` as the step takes them: a
+        block-diffusion batch is ``(x_t, x_0, weights)`` of the window
+        ``x_0``, its noise drawn from ``rng_of(i)`` for window ``i``."""
+        for i, (x, y) in pairs:
+            if diffusion:
+                x_t, weights = block_diffusion_lm.noise(
+                    rng_of(i), x, args.bd_block, vocab_size)
+                yield i, (x_t, x, weights)
+            else:
+                yield i, (x, y)
     # Straggler barrier probe: shards requested (or the cadence-backoff
     # policy armed) + a K-FAC step (the probe reduces over the K-FAC
     # data axes).
@@ -586,11 +644,12 @@ def main(argv=None):
         # keys fold the ABSOLUTE window index so the replayed tail is
         # bit-identical to the uninterrupted epoch's.
         root = jax.random.PRNGKey(args.seed * 1000 + epoch)
-        for i, (x, y) in enumerate(datasets.bptt_batches(
+        for i, batch in windows(enumerate(datasets.bptt_batches(
                 train_ids, args.batch_size, args.bptt,
                 shuffle_offset=True, seed=args.seed, epoch=epoch,
-                skip_batches=skip), start=skip):
-            yield x, y, jax.random.fold_in(root, i)
+                skip_batches=skip), start=skip),
+                lambda i: np.random.default_rng((args.seed, epoch, i))):
+            yield *batch, jax.random.fold_in(root, i)
 
     writer = engine.TensorBoardWriter(args.log_dir) if is_main else None
     t_start = time.perf_counter()
@@ -614,8 +673,7 @@ def main(argv=None):
                     train_m = engine.train_epoch(
                         step_fn, state,
                         launch.global_batches(
-                            mesh, raw,
-                            batch_spec=(data_spec, data_spec, P())),
+                            mesh, raw, batch_spec=train_spec),
                         hyper, log_writer=writer, verbose=is_main,
                         metrics_sink=metrics_sink,
                         checkpointer=step_ckpt,
@@ -648,9 +706,11 @@ def main(argv=None):
                 eval_step, state,
                 launch.global_batches(
                     mesh,
-                    datasets.bptt_batches(val_ids, args.batch_size,
-                                          args.bptt),
-                    batch_spec=(data_spec, data_spec)),
+                    (b for _, b in windows(
+                        enumerate(datasets.bptt_batches(
+                            val_ids, args.batch_size, args.bptt)),
+                        lambda i: np.random.default_rng((args.seed, i)))),
+                    batch_spec=train_spec[:-1]),
                 log_writer=writer, verbose=is_main)
             if is_main and 'loss' in train_m:
                 print(f'epoch {epoch}: train ppl '
