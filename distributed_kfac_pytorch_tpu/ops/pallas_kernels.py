@@ -803,14 +803,38 @@ def _splash_kernels():
 def block_diffusion_keep(q_ids, kv_ids, block: int, length: int):
     """Whether query ``q_ids`` reads key ``kv_ids`` under the block-
     diffusion mask of blocks of ``block`` over two streams of
-    ``length`` (numpy or jax integer arrays, broadcast together). Only
-    comparisons, ``//`` by a constant, ``+`` and the logical operators:
-    the splash kernels evaluate it on their tiles."""
+    ``length`` (numpy or jax integer arrays, broadcast together): the
+    rule's definition, which the plain path and the tile map read. The
+    splash kernels evaluate :func:`block_diffusion_keep_shifted` where
+    the block is a power of two, this one (two ``//`` an element) where
+    it is not."""
     qb, kb = q_ids // block, kv_ids // block
     q_noised, k_noised = q_ids < length, kv_ids < length
     return ((q_noised & ((k_noised & (qb == kb))
                          | (~k_noised & (kb < qb + length // block))))
             | (~q_noised & ~k_noised & (kb <= qb)))
+
+
+def block_diffusion_keep_shifted(q_ids, kv_ids, block: int, length: int):
+    """:func:`block_diffusion_keep` for a power-of-two ``block`` without
+    a division, for ids that are not negative and a ``length`` of whole
+    blocks: ``q & -B`` is q's block start and ``q ^ k < B`` says k lies
+    in q's block. A query keeps its own block and the clean keys from L
+    up to a bound: ``L + start - 1`` for a noised query (the clean
+    blocks before its own), its own block's end for a clean one (whose
+    block the first term then covers too)."""
+    start = q_ids & -block
+    bound = jnp.where(q_ids < length, start + (length - 1),
+                      q_ids | (block - 1))
+    return (((kv_ids >= length) & (kv_ids <= bound))
+            | ((q_ids ^ kv_ids) < block))
+
+
+def block_diffusion_route(block: int) -> str:
+    """How the splash kernels decide a tile's pairs under the mask of
+    blocks of ``block``: ``'shifted'`` (:func:`block_diffusion_keep_shifted`)
+    where it is a power of two, else ``'divided'``."""
+    return 'divided' if block & (block - 1) else 'shifted'
 
 
 def block_diffusion_length(t: int, block: int) -> int:
@@ -825,18 +849,22 @@ def block_diffusion_length(t: int, block: int) -> int:
 
 @functools.lru_cache(maxsize=None)
 def _block_diffusion_mask(t: int, block: int):
-    """Splash's computable mask of :func:`block_diffusion_keep` over
-    ``t`` positions, two streams of ``t / 2``: the kernel computes it
-    on a tile and never loads an array of it."""
+    """Splash's computable mask of the block-diffusion rule over ``t``
+    positions, two streams of ``t / 2``: the kernel computes it on a
+    tile (by :func:`block_diffusion_route`) and never loads an array of
+    it."""
     length = block_diffusion_length(t, block)
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_mask as masks,
     )
+    keep = (block_diffusion_keep_shifted
+            if block_diffusion_route(block) == 'shifted'
+            else block_diffusion_keep)
 
     class BlockDiffusionMask(masks._ComputableMask):
         def __init__(self):
             super().__init__(shape=(t, t), mask_function=functools.partial(
-                block_diffusion_keep, block=block, length=length))
+                keep, block=block, length=length))
 
         def __eq__(self, other):
             return type(other) is type(self)
@@ -856,12 +884,18 @@ def splash_tile_fill(t: int, block: int) -> tuple[int, int]:
     """``(kept, visited)`` (query, key) pairs of one head under the
     block-diffusion mask: the pairs it keeps, and the pairs of the tiles
     the splash kernel visits at its tile size (every tile that keeps a
-    pair; the empty ones are skipped)."""
-    mask, s = _block_diffusion_mask(t, block), _splash_tile(t)
+    pair; the empty ones are skipped), from :func:`block_diffusion_keep`
+    on the host."""
+    import numpy as np
+
+    length, s = block_diffusion_length(t, block), _splash_tile(t)
+    ids = np.arange(t)
     kept = visited = 0
     for lo in range(0, t, s):
         for col in range(0, t, s):
-            n = int(mask[lo:lo + s, col:col + s].sum())
+            n = int(block_diffusion_keep(ids[lo:lo + s, None],
+                                         ids[None, col:col + s], block,
+                                         length).sum())
             kept += n
             visited += s * s if n else 0
     return kept, visited

@@ -234,7 +234,10 @@ def local_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     ``kfac/attention/windowed`` where it has a window and
     ``kfac/attention/grouped`` where k/v have fewer heads than q, and
     ``kfac/attention/block_diffusion`` where it has a
-    ``diffusion_block``. A block-diffusion call on the splash route adds
+    ``diffusion_block``. A block-diffusion call on the splash route
+    counts how its kernels decide a tile's pairs,
+    ``kfac/attention/block_diffusion/mask_shifted`` or ``.../mask_divided``
+    (``ops.pallas_kernels.block_diffusion_route``), and adds
     to ``kfac/attention/tile_fill/kept_pairs`` the (query, key, q head)
     pairs its mask keeps, and to ``.../visited_pairs`` the pairs of the
     tiles the kernel visits (``pallas_kernels.splash_tile_fill``).
@@ -255,6 +258,9 @@ def local_causal_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                 q, k, v, kvalid, diffusion_block=diffusion_block):
             tracing.count('kfac/attention/fused')
             if diffusion_block is not None:
+                tracing.count('kfac/attention/block_diffusion/mask_'
+                              + pallas_kernels.block_diffusion_route(
+                                  diffusion_block))
                 kept, visited = pallas_kernels.splash_tile_fill(
                     q.shape[1], diffusion_block)
                 rows = q.shape[0] * q.shape[2]

@@ -46,6 +46,7 @@ CHECKS = ('loss1_gap', 'loss2_gap', 'loss3_gap', 'grad1_gap',
           'factor_gap', 'factor_median_gap')
 FIXTURES = os.path.join(ROOT, 'tests', 'fixtures')
 DIFFUSION = 'kfac/attention/block_diffusion'
+SHIFTED = 'kfac/attention/block_diffusion/mask_shifted'
 FUSED, PLAIN = 'kfac/attention/fused', 'kfac/attention/plain'
 KEPT = 'kfac/attention/tile_fill/kept_pairs'
 VISITED = 'kfac/attention/tile_fill/visited_pairs'
@@ -90,6 +91,25 @@ def test_the_mask_rule_is_the_brute_force_matrix(length, block):
     # a noised query reads B keys of its block and B b clean ones; a
     # clean query B (b + 1)
     assert want.sum() == family.mask_pairs(length, block)
+
+
+@pytest.mark.parametrize('length,block,route', [
+    (8, 4, 'shifted'), (16, 8, 'shifted'), (32, 16, 'shifted'),
+    (24, 1, 'shifted'), (20, 2, 'shifted'), (12, 3, 'divided')])
+def test_the_kernels_mask_is_the_brute_force_matrix(length, block, route):
+    """What the splash kernels evaluate on a tile: the division-free
+    rule where the block is a power of two, pair by pair, and the
+    rule's own ``//`` form where it is not."""
+    want = brute_mask(length, block)
+    i, j = np.arange(2 * length)[:, None], np.arange(2 * length)[None, :]
+    assert pallas_kernels.block_diffusion_route(block) == route
+    if route == 'shifted':
+        np.testing.assert_array_equal(
+            pallas_kernels.block_diffusion_keep_shifted(
+                jnp.asarray(i), jnp.asarray(j), block, length), want)
+    mask = pallas_kernels._block_diffusion_mask(2 * length, block)
+    np.testing.assert_array_equal(
+        mask.mask_function(jnp.asarray(i), jnp.asarray(j)), want)
 
 
 def test_the_splash_mask_reads_the_rule_tile_by_tile_past_a_ragged_end():
@@ -177,16 +197,18 @@ def _matrix_attention(length, block):
     return attend
 
 
-@pytest.mark.parametrize('dtype,tol', [(jnp.float32, 2e-5),
-                                       (jnp.bfloat16, 2e-2)],
-                         ids=['float32', 'bfloat16'])
-def test_splash_and_plain_are_the_matrix_attention(fresh, dtype, tol):
+@pytest.mark.parametrize('dtype,tol,block', [
+    pytest.param(jnp.float32, 2e-5, 4, id='float32'),
+    pytest.param(jnp.bfloat16, 2e-2, 4, id='bfloat16'),
+    pytest.param(jnp.float32, 2e-5, 16, id='float32-block16'),
+    pytest.param(jnp.bfloat16, 2e-2, 16, id='bfloat16-block16')])
+def test_splash_and_plain_are_the_matrix_attention(fresh, dtype, tol, block):
     """T = 512 (two 256-tiles a side, one skipped), 4 q heads over 2
-    kv heads: output and dq/dk/dv of the splash route on Pallas's TPU
-    interpreter and of the plain path against attention under the
-    brute-force matrix. bfloat16: the kernel's rounding of q/sqrt(d),
-    of P for P.V and of its outputs."""
-    length, block = 256, 4
+    kv heads, blocks of 4 and of 16: output and dq/dk/dv of the splash
+    route on Pallas's TPU interpreter and of the plain path against
+    attention under the brute-force matrix. bfloat16: the kernel's
+    rounding of q/sqrt(d), of P for P.V and of its outputs."""
+    length = 256
     qkv = _qkv(2 * length, 4, 2, dtype)
     want = _out_and_grads(_matrix_attention(length, block), qkv)
     with pltpu.force_tpu_interpret_mode():
@@ -194,7 +216,8 @@ def test_splash_and_plain_are_the_matrix_attention(fresh, dtype, tol):
                                 diffusion_block=block)
     counters = tracing.counters()
     kept, visited = pallas_kernels.splash_tile_fill(512, block)
-    assert counters == {DIFFUSION: 1, FUSED: 1, 'kfac/attention/grouped': 1,
+    assert counters == {DIFFUSION: 1, SHIFTED: 1, FUSED: 1,
+                        'kfac/attention/grouped': 1,
                         KEPT: 4 * kept, VISITED: 4 * visited}
     tracing.clear_trace()
     plain_path = _out_and_grads(sequence.local_causal_attention, qkv,

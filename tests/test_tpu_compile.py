@@ -123,6 +123,34 @@ def test_the_splash_route_compiles_at_mellum2s_widths(one_chip, window):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
 
 
+@pytest.mark.parametrize('t,block', [(4096, 4), (4096, 16), (3072, 3)],
+                         ids=['shifted-4', 'shifted-16', 'divided-3'])
+def test_the_block_diffusion_route_compiles_at_sdars_widths(one_chip, t,
+                                                            block):
+    """The splash route under the block-diffusion mask at the cell's
+    heads (32 q over 4 kv heads of 128, bf16), forward and backward:
+    Mosaic takes the division-free rule at blocks of 4 and 16 and the
+    ``//`` form at a block that is not a power of two, in all three
+    kernels, and nothing of (T, T) size is left to XLA's temporaries."""
+    assert pallas_kernels.block_diffusion_route(block) == (
+        'divided' if block == 3 else 'shifted')
+    q = jax.ShapeDtypeStruct((1, t, 32, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, t, 4, 128), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(*qkv):
+        out = pallas_kernels.splash_attention(*qkv, diffusion_block=block)
+        return jnp.sum(out ** 2)
+
+    compiled = _compiled(jax.value_and_grad(loss, (0, 1, 2)), None, q, kv,
+                         kv)
+    calls = [line for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 3
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20
+
+
 @pytest.mark.parametrize('rows,d,f', [(32768, 2304, 896), (49152, 2048, 768)],
                          ids=['mellum2', 'kanana2'])
 @pytest.mark.parametrize('matrix', ['gate', 'down'])
