@@ -25,8 +25,20 @@ groups starting inside a tile) and the buffer full:
 Then one kanana MoE layer, forward + backward, on either route. Every
 row goes to ``chiprun_out/grouped_experts/table.jsonl`` (``--no-sweep``:
 the routes and the layer only, ``routes.jsonl``); standard output gets
-the fastest tiling of each product and fill. It needs a
-TPU: elsewhere both routes are something else.
+the fastest tiling of each product and fill.
+
+``--covariances`` times the per-expert covariances instead
+(``ops.factors``' statistics, ``sum_r x_r x_r^T`` an expert) at each MoE
+cell's buffer, both widths and routed rows, at the same three fills:
+``ragged_dot_general`` against the Pallas ``tgmm(x^T, x)`` at the
+tiling ``grouped_tiling`` picks and at the other tilings of lane-multiple
+divisors whose tiles fit the budget, one row a tiling, with its share
+of the 197 TFLOP/s peak (2 x routed rows x d^2 a stack), its largest
+relative error against ``ragged_dot_general`` (NaN written into the
+Pallas operand's rows past the last group) and ``max|C - C^T|``; then
+the whole statistic, ``ops.factors._experts_cov``, on either route (the
+XLA one symmetrizes). Rows go to ``covariances.jsonl`` beside the
+table. It needs a TPU: elsewhere both routes are something else.
 """
 
 from __future__ import annotations
@@ -47,12 +59,19 @@ from jax.experimental.pallas.ops.tpu.megablox.gmm import (  # noqa: E402
     gmm, tgmm)
 
 from distributed_kfac_pytorch_tpu.models import mla_moe_lm  # noqa: E402
+from distributed_kfac_pytorch_tpu.ops import factors as F  # noqa: E402
 from distributed_kfac_pytorch_tpu.ops import pallas_kernels as P  # noqa: E402
 
 HELD, PEAK = 8, 197e12
 #: name: (buffer rows, d, f, routed rows an expert in the cell)
 CELLS = {'mellum2': (32768, 2304, 896, 517),
          'kanana2': (49152, 2048, 768, 391)}
+#: name: (buffer rows, (d, f), routed rows an expert in the cell (its
+#: ``moe_rows_per_expert``), tilings swept). sdar has kanana's widths:
+#: its rows take the picked tiling only.
+COV_CELLS = {'mellum2': (32768, (2304, 896), 508, True),
+             'kanana2': (49152, (2048, 768), 391, True),
+             'sdar': (32768, (2048, 768), 243, False)}
 OUT = os.path.join('chiprun_out', 'grouped_experts')
 
 
@@ -129,17 +148,132 @@ def rel(a, b, rows=None) -> float:
                            np.asarray(b[:rows], np.float32))
 
 
+def cov_tilings(d: int) -> list:
+    """The picked tiling first, then the ``(tm, tk, tn)`` of lane-multiple
+    divisors of ``d`` from 256 whose tiles fit the budget and cover at
+    least half the largest such output tile, at row tiles of 256 and
+    512."""
+    picked = P.grouped_tiling(d, d, weight_grad=True, out_itemsize=4)
+    cuts = [t for t in range(d, 255, -P._LANE) if d % t == 0]
+
+    def fits(tm, a, b):
+        return P._grouped_tile_bytes(tm, a, b, 2, True,
+                                     4) <= P.GROUPED_TILE_BUDGET
+
+    pairs = [(a, b) for a in cuts for b in cuts
+             if fits(P.GROUPED_ROW_TILE, a, b)]
+    largest = max((a * b for a, b in pairs), default=0)
+    found = [(tm, a, b) for tm in (256, 512) for a, b in pairs
+             if 2 * a * b >= largest and fits(tm, a, b)]
+    return [picked] + [t for t in found if t != picked]
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def cov_kernel(tiling, x, gs):
+    """The Pallas covariance alone at ``tiling``, no symmetrization."""
+    return tgmm(x.swapaxes(0, 1), x, gs, jnp.float32, tiling)
+
+
+@jax.jit
+def cov_ragged(x, gs):
+    """XLA's grouped contraction alone, no symmetrization."""
+    return jax.lax.ragged_dot_general(
+        x, x, gs, F._RAGGED_ROWS, preferred_element_type=jnp.float32)
+
+
+@jax.jit
+def cov_check(got, want, gs):
+    """On the device: the largest relative error against ``want`` (NaN
+    if ``got`` is not finite), ``max|C - C^T|``, and whether every empty
+    expert's block is zero."""
+    err = jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want))
+    err = jnp.where(jnp.isfinite(got).all(), err, jnp.nan)
+    empty_zero = jnp.all((gs > 0) | ~jnp.any(got != 0, axis=(1, 2)))
+    return err, jnp.max(jnp.abs(got - got.swapaxes(1, 2))), empty_zero
+
+
+def statistic_routes() -> dict:
+    """``ops.factors._experts_cov`` on each route, the XLA one traced
+    with the gate shut."""
+    applies = P.experts_cov_applies
+
+    def shut(x, gs):
+        P.experts_cov_applies = lambda x: False
+        try:
+            return F._experts_cov(x, gs)
+        finally:
+            P.experts_cov_applies = applies
+    return {'pallas': jax.jit(F._experts_cov), 'ragged_dot': jax.jit(shut)}
+
+
+def covariances(emit, sweep: bool) -> None:
+    """The per-expert covariances alone, both routes, tiling by tiling
+    (``sweep``; else the picked tiling only); then the whole statistic
+    on either route."""
+    key = jax.random.PRNGKey(1)
+    routes = statistic_routes()
+    for cell, (rows, dims, per_expert, swept) in COV_CELLS.items():
+        for d in dims:
+            key, kx = jax.random.split(key)
+            x = jax.random.normal(kx, (rows, d), jnp.bfloat16)
+            base = {'cell': cell, 'd': d, 'buffer_rows': rows}
+            sizes = {name: jnp.asarray(fill, jnp.int32)
+                     for name, fill in fills(rows, per_expert).items()}
+            picked = P.grouped_tiling(d, d, weight_grad=True,
+                                      out_itemsize=4)
+
+            def emit_timed(row, fn, *args, show=False):
+                for fill, gs in sizes.items():
+                    here = int(gs.sum())
+                    ms = timed(fn, *args, gs)
+                    emit({**base, **row, 'fill': fill, 'rows': here,
+                          'ms': ms, 'peak_pct':
+                              100 * 2 * here * d * d / PEAK / ms * 1e3},
+                         show=show)
+
+            emit_timed({'route': 'ragged_dot'}, cov_ragged, x, show=True)
+            for tiling in (cov_tilings(d) if sweep and swept
+                           else [picked]):
+                row = {'route': 'pallas', 'tiling': tiling,
+                       'picked': tiling == picked}
+                try:
+                    call = cov_kernel.lower(tiling, x, sizes['full']
+                                            ).compile()
+                except Exception as e:  # Mosaic refuses the tiles
+                    emit({**base, **row, 'refused': str(e).splitlines()[0]})
+                    continue
+                emit_timed(row, call, x, show=tiling == picked)
+                for fill, gs in sizes.items():
+                    here = int(gs.sum())
+                    tail = (jnp.arange(rows) < here)[:, None]
+                    err, asym, empty_zero = cov_check(
+                        call(jnp.where(tail, x, jnp.nan), gs),
+                        cov_ragged(jnp.where(tail, x, 0), gs), gs)
+                    emit({**base, **row, 'fill': fill, 'check': 'nan_tail',
+                          'rel': float(err),
+                          'max_abs_c_minus_ct': float(asym),
+                          'empty_blocks_zero': bool(empty_zero)},
+                         show=tiling == picked)
+            for route, fn in routes.items():
+                emit_timed({'route': route, 'statistic': True}, fn, x,
+                           show=True)
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split('\n')[0])
     parser.add_argument('--no-sweep', dest='sweep', action='store_false',
                         help='time the two routes only, no tiling sweep')
-    sweep = parser.parse_args(argv).sweep
+    parser.add_argument('--covariances', action='store_true',
+                        help='time the per-expert covariances instead')
+    args = parser.parse_args(argv)
+    sweep = args.sweep
     if jax.default_backend() != 'tpu':
         print('needs a TPU', file=sys.stderr)
         return 2
     os.makedirs(OUT, exist_ok=True)
-    table = open(os.path.join(OUT, 'table.jsonl' if sweep
-                              else 'routes.jsonl'), 'w')
+    name = ('covariances.jsonl' if args.covariances
+            else 'table.jsonl' if sweep else 'routes.jsonl')
+    table = open(os.path.join(OUT, name), 'w')
     device = jax.devices()[0].device_kind
 
     def emit(row, show=False):
@@ -149,6 +283,10 @@ def main(argv=None) -> int:
         if show:
             print(json.dumps(row), flush=True)
 
+    if args.covariances:
+        covariances(emit, sweep)
+        table.close()
+        return 0
     key = jax.random.PRNGKey(0)
     for cell, (rows, d, f, per_expert) in CELLS.items():
         for matrix, (k, n) in (('gate', (d, f)), ('down', (f, d))):

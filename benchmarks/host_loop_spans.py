@@ -14,8 +14,10 @@ children of the window's ``kfac/host/step`` spans (mean, median and
 largest, in ms a step, the window's first step left out as
 ``readers/span_ms`` does), every counter (among them the route each
 traced attention call and stacked-expert product took,
-``kfac/attention/*`` and ``kfac/moe/grouped_pallas`` /
-``grouped_xla``), the ``kfac/build/*`` spans'
+``kfac/attention/*``, ``kfac/moe/grouped_pallas`` / ``grouped_xla``,
+and the route each traced per-expert covariance took,
+``kfac/factors/experts_cov_pallas`` / ``experts_cov_xla``), the
+``kfac/build/*`` spans'
 attributes and JAX's backend compiles by the span they came in (PR
 36: one a variant, on a checkout's first run and on a warm one alike).
 Writes them to

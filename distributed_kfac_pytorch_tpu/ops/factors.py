@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from distributed_kfac_pytorch_tpu.observability import profiling, tracing
+from distributed_kfac_pytorch_tpu.ops import pallas_kernels
 
 # The blocked self-covariance's gate (get_cov, b is None), a function of
 # the static (rows, d) alone. At rows >= d the full square a^T a runs
@@ -1052,17 +1053,32 @@ def _experts_cov(x: jax.Array, group_sizes: jax.Array,
     """``(E, d, d)``: per expert ``sum_r x_r x_r^T`` over its own rows
     of the expert-sorted ``(rows, d)`` tensor ``x`` (rows past
     ``sum(group_sizes)`` enter no expert's sum). One grouped contraction
-    (``ragged_dot_general`` with the row dimension ragged): work follows
-    the rows that are there, not ``E x rows``. Precision as
-    :func:`get_cov`; float32 out, symmetrized."""
+    whose work follows the rows that are there, not ``E x rows``: the
+    Pallas ``tgmm`` where ``pallas_kernels.experts_cov_applies`` opens
+    (counted ``kfac/factors/experts_cov_pallas``), else
+    ``ragged_dot_general`` with the row dimension ragged
+    (``experts_cov_xla``). Precision as :func:`get_cov`; float32 out,
+    symmetric: ``tgmm``'s blocks are so bit for bit, ``ragged_dot``'s
+    are symmetrized."""
     precision = None
     if compute_dtype is not None:
         x = x.astype(compute_dtype)
         if jnp.dtype(compute_dtype) == jnp.float32:
             precision = jax.lax.Precision.HIGHEST
+    group_sizes = group_sizes.astype(jnp.int32)
+    if pallas_kernels.experts_cov_applies(x):
+        tracing.count('kfac/factors/experts_cov_pallas')
+        # Entries (i, j) and (j, i) sum the same bf16 products, exact in
+        # float32, over the same rows in the same order: max|C - C^T|
+        # read 0 on a v5e at every fill, width and tiling swept
+        # (benchmarks/grouped_experts_bench.py --covariances). The
+        # symmetrization would be a transposing copy of the whole stack
+        # that changes no bit.
+        return pallas_kernels.experts_cov(x, group_sizes)
+    tracing.count('kfac/factors/experts_cov_xla')
     cov = jax.lax.ragged_dot_general(
-        x, x, group_sizes.astype(jnp.int32), _RAGGED_ROWS,
-        precision=precision, preferred_element_type=jnp.float32)
+        x, x, group_sizes, _RAGGED_ROWS, precision=precision,
+        preferred_element_type=jnp.float32)
     return (cov + cov.transpose(0, 2, 1)) * 0.5
 
 

@@ -1034,51 +1034,73 @@ GROUPED_TILE_BUDGET = 13 * 2 ** 20
 
 
 def _grouped_tile_bytes(tm: int, tk: int, tn: int, itemsize: int,
-                        weight_grad: bool) -> int:
+                        weight_grad: bool,
+                        out_itemsize: int | None = None) -> int:
     """Fast memory of one grid step: the two operand tiles and the
     output tile double-buffered, the fp32 accumulator. ``gmm`` holds a
     ``(tm, tk)`` row tile, a ``(tk, tn)`` weight tile and a ``(tm, tn)``
     output; ``tgmm`` (``weight_grad``) holds ``(tm, tk)`` and
-    ``(tm, tn)`` row tiles and a ``(tk, tn)`` output."""
+    ``(tm, tn)`` row tiles and a ``(tk, tn)`` output. The output takes
+    ``out_itemsize`` bytes an element (default: the operands')."""
     out = tk * tn if weight_grad else tm * tn
     operands = tm * (tk + tn) if weight_grad else tk * (tm + tn)
-    return 2 * itemsize * (operands + out) + 4 * out
+    out_itemsize = out_itemsize or itemsize
+    return 2 * (itemsize * operands + out_itemsize * out) + 4 * out
 
 
-def lane_divisor_below(d: int) -> int | None:
-    """The largest multiple of the lane width under ``d`` that divides
-    it, or None."""
-    for t in range(d - _LANE, 0, -_LANE):
+def lane_divisor_below(d: int, below: int | None = None) -> int | None:
+    """The largest multiple of the lane width under ``below`` (default:
+    ``d``) that divides ``d``, or None."""
+    below = d if below is None else below
+    for t in range((below - 1) // _LANE * _LANE, 0, -_LANE):
         if d % t == 0:
             return t
     return None
 
 
 def grouped_tiling(k: int, n: int, itemsize: int = 2, *,
-                   weight_grad: bool = False) -> tuple[int, int, int]:
+                   weight_grad: bool = False,
+                   out_itemsize: int | None = None) -> tuple[int, int, int]:
     """``(tm, tk, tn)`` for a grouped product that contracts ``k`` into
     ``n`` (``weight_grad``: the ``tgmm`` that makes an ``(E, k, n)``
-    weight gradient from rows of ``k`` and ``n``). Whole ``k`` and ``n``
-    where the tiles fit :data:`GROUPED_TILE_BUDGET`, so that a group's
-    weight tile is fetched once; else the wider of the two is cut to
-    its next lane-multiple divisor until they fit."""
+    weight gradient from rows of ``k`` and ``n``), its output
+    ``out_itemsize`` bytes an element (default: the operands'). Whole
+    ``k`` and ``n`` where the tiles fit :data:`GROUPED_TILE_BUDGET`, so
+    that a group's weight tile is fetched once; else the wider tile is
+    cut to the next lane-multiple divisor of its dim until they fit (a
+    2304-wide covariance: 2304, 1152, then 768, not 1152's own 384: on
+    a v5e (768, 1152) ran 12-14 % faster than (384, 1152),
+    ``benchmarks/grouped_experts_bench.py --covariances``)."""
     tm, tk, tn = GROUPED_ROW_TILE, k, n
-    while _grouped_tile_bytes(tm, tk, tn, itemsize,
-                              weight_grad) > GROUPED_TILE_BUDGET:
-        cut = lane_divisor_below(max(tk, tn))
+    while _grouped_tile_bytes(tm, tk, tn, itemsize, weight_grad,
+                              out_itemsize) > GROUPED_TILE_BUDGET:
+        wide_k = tk >= tn
+        cut = (lane_divisor_below(k, tk) if wide_k
+               else lane_divisor_below(n, tn))
         if cut is None:
             break
-        tk, tn = (cut, tn) if tk >= tn else (tk, cut)
+        tk, tn = (cut, tn) if wide_k else (tk, cut)
     return tm, tk, tn
 
 
-@functools.lru_cache(maxsize=1)
-def _grouped_matmul_allowed() -> bool:
-    """Once per process: the kill switch (recorded)."""
+@functools.lru_cache(maxsize=2)
+def _grouped_kernel_allowed(kernel: str) -> bool:
+    """Once per process and kernel: the kill switch (recorded)."""
     if _forced_fallback():
-        record_fallback('grouped_matmul', 'forced by KFAC_PALLAS_FALLBACK')
+        record_fallback(kernel, 'forced by KFAC_PALLAS_FALLBACK')
         return False
     return True
+
+
+def _grouped_kernels_open(kernel: str, rows: int, *dims: int) -> bool:
+    """What every grouped kernel's gate asks: ``dims`` multiples of the
+    lane width, ``rows`` a whole number of :data:`GROUPED_ROW_TILE`
+    tiles, a TPU or Pallas's TPU interpret mode, and no kill switch."""
+    if any(d % _LANE for d in dims) or rows % GROUPED_ROW_TILE:
+        return False
+    if jax.default_backend() != 'tpu' and not _tpu_interpret_forced():
+        return False
+    return _grouped_kernel_allowed(kernel)
 
 
 def grouped_matmul_applies(x, kernel) -> bool:
@@ -1090,15 +1112,11 @@ def grouped_matmul_applies(x, kernel) -> bool:
     :data:`GROUPED_ROW_TILE` tiles, on a TPU or under Pallas's TPU
     interpret mode. ``KFAC_PALLAS_FALLBACK=1`` closes it (recorded).
     Anything else keeps ``jax.lax.ragged_dot``."""
-    rows, k = x.shape
     if not (x.dtype == kernel.dtype
             and x.dtype in (jnp.bfloat16, jnp.float32)):
         return False
-    if k % _LANE or kernel.shape[-1] % _LANE or rows % GROUPED_ROW_TILE:
-        return False
-    if jax.default_backend() != 'tpu' and not _tpu_interpret_forced():
-        return False
-    return _grouped_matmul_allowed()
+    return _grouped_kernels_open('grouped_matmul', *x.shape,
+                                 kernel.shape[-1])
 
 
 @jax.custom_vjp
@@ -1145,3 +1163,42 @@ def _grouped_bwd(residuals, dy):
 
 
 grouped_matmul.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+# ---------------------------------------------------------------------------
+# Per-expert covariances of stacked experts
+# ---------------------------------------------------------------------------
+#
+# ``ops.factors`` contracts each expert's routed rows with themselves,
+# ``sum_r x_r x_r^T`` a group: on a TPU that is the weight-gradient
+# kernel above with both operands the rows, ``tgmm(x^T, x)``, whose grid
+# visits only the row tiles that hold routed rows.
+
+
+def experts_cov_applies(x) -> bool:
+    """The gate of :func:`experts_cov`, from what the call shows.
+
+    Open for bfloat16 rows ``x`` ``(rows, d)``, ``d`` a multiple of the
+    lane width and ``rows`` a whole number of :data:`GROUPED_ROW_TILE`
+    tiles, on a TPU or under Pallas's TPU interpret mode.
+    ``KFAC_PALLAS_FALLBACK=1`` closes it (recorded). Anything else,
+    float32 rows among them (``get_cov``'s ``HIGHEST`` contract),
+    keeps ``jax.lax.ragged_dot_general``."""
+    return (x.dtype == jnp.bfloat16
+            and _grouped_kernels_open('experts_cov', *x.shape))
+
+
+def experts_cov(x: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """``(E, d, d)`` float32: per expert ``sum_r x_r x_r^T`` over its
+    ``group_sizes[e]`` rows of the expert-sorted ``(rows, d)`` tensor
+    ``x``, fp32 accumulation. Rows past ``sum(group_sizes)`` are
+    neither read into a sum nor multiplied by zero (a NaN there reaches
+    no block); an expert with no row gets a zero block."""
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
+
+    d = x.shape[1]
+    # As in the products' weight gradient: the transpose folds into
+    # tgmm's own, the rows are not copied.
+    return tgmm(x.swapaxes(0, 1), x, group_sizes, jnp.float32,
+                grouped_tiling(d, d, x.dtype.itemsize, weight_grad=True,
+                               out_itemsize=4))

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import pytest
 
 from distributed_kfac_pytorch_tpu.observability import memory as obs_memory
+from distributed_kfac_pytorch_tpu.ops import factors
 from distributed_kfac_pytorch_tpu.ops import pallas_kernels
 from distributed_kfac_pytorch_tpu.parallel import distributed as D
 
@@ -180,3 +181,32 @@ def test_the_grouped_expert_kernels_compile_at_the_moe_cells_widths(
     for line in calls:
         assert re.search(r'op_name="[^"]*kfac_model/moe/experts',
                          line), line[:200]
+
+
+@pytest.mark.parametrize('rows,d', [(32768, 2304), (32768, 896),
+                                    (49152, 2048), (49152, 768)],
+                         ids=['mellum2_d', 'mellum2_f', 'kanana2_d',
+                              'kanana2_f'])
+def test_the_experts_covariance_compiles_at_the_moe_cells_widths(
+        one_chip, monkeypatch, rows, d):
+    """A per-expert statistic at a MoE cell's buffer and width (8
+    experts held, bf16 rows), the gate held open as a TPU backend would
+    open it: Mosaic takes ``tgmm`` at the float32 output's tiles, the
+    kernel carries the statistic's scope in its ``op_name``
+    (``kfac_expert_factors_ms`` reads it by ``kfac/factors/experts_``),
+    and neither a ``ragged-dot`` nor a transposed copy of the stack (the
+    symmetrization the kernel's symmetric blocks do not need) is left."""
+    monkeypatch.setattr(pallas_kernels, 'experts_cov_applies',
+                        lambda x: True)
+    x = jax.ShapeDtypeStruct((rows, d), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((8,), jnp.int32, sharding=one_chip)
+    compiled = _compiled(lambda x, sizes: factors.experts_a_factor(
+        x, sizes, 8), None, x, sizes)
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1
+    assert re.search(r'op_name="[^"]*kfac/factors/experts_a', calls[0]), \
+        calls[0][:200]
+    assert 'ragged' not in text
+    assert not re.search(r'f32\[8,%d,%d\]\{1,2,0' % (d, d), text)
